@@ -4,9 +4,9 @@ Three column groups, matching the serving stack's three claims:
 
 * **serving** — per workload: the legacy per-statement interpret wall
   (``PallasProgram.__call__``), the whole-program ``jitted()`` wall (one
-  traced XLA computation), and the compiled-Mosaic wall.  On hosts where
-  ``mosaic_supported()`` is False (e.g. CPU-only jax) the compiled
-  columns are ``null`` — recorded, not faked.
+  traced XLA computation), and the compiled-Mosaic wall.  Where Pallas
+  is interpreted (``repro.runtime.pallas_interpret()``: the CPU backend)
+  the compiled columns are ``null`` — recorded, not faked.
 * **batching** — per workload: B sequential interpret invocations vs one
   ``batched(B)`` dispatch (``jit(vmap(step))``), with throughputs and
   the speedup.  The acceptance gate: the batched dispatch beats the B
@@ -105,10 +105,10 @@ def _program(builder, interpret: Optional[bool] = None):
 # group 1: interpret vs jitted vs compiled wall
 # --------------------------------------------------------------------------
 def run_serving(small: bool = False) -> List[Dict]:
-    from repro.core.backend_pallas import mosaic_supported
+    from repro.runtime import pallas_interpret
     rows = []
     for name, build in _cases(small):
-        prog = _program(build)
+        prog = _program(build, interpret=True)
         args = _inputs(prog.fn)
         interp_s = _best_wall(lambda: prog(args))
         jit_s: Optional[float] = None
@@ -117,7 +117,7 @@ def run_serving(small: bool = False) -> List[Dict]:
             _block(run(args))                       # compile outside timing
             jit_s = _best_wall(lambda: run(args))
         compiled_s: Optional[float] = None
-        if mosaic_supported():
+        if not pallas_interpret():
             cprog = _program(build, interpret=False)
             crun = cprog.jitted()
             _block(crun(args))
@@ -140,7 +140,7 @@ def run_serving(small: bool = False) -> List[Dict]:
 def run_batching(small: bool = False, batch: int = BATCH) -> List[Dict]:
     rows = []
     for name, build in _cases(small):
-        prog = _program(build)
+        prog = _program(build, interpret=True)
         bargs = _batch_inputs(prog.fn, batch)
         lanes = [{k: v[i] for k, v in bargs.items()} for i in range(batch)]
 
@@ -219,9 +219,9 @@ def run_scan(small: bool = False) -> Dict:
 # --------------------------------------------------------------------------
 def _host() -> Dict:
     import jax
-    from repro.core.backend_pallas import mosaic_supported
+    from repro.runtime import pallas_interpret
     return {
-        "mosaic_supported": mosaic_supported(),
+        "mosaic_supported": not pallas_interpret(),
         "local_devices": jax.local_device_count(),
         "jax": jax.__version__,
     }

@@ -38,6 +38,8 @@ def main() -> None:
     ap.add_argument("--suite", default="all")
     args = ap.parse_args()
 
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, module, kwargs in SUITES:

@@ -9,22 +9,23 @@ This is the TPU-native rendition of the paper's pragma semantics
     MXU op inside the kernel (== `#pragma HLS unroll`),
   * array partitioning      -> **BlockSpec** index maps (HBM->VMEM tiling).
 
-Two statement shapes are supported, which cover the paper's linear-algebra
-benchmarks (GEMM / 2MM / 3MM / BICG / GESUMMV):
+One statement shape lowers to a kernel: the *contraction*
+``D(i..) = D(i..) + X(..) * Y(..)`` (GEMM / 2MM / 3MM / BICG / GESUMMV) ->
+``dot_general`` per block + grid accumulation over the reduction grid dims.
+Any other statement raises ``PallasLowerError``.  A compiled lowering also
+raises for BlockSpecs the TPU cannot tile (``_tpu_block_problem``), so only
+schedules tiled to the (8, 128) layout reach Mosaic.
 
-  1. *contraction*:  D(i..) = D(i..) + X(..) * Y(..)   -> jnp.dot + grid
-     accumulation over reduction grid dims,
-  2. *affine map*:   D(i..) = f(loads with block-aligned accesses)  ->
-     vectorized elementwise block computation.
-
-Anything else falls back to the (slow, exact) JAX oracle backend; the
-dedicated kernels in ``repro.kernels`` cover stencils/scans.
+``PallasProgram.jitted()`` / ``batched(B)`` trace the whole loop AST into
+one XLA computation: a nest whose statement lowers as above runs its
+kernel, every other nest is vectorized into gathers and reductions, or
+becomes a ``fori_loop``.  Whether Pallas runs compiled or interpreted is
+``repro.runtime.pallas_interpret()``: interpreted iff the backend is the CPU.
 """
 from __future__ import annotations
 
 import functools
 import math
-import os
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -32,8 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.runtime import pallas_interpret
+
 from .affine import LinExpr
-from .errors import warn_structured
+from .cost_model import TPU_V5E
 from .ir import BinOp, Call, Const, Expr, Function, IterVal, Load, Placeholder, Statement
 from .ir import loads_of
 from . import faultinject, telemetry
@@ -134,54 +137,28 @@ def _match_contraction(stmt: Statement) -> Optional[Tuple[Load, Load, Load]]:
     return None
 
 
-# Once-per-process probe of compiled (Mosaic/XLA) pallas_call support.
-# CPU-only jax builds raise on ``interpret=False``; TPU hosts compile.
-_MOSAIC_PROBE: Optional[bool] = None
+def _tpu_block_problem(spec: _ArraySpec) -> Optional[str]:
+    """Why Mosaic would refuse ``spec``'s block, or None.  The last two
+    block dims must be multiples of (8, 128) or equal the array's; a
+    rank-1 block must be the whole array or a multiple of 128."""
+    blk, shp = spec.block, spec.shape
+    if len(blk) == 1:
+        if blk[0] != shp[0] and blk[0] % 128:
+            return (f"{spec.name}: rank-1 block {blk} of {shp} is neither "
+                    f"the whole array nor a multiple of 128")
+        return None
+    for b, s, m in zip(blk[-2:], shp[-2:], (8, 128)):
+        if b != s and b % m:
+            return (f"{spec.name}: block {blk} of {shp} breaks the TPU "
+                    f"tiling (last two dims multiples of (8, 128) or equal "
+                    f"to the array's)")
+    return None
 
 
-def mosaic_supported() -> bool:
-    """Probe (once per process) whether ``pl.pallas_call`` lowers and runs
-    *compiled* on this host.  Silent on failure — the answer just decides
-    the ``interpret`` default; callers that explicitly request compiled
-    mode still get the per-runner one-failure interpret fallback."""
-    global _MOSAIC_PROBE
-    if _MOSAIC_PROBE is None:
-        try:
-            def _probe_kernel(x_ref, o_ref):
-                o_ref[...] = x_ref[...] + 1.0
-
-            out = pl.pallas_call(
-                _probe_kernel,
-                out_shape=jax.ShapeDtypeStruct((8,), jnp.float32),
-                interpret=False)(jnp.zeros((8,), jnp.float32))
-            jax.block_until_ready(out)
-            _MOSAIC_PROBE = True
-        except Exception:
-            _MOSAIC_PROBE = False
-    return _MOSAIC_PROBE
-
-
-def _interpret_default() -> bool:
-    """Default for ``interpret``: compiled Mosaic wherever the host
-    supports it (probed once per process), interpret everywhere else.
-    ``POM_PALLAS_INTERPRET`` overrides both ways: truthy forces interpret,
-    ``0``/``false`` forces compiled (with the runtime fallback intact)."""
-    v = os.environ.get("POM_PALLAS_INTERPRET")
-    if v is None:
-        return not mosaic_supported()
-    return v.lower() not in ("0", "false", "no")
-
-
-# (schedule signature, array shapes/dtypes, mode) -> runner.  ``mode`` is
-# "interpret" or "compiled"; a runner that pins itself to interpret after a
-# Mosaic failure *evicts* its "compiled" entry, so a later request for a
-# compiled runner rebuilds fresh instead of being served the pinned one —
-# a transient failure cannot poison subsequent compiles.
+# (schedule signature, array shapes/dtypes, mode) -> runner; ``mode`` is
+# "interpret" or "compiled"
 _PALLAS_RUNNER_CACHE: Dict[Tuple, Callable] = {}
 _PALLAS_RUNNER_CACHE_MAX = 1024
-# statement uids whose mosaic_fallback_interpret warning already fired —
-# at most one structured warning per statement per process
-_FALLBACK_WARNED: set = set()
 
 # backward-compat alias (caching.clear_all reaches in by the old name)
 _LOWER_CACHE = _PALLAS_RUNNER_CACHE
@@ -197,11 +174,10 @@ def lower_stmt_pallas(stmt: Statement, interpret: Optional[bool] = None) -> Call
     shapes/dtypes, requested mode), and the returned runner builds its
     ``pl.pallas_call`` once per observed output shape/dtype — repeated
     ``run()`` calls reuse the compiled callable instead of rebuilding it.
-    ``interpret=None`` defers to ``_interpret_default()`` (compiled where
-    the Mosaic probe succeeds, ``POM_PALLAS_INTERPRET`` overriding).
+    ``interpret=None`` defers to ``pallas_interpret()``.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = pallas_interpret()
     from . import caching
     key = None
     if caching.ENABLED:
@@ -216,7 +192,7 @@ def lower_stmt_pallas(stmt: Statement, interpret: Optional[bool] = None) -> Call
     # span covers only the actual lowering work; memoized hits return above
     with telemetry.span("backend.lower", _cat="backend", backend="pallas",
                         statement=stmt.name, interpret=interpret):
-        run = _lower_stmt_pallas_compute(stmt, interpret, cache_key=key)
+        run = _lower_stmt_pallas_compute(stmt, interpret)
     if key is not None:
         if len(_PALLAS_RUNNER_CACHE) >= _PALLAS_RUNNER_CACHE_MAX:
             _PALLAS_RUNNER_CACHE.clear()
@@ -225,7 +201,6 @@ def lower_stmt_pallas(stmt: Statement, interpret: Optional[bool] = None) -> Call
 
 
 def _lower_stmt_pallas_compute(stmt: Statement, interpret: bool,
-                               cache_key: Optional[Tuple] = None,
                                pure: bool = False) -> Callable:
     grid_dims, block_dims = _classify_dims(stmt)
     trips = _dim_extents(stmt)
@@ -247,6 +222,21 @@ def _lower_stmt_pallas_compute(stmt: Statement, interpret: bool,
         specs[arr.name] = _array_spec(stmt, arr, idx, grid_dims, block_dims,
                                       trips, lbs)
         order.append((arr.name, idx))
+    if not interpret:
+        for spec in specs.values():
+            problem = _tpu_block_problem(spec)
+            if problem:
+                raise PallasLowerError(f"{stmt.name}: {problem}")
+        # x, y, init and out blocks, each double-buffered by the pipeline
+        itemsize = {a.name: jnp.dtype(a.dtype.np or jnp.bfloat16).itemsize
+                    for a in (x_arr, y_arr, store_arr)}
+        vmem = 2 * sum(math.prod(specs[n].block) * itemsize[n]
+                       for n in (x_arr.name, y_arr.name,
+                                 store_arr.name, store_arr.name))
+        if vmem > TPU_V5E.vmem_bytes:
+            raise PallasLowerError(
+                f"{stmt.name}: blocks need {vmem} bytes of VMEM double-"
+                f"buffered, over the {TPU_V5E.vmem_bytes} available")
 
     out_spec = specs[store_arr.name]
     # reduction grid dims: grid dims that do not appear in the store index map
@@ -322,12 +312,9 @@ def _lower_stmt_pallas_compute(stmt: Statement, interpret: bool,
     # one pallas_call per observed output shape/dtype; repeated run() calls
     # (the common case in autotuning sweeps) reuse the built callable
     call_cache: Dict[Tuple, Callable] = {}
-    # compiled (Mosaic) lowering may fail on hosts without TPU lowering
-    # support; after one failure the runner pins itself to interpret mode
-    state = {"interpret": interpret}
 
-    def _call_for(shape: Tuple[int, ...], dtype, interp: bool) -> Callable:
-        ck = (shape, jnp.dtype(dtype).name, interp)
+    def _call_for(shape: Tuple[int, ...], dtype) -> Callable:
+        ck = (shape, jnp.dtype(dtype).name)
         fn = call_cache.get(ck)
         if fn is None:
             fn = pl.pallas_call(
@@ -341,44 +328,31 @@ def _lower_stmt_pallas_compute(stmt: Statement, interpret: bool,
                 out_specs=pl.BlockSpec(out_spec.block,
                                        idx_fn(out_spec.index_map_exprs)),
                 out_shape=jax.ShapeDtypeStruct(shape, dtype),
-                interpret=interp,
+                interpret=interpret,
             )
             call_cache[ck] = fn
         return fn
 
-    if pure:
-        # trace-friendly variant (no try/except, no fault injection): the
-        # caller fixed the mode statically, e.g. inside a jit-traced
-        # program where a runtime fallback could not fire anyway
-        def run_pure(arrays: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-            x = jnp.asarray(arrays[x_arr.name])
-            y = jnp.asarray(arrays[y_arr.name])
-            o = jnp.asarray(arrays[store_arr.name])
-            return _call_for(o.shape, o.dtype, interpret)(x, y, o)
-
-        return run_pure
-
-    def run(arrays: Dict[str, jnp.ndarray]) -> jnp.ndarray:
+    def run_pure(arrays: Dict[str, jnp.ndarray]) -> jnp.ndarray:
         x = jnp.asarray(arrays[x_arr.name])
         y = jnp.asarray(arrays[y_arr.name])
         o = jnp.asarray(arrays[store_arr.name])
-        if not state["interpret"]:
-            try:
-                if faultinject.fires("backend.lower"):
-                    raise RuntimeError("injected Mosaic lowering failure")
-                return _call_for(o.shape, o.dtype, False)(x, y, o)
-            except Exception as e:  # Mosaic/XLA raise backend-specific types
-                if stmt.uid not in _FALLBACK_WARNED:
-                    _FALLBACK_WARNED.add(stmt.uid)
-                    warn_structured("backend_pallas",
-                                    "mosaic_fallback_interpret",
-                                    stmt=stmt.name, error=type(e).__name__)
-                state["interpret"] = True
-                # the pinned runner must not keep serving the "compiled"
-                # cache slot: evict so the next compiled request retries
-                if cache_key is not None:
-                    _PALLAS_RUNNER_CACHE.pop(cache_key, None)
-        return _call_for(o.shape, o.dtype, True)(x, y, o)
+        return _call_for(o.shape, o.dtype)(x, y, o)
+
+    if pure or interpret:
+        # ``pure``: trace-friendly, for the jit-traced program
+        return run_pure
+
+    def run(arrays: Dict[str, jnp.ndarray]) -> jnp.ndarray:
+        # a compiled kernel that fails raises, naming the statement
+        try:
+            if faultinject.fires("backend.lower"):
+                raise RuntimeError("injected Mosaic lowering failure")
+            return run_pure(arrays)
+        except Exception as e:  # Mosaic/XLA raise backend-specific types
+            raise PallasLowerError(
+                f"{stmt.name}: compiled Pallas kernel failed: "
+                f"{type(e).__name__}: {e}") from e
 
     return run
 
@@ -411,8 +385,8 @@ from jax import lax
 
 
 class TraceError(Exception):
-    """The program cannot be traced into a single JAX computation; the
-    serving path falls back to the per-statement/oracle runner."""
+    """The program cannot be traced into a single JAX computation, so it
+    has no ``jitted()`` or ``batched()`` executor."""
 
 
 _JNP_CALLS = {
@@ -501,23 +475,50 @@ def _eval_body(sn, env: Dict, bufs: Dict, by_id: Dict):
     return ev(s.body)
 
 
+def _box_const(lb, ranges: Dict[str, Tuple[int, int]]) -> Optional[int]:
+    """The value of a loop bound when it is one constant over the box of
+    enclosing ``ranges`` (e.g. a split loop's ``min(4095 - 32*i_o, 31)``
+    for ``i_o`` in [0, 127] is 31), else None.  Each term is affine, so
+    its extremes over the box lie at corners, and ceil/floor division
+    keeps them there."""
+    lows, highs = [], []
+    for b in lb.bounds:
+        if any(v not in ranges for v in b.expr.vars()):
+            return None
+        lo = hi = b.expr.const
+        for v, c in b.expr.coeffs.items():
+            a, z = ranges[v]
+            lo += min(c * a, c * z)
+            hi += max(c * a, c * z)
+        lows.append(_tdiv(lo, b.div, lb.is_lower))
+        highs.append(_tdiv(hi, b.div, lb.is_lower))
+    agg = max if lb.is_lower else min
+    lo, hi = agg(lows), agg(highs)
+    return lo if lo == hi else None
+
+
 def _vec_plan(node) -> Optional[Tuple]:
     """Whole-nest vectorization plan for a single-statement ForNode chain.
 
     Returns ``(chain, sn, kept, red, rest_body)`` when the remaining nest
-    can be evaluated all-iterations-at-once: constant bounds, one straight
-    StmtNode leaf, an injective store over the kept dims (each kept var in
-    exactly one store position, coefficient ±1), and no load of the stored
+    can be evaluated all-iterations-at-once: bounds constant over the box
+    of enclosing ranges (``_box_const``), one straight StmtNode leaf, an
+    injective store over the kept dims (each kept var in exactly one store
+    position, alone with coefficient ±1 or as a mixed-radix digit of a
+    split loop), and no load of the stored
     array except the accumulator pattern ``D = D + rest`` (reduction dims)
     or a same-index read (pure map).  ``None`` → execute sequentially.
     """
     from .loop_ir import ForNode, StmtNode
     chain: List[Tuple[str, int, int]] = []
+    ranges: Dict[str, Tuple[int, int]] = {}
     n = node
     while isinstance(n, ForNode):
-        if not (n.lo.is_constant() and n.hi.is_constant()):
+        lo, hi = _box_const(n.lo, ranges), _box_const(n.hi, ranges)
+        if lo is None or hi is None:
             return None
-        chain.append((n.var, n.lo.const_value(), n.hi.const_value()))
+        chain.append((n.var, lo, hi))
+        ranges[n.var] = (lo, hi)
         if len(n.body) != 1:
             return None
         n = n.body[0]
@@ -525,17 +526,22 @@ def _vec_plan(node) -> Optional[Tuple]:
         return None
     sn = n
     s = sn.stmt
-    remaining = {v for v, _, _ in chain}
     arr, store_idx, _ = _stmt_accesses(sn)
     kept: Dict[str, int] = {}          # var -> store position
     for p, e in enumerate(store_idx):
-        vs = [v for v in e.vars() if v in remaining]
-        if len(vs) > 1:
+        vs = [v for v in e.vars() if v in ranges]
+        if any(v in kept for v in vs):
             return None
-        if vs:
-            v = vs[0]
-            if v in kept or abs(e.coeff(v)) != 1:
+        if len(vs) == 1 and abs(e.coeff(vs[0])) != 1:
+            return None
+        # several vars in one position (a split loop, i = 32*i_o + i_u)
+        # must encode the position injectively, as mixed-radix digits
+        span = 0
+        for v in sorted(vs, key=lambda v: abs(e.coeff(v))):
+            if len(vs) > 1 and abs(e.coeff(v)) <= span:
                 return None
+            span += abs(e.coeff(v)) * (ranges[v][1] - ranges[v][0])
+        for v in vs:
             kept[v] = p
     red = [v for v, _, _ in chain if v not in kept]
 
@@ -645,16 +651,14 @@ def _exec_stmt_scalar(sn, bufs: Dict, env: Dict) -> Dict:
 def _build_step(fn: Function, ast, interpret: bool):
     """Trace the loop AST into ``step(bufs) -> bufs`` (pure, jit-able).
 
-    Statement nests are vectorized where legal; with compiled Mosaic
-    available (``interpret=False``) supported contractions use their
-    ``pallas_call`` kernels instead of the generic gather/reduce.  Raises
-    ``TraceError`` (possibly only at trace time) when some construct has
-    no JAX rendition.
+    Statement nests are vectorized where legal; compiled
+    (``interpret=False``), a nest whose statement lowers to a contraction
+    kernel runs that ``pallas_call`` instead of the generic gather/reduce.
+    Raises ``TraceError`` (possibly only at trace time) when some
+    construct has no JAX rendition.
     """
     from .loop_ir import (DataflowRegion, ForNode, IfNode, ProgramAST,
                           ScanRegion, StmtNode, TaskNode)
-
-    use_pallas_kernels = not interpret and mosaic_supported()
 
     def run_nodes(nodes, bufs, env):
         for n in nodes:
@@ -667,7 +671,7 @@ def _build_step(fn: Function, ast, interpret: bool):
         if isinstance(node, ScanRegion):
             return run_scan(node, bufs, env)
         if isinstance(node, ForNode):
-            if use_pallas_kernels:
+            if not interpret:
                 runner = _nest_pallas_runner(node, env)
                 if runner is not None:
                     dest, run = runner
@@ -707,7 +711,8 @@ def _build_step(fn: Function, ast, interpret: bool):
 
     def _nest_pallas_runner(node, env):
         """Compiled pallas_call for a single-statement nest at top level
-        (no outer env) whose schedule the contraction matcher supports."""
+        (no outer env) whose schedule the contraction lowering accepts —
+        it refuses blocks the TPU cannot tile, and those nests vectorize."""
         if env:
             return None
         from .loop_ir import ForNode as _F, StmtNode as _S
@@ -766,31 +771,26 @@ def _build_step(fn: Function, ast, interpret: bool):
 
 class BatchedRunner:
     """``jit(vmap(step))`` over the whole program — one dispatch serves a
-    batch of invocations.  With several local devices and a divisible
-    batch, the vmapped step is ``shard_map``'d across them."""
+    batch of invocations.  With several local devices and a batch they
+    divide, the vmapped step is ``shard_map``'d across them, one slice of
+    the batch per device."""
 
     def __init__(self, program: "PallasProgram", batch_size: Optional[int],
                  step):
         self.program = program
         self.batch_size = batch_size
-        self._sequential = step is None
-        if step is None:
-            return
         batched = jax.vmap(step)
         self.devices = 1
-        ndev = len(jax.local_devices())
-        if ndev > 1 and batch_size and batch_size % ndev == 0:
-            try:
-                from jax.experimental.shard_map import shard_map
-                from jax.sharding import Mesh, PartitionSpec as P
-                import numpy as _np
-                mesh = Mesh(_np.array(jax.local_devices()), ("batch",))
-                batched = shard_map(batched, mesh=mesh,
+        devs = jax.local_devices()
+        if len(devs) > 1 and batch_size and batch_size % len(devs) == 0:
+            from jax.sharding import Mesh, PartitionSpec as P
+            import numpy as _np
+            mesh = Mesh(_np.array(devs), ("batch",))
+            batched = jax.shard_map(batched, mesh=mesh,
                                     in_specs=(P("batch"),),
-                                    out_specs=P("batch"))
-                self.devices = ndev
-            except Exception:
-                pass
+                                    out_specs=P("batch"),
+                                    check_vma=False)  # pallas_call outputs
+            self.devices = len(devs)
         self._fn = jax.jit(batched)
 
     def _infer_batch(self, arrays: Dict[str, Any]) -> int:
@@ -802,43 +802,43 @@ class BatchedRunner:
                 "the runner was built with batch_size=None")
         return self.batch_size
 
-    def __call__(self, arrays: Dict[str, Any]) -> Dict[str, Any]:
-        prog = self.program
-        if self._sequential:
-            b = self._infer_batch(arrays)
-            outs = [prog(dict((k, v[i]) for k, v in arrays.items()))
-                    for i in range(b)]
-            import numpy as _np
-            return {k: _np.stack([_np.asarray(o[k]) for o in outs])
-                    for k in outs[0]}
+    def _bufs(self, arrays: Dict[str, Any]) -> Dict[str, Any]:
         b = self._infer_batch(arrays)
         if self.batch_size is not None and b != self.batch_size:
             raise ValueError(
                 f"batched runner built for batch {self.batch_size}, "
                 f"got {b}")
-        bufs = prog._batch_bufs(arrays, b)
+        return self.program._batch_bufs(arrays, b)
+
+    def lower(self, arrays: Dict[str, Any]):
+        """``jax.stages.Lowered`` of the batched step for ``arrays``."""
+        return self._fn.lower(self._bufs(arrays))
+
+    def __call__(self, arrays: Dict[str, Any]) -> Dict[str, Any]:
+        bufs = self._bufs(arrays)
         with telemetry.span("backend.execute", _cat="backend",
-                            backend="pallas_batched", fn=prog.fn.name,
-                            batch=b):
+                            backend="pallas_batched",
+                            fn=self.program.fn.name,
+                            batch=next(iter(bufs.values())).shape[0]):
             return self._fn(bufs)
 
 
 class PallasProgram:
     """The ``compile(fn, target="pallas")`` artifact.
 
-    Calling it runs the legacy exact path (per-statement ``pallas_call``
-    plan, oracle fallback) — unchanged semantics.  The serving surface on
-    top:
+    Calling it runs ``jitted()`` when Pallas is compiled, and the legacy
+    exact path (per-statement ``pallas_call`` plan, oracle fallback) when
+    it is interpreted.  The serving surface:
 
     * ``jitted()``  — the whole program traced + jit'd as one XLA
       computation (vectorized nests, ``fori_loop`` sequential loops,
       ``lax.scan`` over detected ``ScanRegion`` blocks);
     * ``batched(B)`` — ``jit(vmap(step))`` (+ ``shard_map`` across local
-      devices when available), one dispatch per *batch* of invocations.
+      devices when they divide B), one dispatch per *batch* of
+      invocations.
 
-    Programs the tracer cannot express fall back transparently: calling
-    stays exact, ``batched`` degrades to a sequential per-element loop
-    (with a one-time structured warning).
+    Both raise ``TraceError`` for a program the tracer cannot express;
+    neither falls back to the legacy or host path.
     """
 
     def __init__(self, fn: Function, ast, interpret: bool, legacy,
@@ -846,15 +846,18 @@ class PallasProgram:
         self.fn = fn
         self.ast = ast
         self.interpret = interpret
-        self.mode = mode          # "pallas" (per-stmt plan) | "oracle"
+        # "traced" (compiled: the traced step) | "pallas" (interpreted
+        # per-stmt plan) | "oracle" (interpreted, host loop interpreter)
+        self.mode = mode
         self._legacy = legacy
         self._step = None
-        self._step_ok: Optional[bool] = None
+        self._trace_error: Optional[Exception] = None
         self._jit = None
         self._batched: Dict[Optional[int], BatchedRunner] = {}
 
-    # -- legacy exact path --------------------------------------------------
     def __call__(self, arrays: Dict[str, Any]) -> Dict[str, Any]:
+        if self._legacy is None:
+            return self.jitted()(arrays)
         return self._legacy(arrays)
 
     # -- traced serving path ------------------------------------------------
@@ -889,7 +892,7 @@ class PallasProgram:
     def traceable(self) -> bool:
         """Whether the whole program traces into one JAX computation
         (checked once, via an abstract evaluation — no FLOPs spent)."""
-        if self._step_ok is None:
+        if self._step is None and self._trace_error is None:
             try:
                 step = _build_step(self.fn, self.ast, self.interpret)
                 spec = {ph.name: jax.ShapeDtypeStruct(ph.shape,
@@ -897,26 +900,30 @@ class PallasProgram:
                         for ph in self.fn.placeholders.values()}
                 jax.eval_shape(step, spec)
                 self._step = step
-                self._step_ok = True
             except Exception as e:
-                warn_structured("backend_pallas",
-                                "pallas_trace_fallback",
-                                fn=self.fn.name, error=type(e).__name__)
-                self._step_ok = False
-        return self._step_ok
+                self._trace_error = e
+        return self._step is not None
+
+    def _traced_step(self):
+        if not self.traceable():
+            raise TraceError(
+                f"{self.fn.name}: cannot trace into one JAX computation: "
+                f"{type(self._trace_error).__name__}: {self._trace_error}"
+            ) from self._trace_error
+        return self._step
 
     def jitted(self):
-        """Single-invocation jit'd executor: ``run(arrays) -> dict``."""
-        if not self.traceable():
-            return self._legacy
+        """Single-invocation jit'd executor: ``run(arrays) -> dict``;
+        ``run.lower(arrays)`` gives its ``jax.stages.Lowered``."""
         if self._jit is None:
-            jfn = jax.jit(self._step)
+            jfn = jax.jit(self._traced_step())
 
             def run(arrays: Dict[str, Any]) -> Dict[str, Any]:
                 with telemetry.span("backend.execute", _cat="backend",
                                     backend="pallas_jit", fn=self.fn.name):
                     return jfn(self._full_bufs(arrays))
 
+            run.lower = lambda arrays: jfn.lower(self._full_bufs(arrays))
             self._jit = run
         return self._jit
 
@@ -924,7 +931,6 @@ class PallasProgram:
         """Batched executor: every input carries a leading batch dim."""
         br = self._batched.get(batch_size)
         if br is None:
-            step = self._step if self.traceable() else None
-            br = BatchedRunner(self, batch_size, step)
+            br = BatchedRunner(self, batch_size, self._traced_step())
             self._batched[batch_size] = br
         return br

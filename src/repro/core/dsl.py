@@ -169,13 +169,6 @@ class PomFunction:
         return f"PomFunction({self.fn.name})"
 
 
-def mosaic_supported() -> bool:
-    """Whether this host compiles Pallas kernels with Mosaic (probed once
-    per process; lazy so the base import path stays jax-free)."""
-    from .backend_pallas import mosaic_supported as probe
-    return probe()
-
-
 def function(name: str, outputs: Optional[Sequence[str]] = None,
              dataflow: Optional[bool] = None) -> PomFunction:
     """Open a POM function scope; ``outputs`` optionally names the

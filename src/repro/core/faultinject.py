@@ -2,9 +2,9 @@
 
 Every recovery path in the resilient compile service — worker
 supervision in ``search.PoolEvaluator``, checksum/quarantine handling in
-``designdb.DesignDB``, the Mosaic→interpret fallback in
-``backend_pallas`` — is exercised through *named injection sites* rather
-than trusted:
+``designdb.DesignDB``, and the compiled-kernel failure path in
+``backend_pallas`` (which raises) — is exercised through *named injection
+sites* rather than trusted:
 
 =================  ==========================================  ==============
 site               where it fires                              kinds

@@ -554,10 +554,11 @@ class CompileJAX(Pass):
 
 
 class LowerPallas(Pass):
-    """Lower each statement to a ``pl.pallas_call``; statements the Pallas
-    matcher does not support — or functions whose fusion specs interleave
-    statement instances — fall back to the exact JAX oracle, keeping the
-    backend total."""
+    """Build the Pallas artifact (``lower_function_pallas``): compiled, the
+    traced whole-program step; interpreted, per-statement ``pl.pallas_call``
+    wrappers, with the exact JAX oracle for statements the Pallas matcher
+    does not support or functions whose fusion specs interleave statement
+    instances."""
     name, stage, dumps = "lower-pallas", "backend", "backend"
 
     def __init__(self, interpret: Optional[bool] = None, fallback: bool = True):
@@ -574,20 +575,31 @@ def lower_function_pallas(fn: Function, ast=None,
                           fallback: bool = True):
     """Program-level Pallas artifact: a ``backend_pallas.PallasProgram``.
 
-    Calling the artifact runs the legacy exact path: without fusion specs
-    the statements execute whole-nest sequentially, which is exactly the
-    unfused loop IR's instance order, so chaining the per-statement
-    ``pallas_call`` wrappers is semantics-preserving; fused programs
-    (shared loops interleave instances of different statements) and
-    unsupported statement shapes use the oracle instead.  The serving
-    surface (``.jitted()`` / ``.batched(B)``) traces the whole loop AST —
-    including ``ScanRegion`` scan-over-layers — into one jit'd (and
-    vmapped / shard_mapped) computation."""
+    Compiled (``interpret=False``, the default on an accelerator), calling
+    the artifact runs its traced step, ``.jitted()``: nests whose blocks
+    the TPU can tile run the contraction kernel, every other nest runs as
+    vectorized XLA on the device.  Nothing falls back to the host oracle;
+    an untraceable program raises ``TraceError``.
+
+    Interpreted, calling the artifact runs the legacy exact path: without
+    fusion specs the statements execute whole-nest sequentially, which is
+    exactly the unfused loop IR's instance order, so chaining the
+    per-statement ``pallas_call`` wrappers is semantics-preserving; fused
+    programs (shared loops interleave instances of different statements)
+    and unsupported statement shapes use the oracle instead, unless
+    ``fallback`` is off.  In both modes the serving surface (``.jitted()``
+    / ``.batched(B)``) traces the whole loop AST — including
+    ``ScanRegion`` scan-over-layers — into one jit'd (and vmapped /
+    shard_mapped) computation."""
+    from repro.runtime import pallas_interpret
     from .backend_pallas import (PallasLowerError, PallasProgram,
-                                 _interpret_default, lower_stmt_pallas)
+                                 lower_stmt_pallas)
     from .astbuild import build_ast
     if ast is None:
         ast = build_ast(fn)
+    eff = pallas_interpret() if interpret is None else bool(interpret)
+    if not eff:
+        return PallasProgram(fn, ast, False, None, "traced")
 
     plan = []
     fused = any(s.after_spec is not None for s in fn.statements)
@@ -595,7 +607,7 @@ def lower_function_pallas(fn: Function, ast=None,
         try:
             for s in fn.statements:
                 arr, _ = s.store_access()
-                plan.append((arr.name, lower_stmt_pallas(s, interpret=interpret)))
+                plan.append((arr.name, lower_stmt_pallas(s, interpret=True)))
         except PallasLowerError:
             plan = []
     if not plan:
@@ -617,9 +629,7 @@ def lower_function_pallas(fn: Function, ast=None,
             return bufs
 
         legacy, mode = run, "pallas"
-
-    eff = _interpret_default() if interpret is None else bool(interpret)
-    return PallasProgram(fn, ast, eff, legacy, mode)
+    return PallasProgram(fn, ast, True, legacy, mode)
 
 
 def backend_pass(target: str, **kw) -> Pass:
@@ -652,7 +662,7 @@ def compile(fn, target: str = "hls",
     ``fn`` is an ``ir.Function`` or a DSL ``PomFunction``.  ``target``
     picks the lowering pass: ``"hls"`` returns synthesizable C,
     ``"jax"`` an executable oracle ``run(arrays) -> dict``, ``"pallas"``
-    a TPU-kernel runner with oracle fallback.  ``graph_passes`` names
+    a ``PallasProgram`` (see ``lower_function_pallas``).  ``graph_passes`` names
     graph-level optimizations to run (``"cse"``, ``"dce"``, ``"fuse"``);
     the default is the always-safe memo-sharing pass.  When ``outputs``
     narrows the externally observable arrays, dead-op elimination is

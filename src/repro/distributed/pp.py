@@ -19,7 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -86,10 +85,10 @@ def gpipe_forward(layer_fn: Callable, stacked_params, x_microbatched,
         return last[None]
 
     pspec = jax.tree_util.tree_map(lambda _: P(stage_axis), stacked_params)
-    fm = shard_map(stage_fn, mesh=mesh,
-                   in_specs=(pspec, P(stage_axis)),
-                   out_specs=P(stage_axis),
-                   check_rep=False)
+    fm = jax.shard_map(stage_fn, mesh=mesh,
+                       in_specs=(pspec, P(stage_axis)),
+                       out_specs=P(stage_axis),
+                       check_vma=False)
     # reshape stacked params: (L, ...) -> (S, L/S, ...), x -> (S=1 bcast)
     sp = jax.tree_util.tree_map(
         lambda p: p.reshape((n_stages, per_stage) + p.shape[1:]),

@@ -18,12 +18,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.runtime import pallas_interpret
+
 NEG_INF = -1e30
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, scale: float, nkv: int, bkv: int):
-    ik = pl.program_id(1)
+    h, ik = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
@@ -37,13 +39,13 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale  # (1, bkv)
     kpos = ik * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
-    s = jnp.where(kpos < len_ref[0], s, NEG_INF)
+    s = jnp.where(kpos < len_ref[h], s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
+    m_prev = m_ref[...]                                       # (1, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -58,8 +60,11 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                      length: Optional[jnp.ndarray] = None,
                      scale: Optional[float] = None, bkv: int = 256,
-                     interpret: bool = True) -> jnp.ndarray:
-    """q: (B, Hq, D), k/v: (B, Hkv, S, D), length: (B,) -> (B, Hq, D)."""
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
+    """q: (B, Hq, D), k/v: (B, Hkv, S, D), length: (B,) -> (B, Hq, D).
+
+    The per-row lengths ride in SMEM by scalar prefetch (a rank-1 VMEM
+    block of one length per grid row would break the TPU tiling)."""
     b, hq, d = q.shape
     _, hkv, s, _ = k.shape
     assert hq % hkv == 0
@@ -78,21 +83,24 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, nkv=grid[1], bkv=bkv),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda h, ik: (h,)),
-            pl.BlockSpec((1, 1, d), lambda h, ik: (h, 0, 0)),
-            pl.BlockSpec((1, bkv, d), lambda h, ik, grp=group: (h // grp, ik, 0)),
-            pl.BlockSpec((1, bkv, d), lambda h, ik, grp=group: (h // grp, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda h, ik: (h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, d), lambda h, ik, lens: (h, 0, 0)),
+                pl.BlockSpec((1, bkv, d),
+                             lambda h, ik, lens, grp=group: (h // grp, ik, 0)),
+                pl.BlockSpec((1, bkv, d),
+                             lambda h, ik, lens, grp=group: (h // grp, ik, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, d), lambda h, ik, lens: (h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, d), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(lengths, qf, kf, vf)

@@ -8,11 +8,14 @@ leading grid dim walks experts; expert weights stream HBM->VMEM once per
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import pallas_interpret
 
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
@@ -31,7 +34,7 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
 
 def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
                    bm: int = 128, bn: int = 128, bk: int = 128,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: (E, cap, d) @ w: (E, d, f) -> (E, cap, f)."""
     e, cap, d = x.shape
     _, _, f = w.shape
@@ -48,7 +51,7 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, bm, bn), lambda ee, i, j, kk: (ee, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, cap, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
     )(x, w)

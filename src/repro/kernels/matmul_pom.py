@@ -15,12 +15,14 @@ budget, keep MXU dims 128-aligned).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import pallas_interpret
 
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
@@ -38,7 +40,7 @@ def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
 
 def matmul(x: jnp.ndarray, y: jnp.ndarray, *,
            bm: int = 128, bn: int = 128, bk: int = 128,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: (M, K) @ y: (K, N) -> (M, N); shapes padded to block multiples."""
     m, k = x.shape
     k2, n = y.shape
@@ -60,7 +62,7 @@ def matmul(x: jnp.ndarray, y: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(xp, yp)

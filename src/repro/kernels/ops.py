@@ -1,10 +1,11 @@
 """Public jit'd wrappers over the Pallas kernels (the ``ops.py`` contract).
 
 Every op takes ``schedule='pom' | 'naive'`` (POM-DSE block shapes vs fixed
-defaults) and ``impl='pallas' | 'ref'``.  On this CPU container the models
-default to ``impl='ref'`` (pure jnp -- XLA fuses it well and the multi-pod
-dry-run can compile it); on real TPU the launcher flips to ``impl='pallas'``
-with ``interpret=False``.
+defaults) and ``impl='pallas' | 'ref'``.  The models pick ``impl='pallas'``
+when their config sets ``use_pallas`` and ``impl='ref'`` (pure jnp)
+otherwise.  ``interpret=None`` leaves the choice to
+``repro.runtime.pallas_interpret()``: compiled on an accelerator,
+interpreted on the CPU.
 """
 from __future__ import annotations
 
@@ -27,7 +28,7 @@ Impl = str  # 'pallas' | 'ref'
 
 
 def matmul(x, y, *, schedule: str = "pom", impl: Impl = "ref",
-           interpret: bool = True):
+           interpret: Optional[bool] = None):
     if impl == "ref":
         return ref.matmul(x, y)
     m, k = x.shape
@@ -42,7 +43,7 @@ def matmul(x, y, *, schedule: str = "pom", impl: Impl = "ref",
 
 
 def attention(q, k, v, *, causal: bool = True, schedule: str = "pom",
-              impl: Impl = "ref", interpret: bool = True):
+              impl: Impl = "ref", interpret: Optional[bool] = None):
     if impl == "ref":
         return ref.attention(q, k, v, causal=causal)
     sq, skv, d = q.shape[2], k.shape[2], q.shape[3]
@@ -57,7 +58,7 @@ def attention(q, k, v, *, causal: bool = True, schedule: str = "pom",
 
 
 def decode_attention(q, k, v, *, length=None, schedule: str = "pom",
-                     impl: Impl = "ref", interpret: bool = True):
+                     impl: Impl = "ref", interpret: Optional[bool] = None):
     if impl == "ref":
         return ref.decode_attention(q, k, v, length=length)
     skv, d = k.shape[2], q.shape[2]
@@ -71,7 +72,7 @@ def decode_attention(q, k, v, *, length=None, schedule: str = "pom",
 
 
 def ssm_scan(x, a, b, c, *, schedule: str = "pom", impl: Impl = "ref",
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     if impl == "ref_chunked":
         # chunked pure-jnp path, python-unrolled (dry-run cost extraction)
         return ref.ssm_scan_chunked(x, a, b, c, unroll=True)
@@ -86,14 +87,14 @@ def ssm_scan(x, a, b, c, *, schedule: str = "pom", impl: Impl = "ref",
     return _ssm_pallas(x, a, b, c, chunk=chunk, interpret=interpret)
 
 
-def jacobi2d(x, steps: int = 1, *, impl: Impl = "ref", interpret: bool = True):
+def jacobi2d(x, steps: int = 1, *, impl: Impl = "ref", interpret: Optional[bool] = None):
     if impl == "ref":
         return ref.jacobi2d(x, steps)
     return _jacobi_pallas(x, steps, interpret=interpret)
 
 
 def grouped_matmul(x, w, *, schedule: str = "pom", impl: Impl = "ref",
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     if impl == "ref":
         return ref.grouped_matmul(x, w)
     e, cap, d = x.shape

@@ -17,12 +17,14 @@ with cum = inclusive cumsum(log a); a in (0, 1] keeps all exponents <= 0.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import pallas_interpret
 
 
 def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
@@ -71,7 +73,7 @@ def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
 
 
 def ssm_scan(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray,
-             *, chunk: int = 128, interpret: bool = True
+             *, chunk: int = 128, interpret: Optional[bool] = None
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B,S,H,P), a: (B,S,H), b/c: (B,S,H,N) -> (y (B,S,H,P), h (B,H,N,P))."""
     B, S, H, P = x.shape
@@ -105,7 +107,7 @@ def ssm_scan(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray,
             jax.ShapeDtypeStruct((B * H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(xf, af, bf, cf)

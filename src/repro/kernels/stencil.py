@@ -9,11 +9,14 @@ edge -- the BlockSpec rendition of `array_partition` with ghost zones.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import pallas_interpret
 
 
 def _jacobi_kernel(up_ref, c_ref, dn_ref, o_ref, *, bm: int, m: int, n: int):
@@ -35,7 +38,7 @@ def _jacobi_kernel(up_ref, c_ref, dn_ref, o_ref, *, bm: int, m: int, n: int):
 
 
 def jacobi2d_step(x: jnp.ndarray, *, bm: int = 128,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """One Jacobi sweep over (M, N); boundary cells pass through."""
     m, n = x.shape
     bm = min(bm, m)
@@ -52,14 +55,14 @@ def jacobi2d_step(x: jnp.ndarray, *, bm: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(x, x, x)
 
 
 def jacobi2d(x: jnp.ndarray, steps: int = 1, *, bm: int = 128,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret: Optional[bool] = None) -> jnp.ndarray:
     for _ in range(steps):
         x = jacobi2d_step(x, bm=bm, interpret=interpret)
     return x

@@ -1,22 +1,114 @@
 """Serving driver: batched prefill + decode with a KV cache.
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm_360m \\
-      --batch 4 --prompt-len 32 --gen 32
+      --batch 4 --prompt-len 32 --gen 32 [--mesh 4x1] [--reduced]
+
+Serves the published width by default; ``--reduced`` serves the CPU-sized
+config of the same family.  ``--mesh DATAxMODEL`` partitions the step over
+DATA x MODEL devices by the logical-axis rules of ``distributed.partition``,
+as the train launcher does: requests over DATA, heads, MLP and vocab over
+MODEL.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import dataclasses
 import time
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ParallelConfig, get_config, reduced
-from repro.distributed import step as step_mod
-from repro.distributed.sharding import current, use_mesh
+from repro.configs.base import ModelConfig, get_config, reduced
+from repro.distributed.partition import (cache_logical_axes,
+                                         logical_to_sharding,
+                                         param_logical_axes)
+from repro.distributed.sharding import MeshContext, use_mesh
 from repro.launch.mesh import make_mesh
-from repro.models import decode_step, forward, init_cache, init_params
+from repro.models import decode_step, init_cache, init_params
+from repro.runtime import enable_compile_cache, pallas_interpret
+
+# the decode kernel walks the cache in blocks of 128 rows
+CACHE_BLOCK = 128
+
+
+@dataclasses.dataclass
+class Served:
+    logits: jnp.ndarray     # (B, prompt_len + gen - 1, V) fp32, one row per step
+    tokens: jnp.ndarray     # (B, gen) greedy generations
+    setup_s: float          # decode-step compile
+    prefill_s: float
+    decode_s: float
+    compiled: Any           # the compiled decode step (``.as_text()``: HLO)
+
+
+def step_shardings(cfg: ModelConfig, mc: MeshContext, params, cache,
+                   batch: int):
+    """NamedShardings of the decode step's arguments on ``mc``'s mesh:
+    ``(params, cache, per-request vector)``.  ``params`` and ``cache`` may
+    be arrays or shapes; a mesh axis that does not divide a dimension is
+    dropped from it."""
+    return (logical_to_sharding(param_logical_axes(cfg), mc, params),
+            logical_to_sharding(cache_logical_axes(cfg), mc, cache),
+            logical_to_sharding(("batch",), mc,
+                                jax.ShapeDtypeStruct((batch,), jnp.int32)))
+
+
+def serve(cfg: ModelConfig, params, prompts: jnp.ndarray, gen: int,
+          mesh: Optional[Mesh] = None) -> Served:
+    """Teacher-forced prefill of ``prompts`` (B, S) through the decode path,
+    then ``gen`` greedy tokens, every step through the KV cache.
+
+    With a ``mesh`` the step runs under ``use_mesh``: parameters, cache and
+    requests are placed by ``step_shardings`` and GSPMD partitions the
+    step (the Pallas decode kernel is ``shard_map``'d in the model)."""
+    b, plen = prompts.shape
+    max_seq = -(-(plen + gen) // CACHE_BLOCK) * CACHE_BLOCK
+    cache = init_cache(cfg, b, max_seq)
+    cols = prompts.T                  # cols[t]: every request's t-th token
+    pos = jnp.zeros((b,), jnp.int32)
+    step = jax.jit(lambda p, c, t, q: decode_step(p, cfg, c, t, q),
+                   donate_argnums=(1,))
+
+    with use_mesh(mesh) if mesh is not None else contextlib.nullcontext() \
+            as mc:
+        if mc is not None:
+            param_sh, cache_sh, row_sh = step_shardings(cfg, mc, params,
+                                                        cache, b)
+            params = jax.device_put(params, param_sh)
+            cache = jax.device_put(cache, cache_sh)
+            pos = jax.device_put(pos, row_sh)
+            cols = jax.device_put(cols, NamedSharding(
+                mc.mesh, P(None, *row_sh.spec)))
+        tok = cols[0]
+
+        t0 = time.perf_counter()
+        compiled = step.lower(params, cache, tok, pos).compile()
+        setup_s = time.perf_counter() - t0
+
+        rows = []
+        t0 = time.perf_counter()
+        for t in range(plen):
+            logits, cache = step(params, cache, cols[t], pos + t)
+            rows.append(logits)
+        jax.block_until_ready(logits)
+        prefill_s = time.perf_counter() - t0
+
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        out = [tok]
+        t0 = time.perf_counter()
+        for t in range(plen, plen + gen - 1):
+            logits, cache = step(params, cache, tok, pos + t)
+            rows.append(logits)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out.append(tok)
+        jax.block_until_ready(tok)
+        decode_s = time.perf_counter() - t0
+    return Served(jnp.stack(rows, axis=1), jnp.stack(out, axis=1),
+                  setup_s, prefill_s, decode_s, compiled)
 
 
 def main():
@@ -25,51 +117,37 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
-    ap.add_argument("--mesh", default="1x1")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--mesh", default="1x1", help="DATAxMODEL, e.g. 4x1")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the reduced config (CPU-sized)")
     args = ap.parse_args()
 
-    cfg = reduced(get_config(args.arch)) if args.reduced else get_config(args.arch)
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg)
+    # decode attention runs the Pallas kernel wherever it compiles
+    cfg = dataclasses.replace(cfg, use_pallas=not pallas_interpret())
     d, m = (int(x) for x in args.mesh.split("x"))
     mesh = make_mesh((d, m), ("data", "model"))
-    b, pl, g = args.batch, args.prompt_len, args.gen
-    max_seq = pl + g
+    b, plen, g = args.batch, args.prompt_len, args.gen
 
     rng = np.random.default_rng(0)
-    prompts = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, pl)), jnp.int32)
+    prompts = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, plen)), jnp.int32)
+    params = jax.jit(lambda k: init_params(k, cfg))(jax.random.key(0))
+    res = serve(cfg, params, prompts, g, mesh=mesh)
 
-    with use_mesh(mesh):
-        params = init_params(jax.random.key(0), cfg)
-        cache = init_cache(cfg, b, max_seq)
-        step = jax.jit(lambda p, c, t, q: decode_step(p, cfg, c, t, q))
-
-        # teacher-forced prefill through the decode path (exercises the
-        # cache exactly like production chunked prefill with chunk=1)
-        t0 = time.time()
-        for t in range(pl):
-            logits, cache = step(params, cache, prompts[:, t],
-                                 jnp.full((b,), t, jnp.int32))
-        prefill_s = time.time() - t0
-
-        # greedy generation
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out_tokens = [tok]
-        t0 = time.time()
-        for t in range(pl, pl + g - 1):
-            logits, cache = step(params, cache, tok,
-                                 jnp.full((b,), t, jnp.int32))
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out_tokens.append(tok)
-        jax.block_until_ready(tok)
-        decode_s = time.time() - t0
-
-        gen = np.stack([np.asarray(t) for t in out_tokens], axis=1)
-        print(f"arch={cfg.name} batch={b} prompt={pl} gen={g}")
-        print(f"prefill: {prefill_s:.2f}s ({b * pl / max(prefill_s, 1e-9):.0f} tok/s)")
-        print(f"decode:  {decode_s:.2f}s ({b * (g - 1) / max(decode_s, 1e-9):.0f} tok/s)")
-        print("sample generations (token ids):")
-        for i in range(min(b, 2)):
-            print(f"  [{i}]", gen[i, :16].tolist())
+    gen = np.asarray(res.tokens)
+    print(f"arch={cfg.name} batch={b} prompt={plen} gen={g} "
+          f"device={jax.devices()[0].device_kind} x{mesh.size}")
+    print(f"compile: {res.setup_s:.2f}s")
+    print(f"prefill: {res.prefill_s:.2f}s "
+          f"({b * plen / max(res.prefill_s, 1e-9):.0f} tok/s)")
+    print(f"decode:  {res.decode_s:.2f}s "
+          f"({b * (g - 1) / max(res.decode_s, 1e-9):.0f} tok/s)")
+    print("sample generations (token ids):")
+    for i in range(min(b, 2)):
+        print(f"  [{i}]", gen[i, :16].tolist())
 
 
 if __name__ == "__main__":

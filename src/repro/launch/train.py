@@ -29,6 +29,7 @@ from repro.distributed.sharding import current, use_mesh
 from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.optim import adamw_init
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -49,6 +50,7 @@ def main():
     ap.set_defaults(reduced=True)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

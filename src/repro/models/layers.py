@@ -14,8 +14,10 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import current
 from repro.kernels import ops
 
 Params = Dict
@@ -177,13 +179,41 @@ def attention_decode(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     cache_v = jnp.where(onehot, v.astype(cache_v.dtype), cache_v)
     length = pos + 1
     if cfg.use_pallas:
-        o = ops.decode_attention(q[:, :, 0, :], cache_k, cache_v,
-                                 length=length, impl="pallas")
+        o = _decode_kernel(q[:, :, 0, :], cache_k, cache_v, length)
     else:
         o = ops.decode_attention(q[:, :, 0, :], cache_k, cache_v,
                                  length=length, impl="ref")
     o = o.reshape(b, 1, cfg.num_heads * hd)
     return o @ p["wo"], cache_k, cache_v
+
+
+def _decode_kernel(q, k, v, length):
+    """The Pallas decode kernel, partitioned by hand over the active mesh.
+
+    GSPMD cannot partition a Pallas kernel: compiled, JAX refuses it
+    ("Mosaic kernels cannot be automatically partitioned"); interpreted,
+    GSPMD gathers the whole cache to every device.  So under a mesh the
+    kernel is ``shard_map``'d: rows over the batch axes, KV heads (and
+    their query heads, a whole GQA group each) over the model axis, each
+    only where it divides."""
+    mc = current()
+    if mc is None:
+        return ops.decode_attention(q, k, v, length=length, impl="pallas")
+    bat, hds = mc.spec(("batch", "kv_heads"))
+
+    def size(ax):
+        axes = () if ax is None else (ax,) if isinstance(ax, str) else ax
+        return int(np.prod([mc.mesh.shape[a] for a in axes]))
+
+    bat = bat if q.shape[0] % size(bat) == 0 else None
+    hds = hds if k.shape[1] % size(hds) == 0 else None
+    kv = P(bat, hds, None, None)
+    return jax.shard_map(
+        lambda q, k, v, n: ops.decode_attention(q, k, v, length=n,
+                                                impl="pallas"),
+        mesh=mc.mesh, in_specs=(P(bat, hds, None), kv, kv, P(bat)),
+        out_specs=P(bat, hds, None),
+        check_vma=False)(q, k, v, length)  # pallas_call outputs carry no vma
 
 
 # --------------------------------------------------------------------------

@@ -56,7 +56,6 @@ def check_train_step_sharded():
 
 def check_compressed_psum():
     """int8+EF compressed all-reduce ~ exact psum; EF shrinks the error."""
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.compression import compressed_psum
 
     devs = np.array(jax.devices()[:8])
@@ -69,8 +68,8 @@ def check_compressed_psum():
         g, r = compressed_psum(xs[0], rs[0], "data")
         return g[None], r[None]
 
-    fm = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=(P("data"), P("data")))
+    fm = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                       out_specs=(P("data"), P("data")))
     got, resid = fm(x, jnp.tile(r0[None], (8, 1, 1)))
     want = jnp.sum(x, axis=0)
     err = float(jnp.max(jnp.abs(got[0] - want)) / (jnp.max(jnp.abs(want)) + 1e-9))
@@ -125,6 +124,31 @@ def check_decode_sp_longcontext():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)
     print("OK check_decode_sp_longcontext")
+
+
+def check_serve_mesh():
+    """The serve loop on data, model and 2-D meshes == one device: the
+    same logits (f32) and greedy tokens, the decode kernel shard_map'd."""
+    import dataclasses
+    from repro.configs.base import get_config, reduced
+    from repro.launch.mesh import make_mesh
+    from repro.launch.serve import serve
+    from repro.models import init_params
+    cfg = dataclasses.replace(reduced(get_config("smollm_360m")),
+                              use_pallas=True)
+    params = init_params(jax.random.key(0), cfg)
+    prompts = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (4, 6)), jnp.int32)
+    one = serve(cfg, params, prompts, 4)
+    for shape in ((4, 1), (1, 2), (2, 2)):
+        got = serve(cfg, params, prompts, 4,
+                    mesh=make_mesh(shape, ("data", "model")))
+        np.testing.assert_allclose(np.asarray(got.logits),
+                                   np.asarray(one.logits),
+                                   rtol=1e-5, atol=1e-5, err_msg=str(shape))
+        np.testing.assert_array_equal(np.asarray(got.tokens),
+                                      np.asarray(one.tokens))
+    print("OK check_serve_mesh")
 
 
 def check_pp_gpipe():

@@ -3,7 +3,9 @@ import numpy as np
 import pytest
 
 from repro.core import dsl as pom
-from repro.core.backend_pallas import PallasLowerError, lower_stmt_pallas
+from repro.core.backend_pallas import (PallasLowerError,
+                                      _lower_stmt_pallas_compute,
+                                      lower_stmt_pallas)
 
 
 def _sched_gemm(n=32, ti=8, tj=8, tk=8):
@@ -81,3 +83,59 @@ def test_unsupported_pattern_raises():
         s = pom.compute("s", [i], A(i - 1) + A(i + 1), B(i))
     with pytest.raises(PallasLowerError):
         lower_stmt_pallas(s.stmt)
+
+
+def _untiled_gemm(n):
+    with pom.function("gemm") as f:
+        i, j, k = pom.var("i", 0, n), pom.var("j", 0, n), pom.var("k", 0, n)
+        A = pom.placeholder("A", (n, n))
+        B = pom.placeholder("B", (n, n))
+        C = pom.placeholder("C", (n, n))
+        s = pom.compute("s", [i, j, k], C(i, j) + A(i, k) * B(k, j), C(i, j))
+    return f, s
+
+
+def test_compiled_lowering_refuses_untileable_block():
+    """An untiled nest gives (1, 1) blocks: Mosaic would refuse them, so a
+    compiled lowering raises; the interpreter takes any block."""
+    f, s = _untiled_gemm(256)
+    with pytest.raises(PallasLowerError, match=r"s: .*\(1, 1\).*tiling"):
+        _lower_stmt_pallas_compute(s.stmt, interpret=False)
+    assert callable(_lower_stmt_pallas_compute(s.stmt, interpret=True))
+
+
+@pytest.mark.parametrize("n,t,ok", [(256, 128, True), (256, 8, False),
+                                    (64, 16, False), (64, 64, True)])
+def test_compiled_lowering_tiling_rule(n, t, ok):
+    """Last two block dims: multiples of (8, 128) or the whole array."""
+    f, s = _sched_gemm(n, t, t, t)
+    if ok:
+        assert callable(_lower_stmt_pallas_compute(s.stmt, interpret=False))
+    else:
+        with pytest.raises(PallasLowerError, match="tiling"):
+            _lower_stmt_pallas_compute(s.stmt, interpret=False)
+
+
+def test_compiled_lowering_refuses_rank1_partial_block():
+    n, t = 256, 16
+    with pom.function("mv") as f:
+        i, j = pom.var("i", 0, n), pom.var("j", 0, n)
+        A = pom.placeholder("A", (n, n))
+        p = pom.placeholder("p", (n,))
+        q = pom.placeholder("q", (n,))
+        s = pom.compute("s", [i, j], q(i) + A(i, j) * p(j), q(i))
+    s.split("i", t, "i0", "i1")
+    s.unroll("i1", t)
+    s.unroll("j", n)
+    with pytest.raises(PallasLowerError, match="rank-1 block"):
+        _lower_stmt_pallas_compute(s.stmt, interpret=False)
+
+
+def test_compiled_lowering_refuses_blocks_over_vmem():
+    """Whole-array (4096, 4096) f32 blocks tile, but four of them
+    double-buffered need 512 MiB of VMEM."""
+    f, s = _untiled_gemm(4096)
+    for d in ("i", "j", "k"):
+        s.unroll(d, 4096)
+    with pytest.raises(PallasLowerError, match="VMEM"):
+        _lower_stmt_pallas_compute(s.stmt, interpret=False)

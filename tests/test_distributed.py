@@ -39,6 +39,10 @@ def test_decode_sp_long_context():
     _run("check_decode_sp_longcontext")
 
 
+def test_serve_on_meshes_matches_one_device():
+    _run("check_serve_mesh")
+
+
 def test_pp_gpipe_forward():
     _run("check_pp_gpipe")
 

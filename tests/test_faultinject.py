@@ -12,8 +12,8 @@ Sites covered (``core/faultinject.py``):
   * ``designdb.read`` truncate / bitflip / error and ``designdb.write``
     torn writes — checksum/JSON validation quarantines the entry and the
     design is recomputed.
-  * ``backend.lower`` — compiled Mosaic failure falls back to
-    ``interpret=True`` with a structured warning and a correct result.
+  * ``backend.lower`` — a compiled Mosaic failure raises
+    ``PallasLowerError`` naming the statement; nothing falls back.
 """
 import os
 import warnings
@@ -223,26 +223,27 @@ def test_service_recomputes_after_quarantine(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# backend.lower: Mosaic -> interpret fallback
+# backend.lower: a compiled kernel failure raises (no interpret fallback)
 # --------------------------------------------------------------------------
 def test_backend_lower_falls_back_to_interpret():
     np = pytest.importorskip("numpy")
-    from repro.core.backend_pallas import lower_stmt_pallas
+    from repro.core.backend_pallas import PallasLowerError, lower_stmt_pallas
     f = workloads.gemm(8).fn
     s = f.statements[0]
-    s.unrolls["j"] = 8
+    for d in ("i", "j", "k"):
+        s.unrolls[d] = 8
     arrays = {"A": np.random.rand(8, 8).astype("float32"),
               "B": np.random.rand(8, 8).astype("float32"),
               "C": np.random.rand(8, 8).astype("float32")}
-    ref = arrays["C"] + arrays["A"] @ arrays["B"]
     run = lower_stmt_pallas(s, interpret=False)
     with faultinject.injected("backend.lower", "error", max_fires=1) as spec:
-        with pytest.warns(PomWarning, match="mosaic_fallback_interpret"):
-            out = run(arrays)
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", PomWarning)
+            with pytest.raises(PallasLowerError,
+                               match="^s: .*injected Mosaic lowering"):
+                run(arrays)
     assert spec.fires == 1
+    # the interpret lowering of the same statement is a separate runner
+    ref = arrays["C"] + arrays["A"] @ arrays["B"]
+    out = lower_stmt_pallas(s, interpret=True)(arrays)
     assert np.allclose(np.asarray(out), ref, atol=1e-4)
-    # the runner pins itself to interpret mode: no second warning
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", PomWarning)
-        out2 = run(arrays)
-    assert np.allclose(np.asarray(out2), ref, atol=1e-4)

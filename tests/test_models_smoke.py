@@ -118,3 +118,26 @@ def test_moe_active_params():
     cfg = get_config("llama4_maverick_400b")
     active = cfg.active_param_count()
     assert 10e9 <= active <= 25e9, f"active {active / 1e9:.1f}B vs nameplate 17B"
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_serve_logits_match_forward(use_pallas):
+    """The serve loop (prefill then greedy decode through the KV cache)
+    gives, at every step, the logits a full forward gives over the same
+    tokens; ``use_pallas`` runs the decode-attention kernel."""
+    import dataclasses
+    from repro.launch.serve import serve
+    cfg = dataclasses.replace(reduced(get_config("smollm_360m")),
+                              use_pallas=use_pallas)
+    params = init_params(jax.random.key(4), cfg)
+    rng = np.random.default_rng(4)
+    prompts = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 6)), jnp.int32)
+    res = serve(cfg, params, prompts, 4)
+    assert res.logits.shape == (2, 9, cfg.padded_vocab_size)
+    assert res.tokens.shape == (2, 4)
+    seq = jnp.concatenate([prompts, res.tokens[:, :-1]], axis=1)
+    ref, _ = forward(params, cfg, tokens=seq)
+    np.testing.assert_allclose(np.asarray(res.logits), np.asarray(ref),
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(np.asarray(res.tokens[:, 1:]),
+                                  np.asarray(jnp.argmax(ref[:, 6:], -1)))

@@ -2,15 +2,15 @@
 
 Covers the serving-path stack end to end:
 
-  * the Mosaic probe + ``POM_PALLAS_INTERPRET`` tri-state default and the
-    runner-cache re-keying (a requested-compiled runner that pinned itself
-    to interpret is evicted, so a transient Mosaic failure cannot poison
-    later compiles);
-  * ``PallasProgram``: legacy ``__call__`` parity, whole-program tracing
-    (``jitted()``) on all 13 workloads, ``batched(B)`` equal bit-for-bit
-    to B sequential jitted runs, the sequential fallback for untraceable
-    programs, and compiled-vs-interpret numerical parity (auto-skipped
-    when the host has no Mosaic lowering);
+  * the one interpret decision (``repro.runtime.pallas_interpret``: the
+    CPU backend interprets) and the runner cache keyed by mode; a compiled
+    runner that fails raises, naming the statement, and stays cached;
+  * ``PallasProgram``: interpreted ``__call__`` parity, compiled
+    ``__call__`` running the traced step, whole-program tracing
+    (``jitted()``) on all 13 workloads and on a DSE-split gemm,
+    ``batched(B)`` equal bit-for-bit to B sequential jitted runs,
+    ``TraceError`` for untraceable programs, and compiled-vs-interpret
+    numerical parity (skipped, from a fixture, where Pallas interprets);
   * scan-over-layers: ``graph_ir.detect_scan_chains`` role derivation,
     ``ScanRegion`` loop-IR plumbing (verify, describe, HLS annotation,
     oracle execution), scan == unrolled bit-for-bit, and
@@ -32,11 +32,12 @@ from repro.core import graph_ir
 from repro.core.astbuild import build_ast
 from repro.core.backend_hls import emit_hls
 from repro.core.backend_jax import compile_jax
-from repro.core.backend_pallas import PallasProgram, mosaic_supported
+from repro.core.backend_pallas import PallasProgram, TraceError
 from repro.core.cost_model import HlsModel
 from repro.core.errors import PomWarning
 from repro.core.loop_ir import ScanRegion, describe, walk
 from repro.core.pipeline import compile as pcompile
+from repro.runtime import pallas_interpret
 
 
 @pytest.fixture(autouse=True)
@@ -78,18 +79,19 @@ def _outputs(fn):
 # probe + artifact surface
 # --------------------------------------------------------------------------
 def test_mosaic_probe_is_stable_and_bool():
-    a, b = mosaic_supported(), mosaic_supported()
+    import jax
+    a, b = pallas_interpret(), pallas_interpret()
     assert isinstance(a, bool) and a == b
+    assert a == (jax.default_backend() == "cpu")
 
 
 def test_interpret_env_tristate(monkeypatch):
-    from repro.core import backend_pallas as bp
-    monkeypatch.setenv("POM_PALLAS_INTERPRET", "1")
-    assert bp._interpret_default() is True
+    # the platform decides; no environment variable overrides it
     monkeypatch.setenv("POM_PALLAS_INTERPRET", "0")
-    assert bp._interpret_default() is False
-    monkeypatch.delenv("POM_PALLAS_INTERPRET")
-    assert bp._interpret_default() == (not mosaic_supported())
+    prog = pcompile(workloads.gemm(8).fn, target="pallas")
+    assert prog.interpret is pallas_interpret()
+    assert pcompile(workloads.gemm(8).fn, target="pallas",
+                    interpret=True).interpret is True
 
 
 def test_artifact_is_program_and_legacy_callable():
@@ -101,6 +103,21 @@ def test_artifact_is_program_and_legacy_callable():
     ref = compile_jax(f.fn, build_ast(f.fn))(dict(arrs))
     np.testing.assert_allclose(np.asarray(out["C"], dtype=np.float64),
                                ref["C"], rtol=1e-5, atol=1e-5)
+
+
+def test_compiled_artifact_call_runs_the_traced_step():
+    """Compiled, calling the artifact runs ``jitted()``: the untiled gemm's
+    (1, 1) blocks are refused and the nest vectorizes on the device; no
+    per-statement plan and no host oracle."""
+    f = workloads.gemm(256)
+    prog = pcompile(f.fn, target="pallas", interpret=False)
+    assert prog.mode == "traced" and prog.interpret is False
+    arrs = _inputs(f.fn)
+    out = prog(dict(arrs))
+    assert prog._jit is not None
+    a = {k: np.asarray(v, dtype=np.float64) for k, v in arrs.items()}
+    np.testing.assert_allclose(np.asarray(out["C"], dtype=np.float64),
+                               a["A"] @ a["B"], rtol=1e-4, atol=1e-4)
 
 
 # --------------------------------------------------------------------------
@@ -121,10 +138,14 @@ def test_jitted_matches_oracle(name):
             rtol=1e-4, atol=1e-4, err_msg=f"{name}:{k}")
 
 
-@pytest.mark.skipif(not mosaic_supported(),
-                    reason="host has no compiled Mosaic lowering")
+@pytest.fixture
+def compiled_pallas():
+    if pallas_interpret():
+        pytest.skip("Pallas is interpreted on the CPU backend")
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_compiled_matches_interpret(name):
+def test_compiled_matches_interpret(name, compiled_pallas):
     f = CASES[name]()
     arrs = _inputs(f.fn)
     fi = CASES[name]()
@@ -169,19 +190,46 @@ def test_batched_rejects_wrong_batch():
         br(arrs)
 
 
-def test_untraceable_program_falls_back_sequential():
+def test_untraceable_program_falls_back_sequential(monkeypatch):
+    """No fallback: an untraceable program has no jitted() or batched()
+    executor, and both raise TraceError naming the program and cause."""
+    from repro.core import backend_pallas as bp
+
+    def untraceable(fn, ast, interpret):
+        raise bp.TraceError("no JAX rendition")
+
+    monkeypatch.setattr(bp, "_build_step", untraceable)
     f = workloads.gemm(8)
     prog = pcompile(f.fn, target="pallas", interpret=True)
-    prog._step_ok = False          # force the fallback path
-    br = prog.batched(2)
-    singles = [_inputs(f.fn, seed=s) for s in range(2)]
-    batched = {k: np.stack([s[k] for s in singles]) for k in singles[0]}
-    out = br(batched)
-    for i, s in enumerate(singles):
-        ref = prog(dict(s))
-        np.testing.assert_allclose(np.asarray(out["C"][i]),
-                                   np.asarray(ref["C"]),
-                                   rtol=1e-5, atol=1e-5)
+    assert not prog.traceable()
+    with pytest.raises(TraceError, match="gemm: .*no JAX rendition"):
+        prog.batched(2)
+    with pytest.raises(TraceError, match="gemm"):
+        prog.jitted()
+    # the legacy per-statement path is unaffected
+    ref = prog(dict(_inputs(f.fn)))
+    assert np.asarray(ref["C"]).shape == (8, 8)
+
+
+def test_dse_split_nest_vectorizes():
+    """A DSE-split nest (i = 32*i_o + i_u, bounds min/max of i_o) traces
+    with no loop: its bounds are constant over the enclosing box and the
+    store is a mixed-radix encoding of (i_o, i_u)."""
+    import jax
+    n = 64
+    f = workloads.gemm(n)
+    prog = pcompile(f.fn, target="pallas", interpret=True, dse=True)
+    assert any(" i_u " in line for line in describe(prog.ast).splitlines())
+    spec = {p.name: jax.ShapeDtypeStruct(p.shape, np.float32)
+            for p in f.fn.placeholders.values()}
+    assert prog.traceable()
+    assert "while" not in str(jax.make_jaxpr(prog._step)(spec))
+    arrs = _inputs(f.fn)
+    got = prog.jitted()(dict(arrs))
+    ref = compile_jax(f.fn, build_ast(f.fn))(
+        {k: np.asarray(v, dtype=np.float64) for k, v in arrs.items()})
+    np.testing.assert_allclose(np.asarray(got["C"], dtype=np.float64),
+                               ref["C"], rtol=1e-4, atol=1e-4)
 
 
 def test_service_pallas_runner_caches_executors(tmp_path):
@@ -211,7 +259,7 @@ def test_dsl_runner_shortcut():
 
 
 # --------------------------------------------------------------------------
-# runner cache re-keying on Mosaic pin-to-interpret
+# runner cache keyed by mode; compiled failures raise
 # --------------------------------------------------------------------------
 def _stmt_cache_key(s, mode):
     from repro.core.ir import loads_of
@@ -232,24 +280,28 @@ def test_runner_cache_keys_distinguish_modes():
 
 
 def test_pin_to_interpret_evicts_compiled_cache_entry():
+    """No pin: a compiled runner whose kernel fails raises with the
+    statement's name, never switches to interpret, and keeps its slot."""
     from repro.core import backend_pallas as bp
     from repro.core import faultinject
     f = workloads.gemm(8)
     s = f.fn.statements[0]
-    s.unrolls["j"] = 8
+    for d in ("i", "j", "k"):       # one (8, 8) block per array: tileable
+        s.unrolls[d] = 8
     runner = bp.lower_stmt_pallas(s, interpret=False)
     key = _stmt_cache_key(s, "compiled")
     assert key in bp._PALLAS_RUNNER_CACHE
     arrs = {k: np.asarray(v) for k, v in _inputs(f.fn).items()}
     arrs["C"] = np.zeros((8, 8), dtype=np.float32)
     with faultinject.injected("backend.lower", "error", max_fires=1):
-        with pytest.warns(PomWarning, match="mosaic_fallback_interpret"):
-            runner(arrs)
-    # the pinned runner no longer shadows the compiled key: a later
-    # lower_stmt_pallas(interpret=False) builds a fresh runner
-    assert key not in bp._PALLAS_RUNNER_CACHE
-    fresh = bp.lower_stmt_pallas(s, interpret=False)
-    assert fresh is not runner
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", PomWarning)
+            with pytest.raises(bp.PallasLowerError,
+                               match="^s: compiled Pallas kernel failed"):
+                runner(arrs)
+    assert key in bp._PALLAS_RUNNER_CACHE
+    assert _stmt_cache_key(s, "interpret") not in bp._PALLAS_RUNNER_CACHE
+    assert bp.lower_stmt_pallas(s, interpret=False) is runner
 
 
 # --------------------------------------------------------------------------
