@@ -41,6 +41,9 @@ from .ir import BinOp, Call, Const, Expr, Function, IterVal, Load, Placeholder, 
 from .ir import loads_of
 from . import faultinject, telemetry
 
+# every XLA compile of the program's steps counts in ``xla.compiles``
+telemetry.watch_xla()
+
 
 class PallasLowerError(Exception):
     pass
@@ -329,6 +332,7 @@ def _lower_stmt_pallas_compute(stmt: Statement, interpret: bool,
                                        idx_fn(out_spec.index_map_exprs)),
                 out_shape=jax.ShapeDtypeStruct(shape, dtype),
                 interpret=interpret,
+                name=stmt.name,
             )
             call_cache[ck] = fn
         return fn
@@ -674,13 +678,16 @@ def _build_step(fn: Function, ast, interpret: bool):
             if not interpret:
                 runner = _nest_pallas_runner(node, env)
                 if runner is not None:
-                    dest, run = runner
+                    name, dest, run = runner
                     bufs = dict(bufs)
-                    bufs[dest] = run(bufs)
+                    with jax.named_scope(name):
+                        bufs[dest] = run(bufs)
                     return bufs
             plan = _vec_plan(node)
             if plan is not None:
-                return _run_vectorized(plan, bufs, env)
+                _, sn, *_ = plan
+                with jax.named_scope(sn.stmt.name):
+                    return _run_vectorized(plan, bufs, env)
             lo = _bound_val(node.lo, env)
             hi = _bound_val(node.hi, env)
 
@@ -706,7 +713,8 @@ def _build_step(fn: Function, ast, interpret: bool):
                             lambda b: run_nodes(node.body, b, env),
                             lambda b: b, bufs)
         if isinstance(node, StmtNode):
-            return _exec_stmt_scalar(node, bufs, env)
+            with jax.named_scope(node.stmt.name):
+                return _exec_stmt_scalar(node, bufs, env)
         raise TraceError(f"unknown node {type(node).__name__}")
 
     def _nest_pallas_runner(node, env):
@@ -729,7 +737,7 @@ def _build_step(fn: Function, ast, interpret: bool):
         except PallasLowerError:
             return None
         arr, _ = s.store_access()
-        return arr.name, run
+        return s.name, arr.name, run
 
     def run_scan(node, bufs, env):
         if env:  # a scan region nested under live loops: run unrolled
@@ -815,11 +823,13 @@ class BatchedRunner:
         return self._fn.lower(self._bufs(arrays))
 
     def __call__(self, arrays: Dict[str, Any]) -> Dict[str, Any]:
-        bufs = self._bufs(arrays)
         with telemetry.span("backend.execute", _cat="backend",
                             backend="pallas_batched",
-                            fn=self.program.fn.name,
-                            batch=next(iter(bufs.values())).shape[0]):
+                            fn=self.program.fn.name) as sp:
+            with telemetry.span("backend.bufs", _cat="backend"):
+                bufs = self._bufs(arrays)
+            if sp:
+                sp.add(batch=next(iter(bufs.values())).shape[0])
             return self._fn(bufs)
 
 
@@ -921,7 +931,9 @@ class PallasProgram:
             def run(arrays: Dict[str, Any]) -> Dict[str, Any]:
                 with telemetry.span("backend.execute", _cat="backend",
                                     backend="pallas_jit", fn=self.fn.name):
-                    return jfn(self._full_bufs(arrays))
+                    with telemetry.span("backend.bufs", _cat="backend"):
+                        bufs = self._full_bufs(arrays)
+                    return jfn(bufs)
 
             run.lower = lambda arrays: jfn.lower(self._full_bufs(arrays))
             self._jit = run

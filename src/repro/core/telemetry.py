@@ -1,7 +1,8 @@
 """Unified telemetry: structured span tracing + the metrics registry.
 
 POM's pitch is that multi-level IR makes optimization *debuggable*; this
-module is where the engine explains itself.  Two zero-dependency pieces:
+module is where the engine explains itself.  Two pieces, neither of which
+imports anything outside the standard library until a trace starts:
 
 **Span tracing** — ``telemetry.span("stage2.rung", statement="s", P=4)``
 is a context manager that records one timed event; ``telemetry.event``
@@ -22,6 +23,15 @@ Worker processes appear as separate tracks: workers are forked, so
 sides one clock base, and each worker's events ride back to the parent
 on the existing candidate-result replies — no re-alignment needed.
 
+While a session is active every span is also a
+``jax.profiler.TraceAnnotation``, so a JAX profile taken meanwhile shows
+it on its ``/host:CPU`` plane, on the clock of PJRT's own host events.
+XLA's compile stages, which JAX announces through ``jax.monitoring``,
+are recorded as ``xla.trace`` / ``xla.lower`` / ``xla.compile`` spans
+(``fun=`` the function) while a session is active, and every backend
+compile or persistent-cache load counts in the ``xla.compiles`` counter
+whether or not one is (``watch_xla``).
+
 **Strictly pay-for-use**: with tracing off, ``span()`` returns one
 shared no-op object (no allocation, no timestamp read) and ``event()``
 is a single ``is None`` check.  Tracing records *observations only* —
@@ -30,7 +40,7 @@ it never issues analysis queries — so every bit-identity invariant
 tracing on or off; ``tests/test_perf_smoke.py`` pins the counter
 parity.
 
-**Metrics registry** — named counters / gauges / histograms unifying
+**Metrics registry** — named counters and histograms unifying
 what used to be ad-hoc dicts: ``cost_model.CostStats``, the beam's
 ``wave_stats``, ``designdb.DbStats``, warm-pool health, and
 ``CompileService`` request latencies (p50/p99).  ``pom.metrics()``
@@ -45,13 +55,13 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 __all__ = [
     "span", "event", "on", "warning", "metrics", "dump_stream",
     "start_trace", "stop_trace", "maybe_trace", "export_trace",
-    "buffer_mark", "buffer_delta", "absorb",
-    "counter", "gauge", "histogram", "REGISTRY", "Registry",
+    "buffer_mark", "buffer_delta", "absorb", "watch_xla",
+    "counter", "histogram", "REGISTRY", "Registry",
 ]
 
 
@@ -86,11 +96,12 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records a Chrome 'X' (complete) event on exit.
+    """One live span: records a Chrome 'X' (complete) event on exit, and
+    is mirrored by the tracer's profiler annotation while it is open.
 
     ``add(**args)`` attaches arguments discovered mid-span (eval-count
     deltas, accept/reject outcomes) — the recorded event carries them."""
-    __slots__ = ("tracer", "name", "cat", "args", "t0")
+    __slots__ = ("tracer", "name", "cat", "args", "t0", "mirror")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -101,12 +112,15 @@ class _Span:
         self.t0 = 0.0
 
     def __enter__(self):
+        self.mirror = self.tracer.annotate(self.name)
+        self.mirror.__enter__()
         self.t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
         self.tracer._record(self.name, self.cat, self.t0,
                             _now_us() - self.t0, self.args)
+        self.mirror.__exit__(*exc)
         return False
 
     def __bool__(self):
@@ -119,10 +133,14 @@ class _Span:
 
 class Tracer:
     """Event buffer + export for one trace session (usually the process;
-    forked workers inherit it and ship their buffer deltas back)."""
+    forked workers inherit it and ship their buffer deltas back).
 
-    def __init__(self, dest: str):
+    ``annotate(name)`` gives the context manager that mirrors a span onto
+    a profiler's timeline (``jax.profiler.TraceAnnotation``)."""
+
+    def __init__(self, dest: str, annotate: Callable):
         self.dest = dest
+        self.annotate = annotate
         self.events: List[dict] = []
         self.t_start = _now_us()
 
@@ -242,11 +260,14 @@ def warning(component: str, event_name: str, message: str,
 def start_trace(dest: str) -> Tracer:
     """Begin a trace session writing to ``dest`` (a path, or ``-`` for
     the stdout tree summary).  One session per process; starting while
-    one is active is an error (use :func:`maybe_trace` to join)."""
+    one is active is an error (use :func:`maybe_trace` to join).  Its
+    spans are mirrored onto any JAX profile taken while it is active."""
     global _TRACER
     if _TRACER is not None:
         raise RuntimeError("a trace session is already active")
-    _TRACER = Tracer(dest)
+    watch_xla()
+    from jax.profiler import TraceAnnotation
+    _TRACER = Tracer(dest, annotate=TraceAnnotation)
     return _TRACER
 
 
@@ -294,6 +315,45 @@ class _MaybeTrace:
 
 def maybe_trace(trace_path: Optional[str] = None) -> _MaybeTrace:
     return _MaybeTrace(trace_path)
+
+
+# --------------------------------------------------------------------------
+# XLA compile stages (jax.monitoring time spans)
+# --------------------------------------------------------------------------
+_XLA_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla.lower",
+    # wraps compile_or_get_cached: a persistent-cache load fires it too
+    "/jax/core/compile/backend_compile_duration": "xla.compile",
+}
+_XLA_WATCHED = False
+
+
+def _on_xla_span(event: str, start: float, end: float, **kwargs) -> None:
+    name = _XLA_SPANS.get(event)
+    if name is None:
+        return
+    if name == "xla.compile":
+        REGISTRY.counter("xla.compiles").inc()
+    t = _TRACER
+    if t is not None:
+        # the listener's endpoints are time.time(); it is called as the
+        # stage ends, so one reading of both clocks maps them over
+        shift = _now_us() - time.time() * 1e6
+        t._record(name, "xla", start * 1e6 + shift, (end - start) * 1e6,
+                  {"fun": kwargs.get("fun_name")})
+
+
+def watch_xla() -> None:
+    """Listen, once per process, to JAX's compile-stage time spans: count
+    every backend compile or cache load in ``xla.compiles`` and, while a
+    session is active, record each stage as an ``xla.*`` span."""
+    global _XLA_WATCHED
+    if _XLA_WATCHED:
+        return
+    import jax.monitoring
+    jax.monitoring.register_event_time_span_listener(_on_xla_span)
+    _XLA_WATCHED = True
 
 
 # --------------------------------------------------------------------------
@@ -354,16 +414,6 @@ class Counter:
         self.value += n
 
 
-class Gauge:
-    __slots__ = ("value",)
-
-    def __init__(self):
-        self.value = 0.0
-
-    def set(self, v: float) -> None:
-        self.value = v
-
-
 class Histogram:
     """Streaming histogram: exact count/sum/min/max, quantiles over a
     bounded sample window (plenty for request-latency p50/p99)."""
@@ -405,12 +455,11 @@ class Histogram:
 
 
 class Registry:
-    """Named counters/gauges/histograms with one JSON-ready snapshot —
+    """Named counters and histograms with one JSON-ready snapshot —
     the shared schema ``bench_*`` and CI consume instead of ad-hoc dicts."""
 
     def __init__(self):
         self._counters: Dict[str, Counter] = {}
-        self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
 
     def counter(self, name: str) -> Counter:
@@ -418,12 +467,6 @@ class Registry:
         if c is None:
             c = self._counters[name] = Counter()
         return c
-
-    def gauge(self, name: str) -> Gauge:
-        g = self._gauges.get(name)
-        if g is None:
-            g = self._gauges[name] = Gauge()
-        return g
 
     def histogram(self, name: str) -> Histogram:
         h = self._histograms.get(name)
@@ -439,20 +482,17 @@ class Registry:
         return {
             "counters": {n: c.value
                          for n, c in sorted(self._counters.items())},
-            "gauges": {n: g.value for n, g in sorted(self._gauges.items())},
             "histograms": {n: h.to_json()
                            for n, h in sorted(self._histograms.items())},
         }
 
     def reset(self) -> None:
         self._counters.clear()
-        self._gauges.clear()
         self._histograms.clear()
 
 
 REGISTRY = Registry()
 counter = REGISTRY.counter
-gauge = REGISTRY.gauge
 histogram = REGISTRY.histogram
 
 

@@ -1,8 +1,13 @@
 """POM schedule -> Pallas lowering, validated in interpret mode vs oracles."""
+import glob
+import os
+
+import jax
 import numpy as np
 import pytest
 
 from repro.core import dsl as pom
+from repro.core import telemetry
 from repro.core.backend_pallas import (PallasLowerError,
                                       _lower_stmt_pallas_compute,
                                       lower_stmt_pallas)
@@ -139,3 +144,71 @@ def test_compiled_lowering_refuses_blocks_over_vmem():
         s.unroll(d, 4096)
     with pytest.raises(PallasLowerError, match="VMEM"):
         _lower_stmt_pallas_compute(s.stmt, interpret=False)
+
+
+def _jitted_gemm(n=32):
+    from repro.core.pipeline import compile as pom_compile
+    f, _ = _untiled_gemm(n)
+    run = pom_compile(f, target="pallas").jitted()
+    rng = np.random.default_rng(2)
+    arrays = {k: rng.normal(size=(n, n)).astype(np.float32) for k in "ABC"}
+    return run, arrays
+
+
+@pytest.fixture
+def session():
+    t = telemetry.start_trace(os.devnull)
+    yield t
+    if telemetry.on():
+        telemetry.stop_trace(export=False)
+
+
+def test_call_spans_reach_the_profilers_host_plane(tmp_path, session):
+    """Under a session, one ``jitted()`` call puts ``backend.execute``,
+    with ``backend.bufs`` inside it, on the profile's /host:CPU plane."""
+    from jax.profiler import ProfileData
+    run, arrays = _jitted_gemm()
+    jax.block_until_ready(run(arrays))                  # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(run(arrays))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"]
+    events = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+              for p in host for line in p.lines for e in line.events
+              if e.name.startswith("backend.")}
+    (x0, x1), (b0, b1) = events["backend.execute"], events["backend.bufs"]
+    assert x0 <= b0 < b1 <= x1 and b1 - b0 < x1 - x0
+
+
+def test_first_call_records_and_counts_xla_compiles(session):
+    """The first call's compile is an ``xla.compile`` span naming its
+    function, nested in ``backend.execute``, and counts in
+    ``xla.compiles``; a second call with the same shapes compiles
+    nothing."""
+    compiles = telemetry.REGISTRY.counter("xla.compiles")
+    run, arrays = _jitted_gemm()
+    c0 = compiles.value
+    jax.block_until_ready(run(arrays))
+    c1 = compiles.value
+    jax.block_until_ready(run(arrays))
+    assert c1 > c0 and compiles.value == c1
+    events = session.events
+    (first, _) = [e for e in events if e["name"] == "backend.execute"]
+    xla = [e for e in events if e["name"] == "xla.compile"
+           and first["ts"] <= e["ts"]
+           and e["ts"] + e["dur"] <= first["ts"] + first["dur"]]
+    assert xla and all(e["args"]["fun"] for e in xla)
+    assert {"xla.lower", "xla.compile"} <= {e["name"] for e in events}
+
+
+def test_compiles_are_counted_with_no_session():
+    assert not telemetry.on()
+    compiles = telemetry.REGISTRY.counter("xla.compiles")
+    run, arrays = _jitted_gemm(16)
+    c0 = compiles.value
+    jax.block_until_ready(run(arrays))
+    assert compiles.value > c0

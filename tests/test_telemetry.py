@@ -111,6 +111,37 @@ def test_disabled_span_is_shared_null_singleton():
     telemetry.event("nobody.listens", field=3)   # no-op without a session
 
 
+def test_profiler_annotations_only_inside_a_session(tmp_path, monkeypatch):
+    """A session mirrors each span as a ``jax.profiler.TraceAnnotation``;
+    with no session the span is the null singleton and none is made."""
+    import jax.profiler
+    made = []
+
+    class Counting:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    with telemetry.span("backend.execute") as sp:
+        assert sp is telemetry._NULL_SPAN
+    assert made == []
+    telemetry.start_trace(str(tmp_path / "t.json"))
+    with telemetry.span("outer"):
+        with telemetry.span("inner"):
+            pass
+    telemetry.stop_trace(export=False)
+    assert made == ["outer", "inner"]
+    with telemetry.span("after"):
+        pass
+    assert made == ["outer", "inner"]
+
+
 def test_start_stop_trace_lifecycle(tmp_path):
     tp = str(tmp_path / "t.json")
     telemetry.start_trace(tp)
@@ -168,13 +199,11 @@ def test_registry_counter_gauge_histogram():
     r = telemetry.Registry()
     r.counter("c").inc()
     r.counter("c").inc(4)
-    r.gauge("g").set(2.5)
     h = r.histogram("h")
     for v in range(100):
         h.observe(float(v))
     snap = r.snapshot()
     assert snap["counters"]["c"] == 5
-    assert snap["gauges"]["g"] == 2.5
     hj = snap["histograms"]["h"]
     assert hj["count"] == 100 and hj["min"] == 0.0 and hj["max"] == 99.0
     assert 40 <= hj["p50"] <= 60 and hj["p99"] >= 90
@@ -193,8 +222,7 @@ def test_histogram_decimation_keeps_exact_count():
 
 def test_pom_metrics_snapshot():
     snap = pom.metrics()
-    assert {"counters", "gauges", "histograms", "caching", "tracing"} \
-        <= set(snap)
+    assert {"counters", "histograms", "caching", "tracing"} <= set(snap)
     assert snap["tracing"]["active"] is False
     json.dumps(snap)                    # snapshot is JSON-serializable
 

@@ -62,6 +62,21 @@ def test_tiled_gemm_4096_compiles_to_a_kernel(one_chip):
     assert _step_text(prog, one_chip).count(KERNEL) == 1
 
 
+def test_kernel_and_its_ops_carry_the_statement_name(one_chip):
+    """The contraction kernel is named for its statement, and the step's
+    ops sit under a named scope of the statement that made them."""
+    f = workloads.gemm(1024)
+    s = f.stmt("s")
+    s.tile("i", "j", 256, 256, "i0", "j0", "i1", "j1")
+    s.split("k", 512, "k0", "k1")
+    s.unroll("i1", 256).unroll("j1", 256).unroll("k1", 512)
+    prog = pcompile(f.fn, target="pallas", interpret=False)
+    (kernel,) = [line for line in _step_text(prog, one_chip).splitlines()
+                 if KERNEL in line]
+    assert kernel.lstrip().startswith("%s.")
+    assert 'op_name="jit(step)/s/' in kernel
+
+
 def test_untiled_gemm_4096_compiles_without_a_kernel(one_chip):
     """(1, 1) blocks would break the tiling: the nest vectorizes instead."""
     prog = pcompile(workloads.gemm(4096).fn, target="pallas",
