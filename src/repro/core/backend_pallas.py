@@ -18,8 +18,9 @@ schedules tiled to the (8, 128) layout reach Mosaic.
 
 ``PallasProgram.jitted()`` / ``batched(B)`` trace the whole loop AST into
 one XLA computation: a nest whose statement lowers as above runs its
-kernel, every other nest is vectorized into gathers and reductions, or
-becomes a ``fori_loop``.  Whether Pallas runs compiled or interpreted is
+kernel, every other nest is vectorized (unit-stride accesses as slices,
+the rest as gathers and scatters, then reductions), or becomes a
+``fori_loop``.  Whether Pallas runs compiled or interpreted is
 ``repro.runtime.pallas_interpret()``: interpreted iff the backend is the CPU.
 """
 from __future__ import annotations
@@ -445,10 +446,15 @@ def _stmt_accesses(sn) -> Tuple:
     return arr, store_idx, by_id
 
 
-def _eval_body(sn, env: Dict, bufs: Dict, by_id: Dict):
-    """Evaluate the statement body over an env of scalars or grid arrays."""
+def _eval_body(sn, env: Dict, bufs: Dict, by_id: Dict,
+               expr: Optional[Expr] = None,
+               loaded: Optional[Dict[int, Any]] = None):
+    """Evaluate the statement body (or its sub-expression ``expr``) over an
+    env of scalars or grid arrays; a load whose id is in ``loaded`` takes
+    that value instead of indexing its array."""
     s = sn.stmt
     ren = sn.dim_map
+    loaded = loaded or {}
 
     def ev(e: Expr):
         if isinstance(e, Const):
@@ -456,6 +462,8 @@ def _eval_body(sn, env: Dict, bufs: Dict, by_id: Dict):
         if isinstance(e, IterVal):
             return _lin_val(s.subst_lin(e.expr).rename(ren), env)
         if isinstance(e, Load):
+            if id(e) in loaded:
+                return loaded[id(e)]
             _, idx = by_id[id(e)]
             return bufs[e.array.name][tuple(_lin_val(x, env) for x in idx)]
         if isinstance(e, BinOp):
@@ -476,7 +484,7 @@ def _eval_body(sn, env: Dict, bufs: Dict, by_id: Dict):
             return fn(*[ev(a) for a in e.args])
         raise TraceError(f"unknown expr {e!r}")
 
-    return ev(s.body)
+    return ev(s.body if expr is None else expr)
 
 
 def _box_const(lb, ranges: Dict[str, Tuple[int, int]]) -> Optional[int]:
@@ -575,10 +583,98 @@ def _vec_plan(node) -> Optional[Tuple]:
     return chain, sn, kept, red, rest_body
 
 
+def _slice_access(idx: Tuple[LinExpr, ...], chain, shape: Tuple[int, ...],
+                  env: Dict) -> Optional[Tuple[List, List[int], List]]:
+    """Classify one access of a vectorized nest for the slice path.
+
+    Each index position must be a *window* (exactly one ``chain`` var with
+    coefficient +1 or -1, plus a constant and outer ``env`` terms) or a
+    *point* (no chain var), and no chain var may index two positions.
+    Returns ``(starts, sizes, axes)`` per position: the first array index
+    the window covers, its extent, and ``(chain axis, sign)`` (None for a
+    point).  Returns None, so the access keeps its index grids, for any
+    other access or when a start known at trace time puts the window
+    outside the array."""
+    where = {v: (ax, lo, hi) for ax, (v, lo, hi) in enumerate(chain)}
+    starts: List = []
+    sizes: List[int] = []
+    axes: List = []
+    for e, dim in zip(idx, shape):
+        vs = [v for v in e.vars() if v in where]
+        if len(vs) > 1:
+            return None
+        if vs:
+            c = e.coeff(vs[0])
+            ax, lo, hi = where[vs[0]]
+            if abs(c) != 1 or any(a and a[0] == ax for a in axes):
+                return None
+            e = e.substitute(vs[0], LinExpr.cst(lo if c > 0 else hi))
+            axes.append((ax, c))
+            sizes.append(hi - lo + 1)
+        else:
+            axes.append(None)
+            sizes.append(1)
+        start = _lin_val(e, env)
+        if sizes[-1] > dim or (isinstance(start, int)
+                               and not 0 <= start <= dim - sizes[-1]):
+            return None
+        starts.append(start)
+    return starts, sizes, axes
+
+
+def _window(buf, starts: List, sizes: List[int]):
+    """The window of ``buf`` at ``starts``: ``lax.slice`` when every start
+    is known at trace time, else ``lax.dynamic_slice``."""
+    if all(isinstance(s, int) for s in starts):
+        return lax.slice(buf, starts, [s + z for s, z in zip(starts, sizes)])
+    return lax.dynamic_slice(buf, starts, sizes)
+
+
+def _load_window(buf, acc, chain):
+    """A slice access's values shaped like its index-grid gather: one axis
+    per chain var in chain order, of size 1 where the access omits it."""
+    starts, sizes, axes = acc
+    w = _window(buf, starts, sizes)
+    rev = [p for p, a in enumerate(axes) if a and a[1] < 0]
+    if rev:
+        w = lax.rev(w, rev)
+    used = [a[0] for a in axes if a]
+    w = w.reshape([sizes[p] for p, a in enumerate(axes) if a])
+    order = sorted(range(len(used)), key=used.__getitem__)
+    if order != list(range(len(used))):
+        w = w.transpose(order)
+    return w.reshape([hi - lo + 1 if ax in used else 1
+                      for ax, (_, lo, hi) in enumerate(chain)])
+
+
+def _store_window(val, acc):
+    """``val`` over the kept chain axes (every one indexes a window
+    position of the store) brought to the store window's array order:
+    the inverse of ``_load_window``."""
+    _, sizes, axes = acc
+    win = [a for a in axes if a]
+    perm = [ax for ax, _ in win]
+    if perm != sorted(perm):
+        val = val.transpose(perm)
+    rev = [k for k, (_, c) in enumerate(win) if c < 0]
+    if rev:
+        val = lax.rev(val, rev)
+    return val.reshape(sizes)
+
+
 def _run_vectorized(plan, bufs: Dict, env: Dict) -> Dict:
-    """Execute a ``_vec_plan`` nest: build per-dim index grids, evaluate
-    the body as one broadcasted expression, reduce over the reduction
-    axes, and scatter into the destination."""
+    """Execute a ``_vec_plan`` nest as one broadcasted expression over the
+    chain's axes, reduced over the reduction axes, then stored.
+
+    An access whose every index position is a window or a point
+    (``_slice_access``) is a rectangular window of its array: a load
+    takes it with ``lax.slice`` / ``lax.dynamic_slice``, and the store
+    writes it back with ``lax.dynamic_update_slice`` (an accumulator adds
+    into the window first, which equals a scatter-add because
+    ``_vec_plan`` proves the store injective).  Every other access indexes
+    its array with broadcast iota grids, a gather, and every other store
+    scatters.  Each access counts once per trace in ``vec.slice_access``
+    or ``vec.gather_access``."""
     chain, sn, kept, red, rest_body = plan
     arr, store_idx, by_id = _stmt_accesses(sn)
     shape = tuple(hi - lo + 1 for _, lo, hi in chain)
@@ -588,58 +684,56 @@ def _run_vectorized(plan, bufs: Dict, env: Dict) -> Dict:
         g = lo + jnp.arange(hi - lo + 1)
         grids[v] = g.reshape((1,) * ax + (len(g),) + (1,) * (nd - 1 - ax))
 
-    # store index arrays over the *kept* axes only
-    kvars = [v for v, _, _ in chain if v in kept]
-    kenv = dict(env)
-    for ax, v in enumerate(kvars):
-        lo = next(l for vv, l, _ in chain if vv == v)
-        hi = next(h for vv, _, h in chain if vv == v)
-        g = lo + jnp.arange(hi - lo + 1)
-        kenv[v] = g.reshape((1,) * ax + (len(g),) + (1,) * (len(kvars) - 1 - ax))
-    sidx = tuple(_lin_val(e, kenv) for e in store_idx)
+    loads = loads_of(rest_body if red else sn.stmt.body)
+    loaded = {}
+    for ld in loads:
+        a, idx = by_id[id(ld)]
+        acc = _slice_access(idx, chain, bufs[a.name].shape, env)
+        if acc is not None:
+            loaded[id(ld)] = _load_window(bufs[a.name], acc, chain)
+    n_slice = len(loaded)
+    n_gather = len(loads) - n_slice
+
+    kchain = [c for c in chain if c[0] in kept]
+    buf = bufs[arr.name]
+    sacc = _slice_access(store_idx, kchain, buf.shape, env)
+    if sacc is None:
+        n_gather += 1
+        # store index arrays over the *kept* axes only
+        kenv = dict(env)
+        for ax, (v, lo, hi) in enumerate(kchain):
+            g = lo + jnp.arange(hi - lo + 1)
+            kenv[v] = g.reshape((1,) * ax + (len(g),)
+                                + (1,) * (len(kchain) - 1 - ax))
+        sidx = tuple(_lin_val(e, kenv) for e in store_idx)
+    else:
+        n_slice += 1
+    telemetry.counter("vec.slice_access").inc(n_slice)
+    telemetry.counter("vec.gather_access").inc(n_gather)
 
     bufs = dict(bufs)
     if red:
         # D = D + sum(rest) over the reduction axes
-        val = _eval_rest(sn, rest_body, grids, bufs, by_id)
+        val = _eval_body(sn, grids, bufs, by_id, rest_body, loaded)
         val = jnp.broadcast_to(val, shape)
         red_axes = tuple(ax for ax, (v, _, _) in enumerate(chain) if v in red)
-        reduced = val.sum(axis=red_axes)
-        bufs[arr.name] = bufs[arr.name].at[sidx].add(
-            reduced.astype(bufs[arr.name].dtype))
+        reduced = val.sum(axis=red_axes).astype(buf.dtype)
+        if sacc is None:
+            bufs[arr.name] = buf.at[sidx].add(reduced)
+        else:
+            starts, sizes, _ = sacc
+            bufs[arr.name] = lax.dynamic_update_slice(
+                buf, _window(buf, starts, sizes)
+                + _store_window(reduced, sacc), starts)
     else:
-        val = _eval_body(sn, grids, bufs, by_id)
-        val = jnp.broadcast_to(val, shape)
-        bufs[arr.name] = bufs[arr.name].at[sidx].set(
-            val.astype(bufs[arr.name].dtype))
+        val = _eval_body(sn, grids, bufs, by_id, loaded=loaded)
+        val = jnp.broadcast_to(val, shape).astype(buf.dtype)
+        if sacc is None:
+            bufs[arr.name] = buf.at[sidx].set(val)
+        else:
+            bufs[arr.name] = lax.dynamic_update_slice(
+                buf, _store_window(val, sacc), sacc[0])
     return bufs
-
-
-def _eval_rest(sn, rest: Expr, env: Dict, bufs: Dict, by_id: Dict):
-    """Evaluate the non-accumulator side of ``D = D + rest``."""
-    s = sn.stmt
-    ren = sn.dim_map
-
-    def ev(e: Expr):
-        if isinstance(e, Const):
-            return e.value
-        if isinstance(e, IterVal):
-            return _lin_val(s.subst_lin(e.expr).rename(ren), env)
-        if isinstance(e, Load):
-            _, idx = by_id[id(e)]
-            return bufs[e.array.name][tuple(_lin_val(x, env) for x in idx)]
-        if isinstance(e, BinOp):
-            a, b = ev(e.lhs), ev(e.rhs)
-            return {"+": lambda: a + b, "-": lambda: a - b,
-                    "*": lambda: a * b, "/": lambda: a / b}[e.op]()
-        if isinstance(e, Call):
-            fn = _JNP_CALLS.get(e.fn)
-            if fn is None:
-                raise TraceError(f"unknown call {e.fn}")
-            return fn(*[ev(a) for a in e.args])
-        raise TraceError(f"unknown expr {e!r}")
-
-    return ev(rest)
 
 
 def _exec_stmt_scalar(sn, bufs: Dict, env: Dict) -> Dict:
@@ -655,9 +749,11 @@ def _exec_stmt_scalar(sn, bufs: Dict, env: Dict) -> Dict:
 def _build_step(fn: Function, ast, interpret: bool):
     """Trace the loop AST into ``step(bufs) -> bufs`` (pure, jit-able).
 
-    Statement nests are vectorized where legal; compiled
+    Statement nests are vectorized where legal (``_run_vectorized``:
+    unit-stride accesses as slices and ``dynamic_update_slice``, any other
+    access through index-grid gathers and scatters); compiled
     (``interpret=False``), a nest whose statement lowers to a contraction
-    kernel runs that ``pallas_call`` instead of the generic gather/reduce.
+    kernel runs that ``pallas_call`` instead.
     Raises ``TraceError`` (possibly only at trace time) when some
     construct has no JAX rendition.
     """

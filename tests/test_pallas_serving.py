@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from benchmarks import workloads
+from repro.core import backend_pallas as bp
 from repro.core import caching
 from repro.core import dsl as pom
 from repro.core import graph_ir
@@ -230,6 +231,93 @@ def test_dse_split_nest_vectorizes():
         {k: np.asarray(v, dtype=np.float64) for k, v in arrs.items()})
     np.testing.assert_allclose(np.asarray(got["C"], dtype=np.float64),
                                ref["C"], rtol=1e-4, atol=1e-4)
+
+
+def _reversed_fn(n=12):
+    """-1 coefficients in a load and in a transposed store."""
+    with pom.function("reversed") as f:
+        i, j = pom.var("i", 0, n), pom.var("j", 1, n)
+        A = pom.placeholder("A", (n, n))
+        B = pom.placeholder("B", (n, n))
+        pom.compute("rev", [i, j], 2.0 * A(n - 1 - i, j - 1), B(j, n - 1 - i))
+    return f
+
+
+def _shifted_fn(n=10, steps=3):
+    """Nests under a live time loop whose accesses carry its var ``t``:
+    windows and points with traced starts."""
+    with pom.function("shifted") as f:
+        t, i = pom.var("t", 0, steps), pom.var("i", 0, n)
+        t2, i2 = pom.var("t2", 0, steps), pom.var("i2", 0, n)
+        A = pom.placeholder("A", (steps + n,))
+        B = pom.placeholder("B", (n,))
+        C = pom.placeholder("C", (steps, n))
+        s1 = pom.compute("s1", [t, i], A(t + i) + B(i), C(t, i))
+        s2 = pom.compute("s2", [t2, i2], 0.5 * C(t2, i2) + B(i2), B(i2))
+        s2.after(s1, 0)
+    return f
+
+
+_SLICE_CASES = {
+    "gaussian": (CASES["gaussian"], True),
+    "blur": (CASES["blur"], True),
+    "jacobi2d": (CASES["jacobi2d"], True),
+    "edge_detect": (CASES["edge_detect"], True),
+    "conv": (CASES["conv"], False),          # img(c, y + r, x + s) gathers
+    "reversed": (_reversed_fn, True),
+    "shifted": (_shifted_fn, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SLICE_CASES))
+def test_unit_stride_accesses_lower_to_slices(name):
+    """A vectorized nest's accesses whose every index position is one loop
+    var with coefficient +-1 (plus constants and outer loop vars) or no
+    loop var trace to slices and dynamic_update_slice, with no gather or
+    scatter; a windowed conv access keeps its index grids.  Both equal
+    the oracle."""
+    import jax
+    from repro.core import telemetry
+    make, sliced = _SLICE_CASES[name]
+    f = make()
+    prog = pcompile(f.fn, target="pallas", interpret=False)
+    assert prog.traceable()
+    spec = {p.name: jax.ShapeDtypeStruct(p.shape, np.float32)
+            for p in f.fn.placeholders.values()}
+    gathers = telemetry.counter("vec.gather_access").value
+    jaxpr = str(jax.make_jaxpr(prog._step)(spec))
+    if sliced:
+        assert "gather" not in jaxpr and "scatter" not in jaxpr
+    else:
+        assert "gather" in jaxpr
+        jax.make_jaxpr(bp._build_step(f.fn, prog.ast, False))(spec)
+        assert telemetry.counter("vec.gather_access").value - gathers >= 1
+    arrs = _inputs(f.fn)
+    got = prog.jitted()(dict(arrs))
+    ref = compile_jax(f.fn, build_ast(f.fn))(
+        {k: np.asarray(v, dtype=np.float64) for k, v in arrs.items()})
+    for k in _outputs(f.fn):
+        np.testing.assert_allclose(
+            np.asarray(got[k], dtype=np.float64), ref[k],
+            rtol=1e-4, atol=1e-4, err_msg=f"{name}:{k}")
+
+
+def test_slice_access_counters_per_trace():
+    """One trace of the gaussian step counts its 9 loads and 1 store as
+    slice accesses and none as gather accesses."""
+    import jax
+    from repro.core import telemetry
+    f = workloads.gaussian(14)
+    prog = pcompile(f.fn, target="pallas", interpret=True)
+    step = bp._build_step(f.fn, prog.ast, prog.interpret)
+    spec = {p.name: jax.ShapeDtypeStruct(p.shape, np.float32)
+            for p in f.fn.placeholders.values()}
+    before = telemetry.REGISTRY.counter_values("vec.")
+    jax.make_jaxpr(step)(spec)
+    after = pom.metrics()["counters"]
+    assert after["vec.slice_access"] - before.get("vec.slice_access", 0) == 10
+    assert after.get("vec.gather_access", 0) \
+        - before.get("vec.gather_access", 0) == 0
 
 
 def test_service_pallas_runner_caches_executors(tmp_path):

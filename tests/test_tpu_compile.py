@@ -84,6 +84,18 @@ def test_untiled_gemm_4096_compiles_without_a_kernel(one_chip):
     assert KERNEL not in _step_text(prog, one_chip)
 
 
+def test_gaussian_4096_step_has_no_gather_scatter_or_sort(one_chip):
+    """The stencil's taps and its store are unit-stride windows: the
+    chip's compiler gets slices and a dynamic-update-slice, no gather,
+    scatter or sort."""
+    prog = pcompile(workloads.gaussian(4096).fn, target="pallas",
+                    interpret=False)
+    text = _step_text(prog, one_chip)
+    assert " dynamic-update-slice(" in text
+    for op in ("gather", "scatter", "sort"):
+        assert f" {op}(" not in text, op
+
+
 SMOLLM = dict(b=4, hq=15, hkv=5, d=64, s=256)
 
 
