@@ -26,12 +26,13 @@ def program(config, schedule):
     return f, config["schedules"][schedule].get("compile", {})
 
 
-def inputs(key, config, lead=()):
-    """``out`` starts random too, so a border the program must leave
-    alone is checked."""
+def inputs(key, config, traffic):
+    """``img`` and ``out``, with a leading axis of the traffic's ``batch``
+    lanes where it names one. ``out`` starts random too, so a border the
+    program must leave alone is checked."""
     n = config["n"]
     ki, ko = jax.random.split(key)
-    shape = tuple(lead) + (n, n)
+    shape = ((traffic["batch"],) if "batch" in traffic else ()) + (n, n)
     return {"img": jax.random.normal(ki, shape, jnp.float32),
             "out": jax.random.normal(ko, shape, jnp.float32)}
 
@@ -56,8 +57,8 @@ def control(a, config):
     return {"out": out.astype(jnp.float32)}
 
 
-def work(config):
-    """Algorithmic FLOPs and minimum HBM bytes of one call: 8 adds and 6
+def work(config, traffic):
+    """Algorithmic FLOPs and minimum HBM bytes of one lane: 8 adds and 6
     multiplies per interior point; ``img`` read once and the interior of
     ``out`` written once (the border is left as it is)."""
     n = config["n"]

@@ -35,10 +35,12 @@ def program(config, schedule):
     return f, params.get("compile", {})
 
 
-def inputs(key, config, lead=()):
+def inputs(key, config, traffic):
+    """``A``, ``B`` and ``C``, with a leading axis of the traffic's
+    ``batch`` lanes where it names one."""
     n = config["n"]
     ka, kb, kc = jax.random.split(key, 3)
-    shape = tuple(lead) + (n, n)
+    shape = ((traffic["batch"],) if "batch" in traffic else ()) + (n, n)
     return {"A": jax.random.normal(ka, shape, jnp.float32),
             "B": jax.random.normal(kb, shape, jnp.float32),
             "C": jax.random.normal(kc, shape, jnp.float32)}
@@ -59,8 +61,8 @@ def control(a, config):
     return {"C": a["C"] + jnp.matmul(q(a["A"]), q(a["B"]), precision=HIGHEST)}
 
 
-def work(config):
-    """Algorithmic FLOPs and minimum HBM bytes of one call: a multiply and
+def work(config, traffic):
+    """Algorithmic FLOPs and minimum HBM bytes of one lane: a multiply and
     an add per (i, j, k); A, B and C read once, C written once."""
     n = config["n"]
     one = {"flops": 2 * n ** 3, "bytes": 4 * 4 * n * n}

@@ -6,10 +6,12 @@ Everything a cell is made of is found by name from ``BENCHMARK.json``
 
 * ``configs/<config>.json`` — sizes, named schedules, the precision the
   configuration computes in, and the limit of each number compared;
-* ``configs/<program>.py`` — the DSL program, its inputs, the plain
-  reference, the lower-precision control and ``work()``;
-* ``traffic/<traffic>.json`` — which entry of the compiled program the
-  window drives, with which schedule, batch and number of input sets;
+* ``<program>.py`` beside it — the DSL program (or a ``build`` that makes
+  the entry itself), its inputs, the plain reference, the
+  lower-precision control and ``work()``;
+* ``traffic/<traffic>.json`` — which entry the window drives, with which
+  schedule, batch (or, for a ``build`` program, requests) and number of
+  input sets;
 * ``metrics/<metric>.py`` — ``read(ctx)`` for each per-layer metric.
 """
 from __future__ import annotations
@@ -64,14 +66,22 @@ class Cell:
     name: str
     chips: int
     config: dict                 # configs/<config>.json
-    program: Any                 # configs/<program>.py
+    program: Any                 # <program>.py beside the config's file
     traffic: dict                # traffic/<traffic>.json
     end_to_end: List[dict]       # BENCHMARK.json entries this cell reports
     per_layer: List[dict]
 
     @property
+    def builds(self) -> bool:
+        """Whether the program builds its own entry (``build``) instead of
+        being a DSL program that ``pom.compile`` lowers."""
+        return hasattr(self.program, "build")
+
+    @property
     def lanes(self) -> int:
-        """Program invocations in one step (one call of the entry)."""
+        """Program invocations in one step (one call of the entry): the
+        vmapped lanes (``batch``) of a compiled program; 1 for a ``build``
+        program, which counts its requests in ``work()``."""
         return self.traffic.get("batch", 1)
 
 
@@ -81,17 +91,23 @@ def resolve(spec: dict, name: str, root: Path = ROOT) -> Cell:
         raise KeyError(f"no workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     entry = {c["name"]: c for c in spec["configs"]}[w["config"]]
-    config = load_json(root / entry["file"])
+    config_file = root / entry["file"]
+    config = load_json(config_file)
     traffic = load_json(HERE / "traffic" / f"{w['traffic']}.json")
     if (traffic["loop"], traffic["callers"]) != ("closed", 1):
         raise ValueError(f"{w['traffic']}: the harness runs one caller in a "
                          "closed loop")
-    return Cell(
+    cell = Cell(
         name=name, chips=w["chips"], config=config,
-        program=load_module(HERE / "configs" / f"{config['program']}.py"),
+        program=load_module(config_file.parent / f"{config['program']}.py"),
         traffic=traffic,
         end_to_end=[m for m in spec["end_to_end"] if applies(m, name)],
         per_layer=[m for m in spec["per_layer"] if applies(m, name)])
+    if cell.builds and "batch" in traffic:
+        raise ValueError(f"{w['traffic']}: 'batch' is the vmapped lanes of a "
+                         "compiled program; a build program names its "
+                         "requests under a key of its own")
+    return cell
 
 
 def peaks_for(device_kind: str) -> dict:
@@ -175,9 +191,13 @@ def compare(outputs: Dict[int, dict], sets: List[dict], reference,
             for name, v in sorted(errs.items())}
 
 
-def build_entry(cell: Cell):
-    """Compile the cell's program with its schedule and return the
-    traffic's entry of the artifact: ``jitted()`` or ``batched(B)``."""
+def build_entry(cell: Cell, sets: Optional[List[dict]] = None):
+    """The entry the window drives. A program with ``build(config,
+    traffic, sets)`` makes it itself from the drawn input sets; any other
+    is compiled with its schedule, and the entry is the traffic's entry of
+    the artifact: ``jitted()`` or ``batched(B)``."""
+    if cell.builds:
+        return cell.program.build(cell.config, cell.traffic, sets)
     from repro.core.pipeline import compile as pom_compile
     fn, options = cell.program.program(cell.config, cell.traffic["schedule"])
     prog = pom_compile(fn, target="pallas", **options)
@@ -194,11 +214,10 @@ def make_inputs(cell: Cell, seed: int) -> List[dict]:
     jitted call."""
     import jax
     n_sets = cell.traffic["input_sets"]
-    lead = (cell.traffic["batch"],) if "batch" in cell.traffic else ()
 
     def draw(key):
         return [cell.program.inputs(jax.random.fold_in(key, i), cell.config,
-                                    lead) for i in range(n_sets)]
+                                    cell.traffic) for i in range(n_sets)]
     return jax.block_until_ready(jax.jit(draw)(seed_key(seed)))
 
 
@@ -303,8 +322,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         telemetry.start_trace(os.devnull)
     try:
-        entry = build_entry(cell)
-        sets = make_inputs(cell, seed)
+        if cell.builds:          # the entry is made from the drawn inputs
+            sets = make_inputs(cell, seed)
+            entry = build_entry(cell, sets)
+        else:
+            entry = build_entry(cell)
+            sets = make_inputs(cell, seed)
         writes = sorted(jax.eval_shape(reference_fn(cell), sets[0]))
         jax.block_until_ready(entry(sets[0]))          # compile + warm-up
     finally:
@@ -364,7 +387,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             shutil.rmtree(profile_dir, ignore_errors=True)
         ctx = Context(cell=cell.name, steps=steps,
                       step_s=win.seconds / max(steps, 1), lanes=cell.lanes,
-                      work=cell.program.work(cell.config), peaks=peaks,
+                      work=cell.program.work(cell.config, cell.traffic),
+                      peaks=peaks,
                       spans=spans, trace=summary)
         result["metrics"] = read_metrics(cell, ctx)
         device["busy_s"] = summary.busy_s

@@ -54,7 +54,7 @@ def test_existing_readings_of_the_recorded_trace_are_unchanged():
     ctx = harness.Context(
         cell=cell.name, steps=summary.runs,
         step_s=summary.window_s / summary.runs, lanes=1,
-        work=cell.program.work(cell.config),
+        work=cell.program.work(cell.config, cell.traffic),
         peaks=harness.peaks_for("TPU v5 lite"), spans=[], trace=summary)
     got = {k: v["value"] for k, v in harness.read_metrics(cell, ctx).items()}
     assert got == pytest.approx({"contraction_roofline": 22.932886780390483,

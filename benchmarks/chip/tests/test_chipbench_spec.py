@@ -56,11 +56,11 @@ def test_every_cell_reports_setup_another_end_to_end_and_a_layer():
 
 def test_work_of_both_configurations():
     gemm = harness.resolve(SPEC, "gemm_4096.tiled")
-    w = gemm.program.work(gemm.config)
+    w = gemm.program.work(gemm.config, gemm.traffic)
     assert w["total"] == {"flops": 2 * 4096 ** 3, "bytes": 16 * 4096 ** 2}
     assert w["contraction"] == w["total"]
     gauss = harness.resolve(SPEC, "gaussian_4096.jitted")
-    w = gauss.program.work(gauss.config)
+    w = gauss.program.work(gauss.config, gauss.traffic)
     assert w["total"] == {"flops": 14 * 4094 ** 2,
                           "bytes": 4 * 4096 ** 2 + 4 * 4094 ** 2}
     assert "contraction" not in w
@@ -69,11 +69,12 @@ def test_work_of_both_configurations():
 def test_rooflines_on_the_v5e():
     peaks = harness.peaks_for("TPU v5 lite")
     gemm = harness.resolve(SPEC, "gemm_4096.tiled")
-    t = harness.roofline_s(gemm.program.work(gemm.config)["total"], peaks)
+    w = gemm.program.work(gemm.config, gemm.traffic)
+    t = harness.roofline_s(w["total"], peaks)
     assert t == pytest.approx(2 * 4096 ** 3 / 197e12)        # compute-bound
     gauss = harness.resolve(SPEC, "gaussian_4096.jitted")
-    t = harness.roofline_s(gauss.program.work(gauss.config)["total"], peaks,
-                           lanes=8)
+    w = gauss.program.work(gauss.config, gauss.traffic)
+    t = harness.roofline_s(w["total"], peaks, lanes=8)
     assert t == pytest.approx(8 * 134152208 / 819e9)          # bytes-bound
 
 

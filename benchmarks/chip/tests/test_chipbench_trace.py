@@ -39,6 +39,29 @@ def test_union_and_clock_shift():
     assert xplane.clock_shift([12.0], host) == 0.0
 
 
+def test_ops_that_enclose_others_are_not_counted_twice():
+    op = xplane.Op
+    loop = op("while.1 while", 0, 100, False)
+    inner = [op("fusion.1 fusion", 5, 20, False),
+             op("call.2 pallas tpu_custom_call", 30, 60, True),
+             op("copy.3 copy", 90, 10, False)]      # ends with the loop
+    after = op("fusion.4 fusion", 100, 7, False)   # starts as it ends
+    got = xplane.leaves([loop] + inner + [after])
+    assert got == inner + [after]
+    # an async slice beside a fusion is no control flow: both count
+    beside = [op("fusion.5 fusion", 120, 30, False),
+              op("slice-done async-done", 125, 10, False)]
+    assert xplane.leaves(beside) == beside
+    summary = xplane.summarize(
+        {"/device:TPU:0": [loop] + inner + [after] + beside}, {},
+        [("bench.window", 0, 200)])
+    assert summary.op_s == pytest.approx(137e-9)
+    assert summary.kernel_s == pytest.approx(60e-9)
+    assert summary.busy_s == pytest.approx(137e-9)
+    assert [name for name, _ in summary.top_ops][0] == \
+        "call.2 pallas tpu_custom_call"
+
+
 def test_gaps_are_attributed_to_host_activity():
     inner = xplane._Spans([(0, 4, "bench.dispatch"), (4, 10, "bench.wait"),
                            (12, 14, "bench.dispatch")])
@@ -65,7 +88,7 @@ def _ctx(summary, spans=()):
     steps = summary.runs            # one program run a call
     return harness.Context(
         cell=cell.name, steps=steps, step_s=summary.window_s / steps,
-        lanes=1, work=cell.program.work(cell.config),
+        lanes=1, work=cell.program.work(cell.config, cell.traffic),
         peaks=harness.peaks_for("TPU v5 lite"), spans=list(spans),
         trace=summary)
 

@@ -23,6 +23,8 @@ from typing import Dict, List, Optional, Tuple
 KERNEL = 'custom_call_target="tpu_custom_call"'
 _OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
 TOP = 10
+# opcodes whose device event spans the ops they run
+CONTROL_FLOW = ("while", "conditional", "call")
 
 
 @dataclass
@@ -37,7 +39,7 @@ class Op:
 class Summary:
     window_s: float                  # the host's bench.window annotation
     busy_s: float                    # union of device op intervals
-    op_s: float                      # sum of device op durations
+    op_s: float                      # sum of leaf device op durations
     kernel_s: float                  # of which Pallas kernels
     runs: int                        # program runs (XLA Modules events)
     devices: int
@@ -64,6 +66,25 @@ def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
         else:
             out.append([s, e])
     return [(s, e) for s, e in out]
+
+
+def leaves(ops: List[Op]) -> List[Op]:
+    """The ops less the control-flow ops (``while``, ``conditional``,
+    ``call``) that enclose another op: the ``while`` of a scan over
+    layers spans the ops of its body, which would otherwise be counted
+    twice. Other ops may overlap (async copies and slices run beside a
+    fusion) and are all counted."""
+    parents, open_ = set(), []
+    for i, o in sorted(enumerate(ops), key=lambda io: (io[1].start_ns,
+                                                       -io[1].dur_ns)):
+        end = o.start_ns + o.dur_ns
+        while open_ and open_[-1][1] <= o.start_ns:
+            open_.pop()
+        if open_ and end <= open_[-1][1]:
+            parents.add(open_[-1][0])
+        if o.name.rsplit(" ", 1)[-1] in CONTROL_FLOW:
+            open_.append((i, end))
+    return [o for i, o in enumerate(ops) if i not in parents]
 
 
 def find_profile(path: str) -> str:
@@ -152,7 +173,7 @@ def summarize(ops: Dict[str, List[Op]], runs: Dict[str, List[float]],
     for dev, dev_ops in sorted(ops.items()):
         spans = union([(o.start_ns, o.start_ns + o.dur_ns) for o in dev_ops])
         busy.append(sum(e - s for s, e in spans))
-        for o in dev_ops:
+        for o in leaves(dev_ops):
             op_ns += o.dur_ns
             kernel_ns += o.dur_ns if o.kernel else 0.0
             per_op[o.name] = per_op.get(o.name, 0.0) + o.dur_ns
