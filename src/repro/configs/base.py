@@ -26,8 +26,19 @@ class ModelConfig:
     qkv_bias: bool = False
     mlp_gated: bool = True         # SwiGLU (True) vs GeLU 2-matrix (False)
     rope_theta: float = 10_000.0
+    position_embedding: str = "rope"   # 'rope' | 'nope' (no position signal)
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    # --- muP multipliers (granite): embeddings x embedding_multiplier, each
+    # residual branch x residual_multiplier, scores x attention_multiplier
+    # (None: 1/sqrt(head_dim)), logits / logits_scaling ---
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    # --- per-layer pattern: one 'mamba' or 'attention' mixer per layer, each
+    # followed by the MLP; empty = the family's own fixed pattern ---
+    layer_types: Tuple[str, ...] = ()
     # --- MoE ---
     num_experts: int = 0
     experts_per_token: int = 0
@@ -62,6 +73,16 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of a Mamba-2 mixer's inner stream (d_inner)."""
+        return self.ssm_expand * self.d_model
+
+    @property
+    def ssm_head_dim(self) -> int:
+        """Width of a Mamba-2 head: d_inner over the heads."""
+        return self.ssm_inner // (self.ssm_heads or self.num_heads)
 
     @property
     def padded_vocab_size(self) -> int:
@@ -128,6 +149,7 @@ ARCH_IDS = [
     "starcoder2_7b", "codeqwen1_5_7b", "smollm_360m", "qwen2_72b",
     "musicgen_large", "zamba2_1_2b", "llama4_maverick_400b",
     "granite_moe_1b", "xlstm_1_3b", "phi3_vision_4_2b",
+    "granite_4_0_h_micro",
 ]
 
 _REGISTRY: Dict[str, ModelConfig] = {}
@@ -171,5 +193,10 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         param_dtype="float32",
         remat="none",
     )
+    if cfg.layer_types:
+        # each kind of layer, in the order it first appears, twice over:
+        # both kinds present and the stack scanned over two repeats
+        kinds = tuple(dict.fromkeys(cfg.layer_types))
+        small.update(layer_types=kinds * 2, num_layers=2 * len(kinds))
     small.update(overrides)
     return dataclasses.replace(cfg, **small)

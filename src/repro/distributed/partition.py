@@ -57,11 +57,9 @@ def _axes_for(path: str, shape: Tuple[int, ...], cfg: ModelConfig) -> Logical:
         return t("embed", "mlp")
     if path.endswith("mamba/conv"):
         return t(None, "mlp")
-    if path.endswith(("mamba/w_b", "mamba/w_c")):
-        return t("embed", None)
-    if path.endswith("mamba/w_dt"):
-        return t("embed", "ssm_heads")
-    if path.endswith(("mamba/a_log", "mamba/dt_bias")):
+    if path.endswith("mamba/conv_b"):
+        return t("mlp")
+    if path.endswith(("mamba/a_log", "mamba/dt_bias", "mamba/d_skip")):
         return t("ssm_heads")
     if path.endswith("mamba/w_out"):
         return t("mlp", "embed")
@@ -170,16 +168,22 @@ def cache_logical_axes(cfg: ModelConfig, long_context: bool = False):
     kv_seq = "kv_seq_sharded" if long_context else "kv_seq"
 
     def kv_axes():
-        return {"k": ("layers", "batch", "kv_heads", kv_seq, None),
-                "v": ("layers", "batch", "kv_heads", kv_seq, None)}
+        return {"k": ("layers", "batch", kv_seq, "kv_heads"),
+                "v": ("layers", "batch", kv_seq, "kv_heads")}
 
+    ssm = {"h": ("layers", "batch", "ssm_heads", None, None),
+           "conv": ("layers", "batch", None, "mlp")}
+    if cfg.layer_types:
+        from repro.models.model import pattern
+        period, _ = pattern(cfg)
+        return {f"l{i}": kv_axes() if kind == "attention" else dict(ssm)
+                for i, kind in enumerate(period)}
     if cfg.family in ("dense", "audio", "vlm"):
         return kv_axes()
     if cfg.family == "moe":
         return {f"l{i}": kv_axes() for i in range(cfg.moe_every)}
     if cfg.family == "hybrid":
-        out = {"ssm": {"h": ("layers", "batch", "ssm_heads", None, None),
-                       "conv": ("layers", "batch", None, "mlp")}}
+        out = {"ssm": dict(ssm)}
         if cfg.attn_every:
             out["shared_kv"] = kv_axes()
         return out

@@ -111,7 +111,7 @@ def pom_scan_schedule(s: int, p: int, n: int, dtype_bytes: int = 2,
     best: Optional[ScanSchedule] = None
     L = 64
     while L <= min(s, 1024):
-        if s % L == 0:
+        if s % L == 0 and (L % 128 == 0 or L == s):
             vmem = (L * p + 2 * L * n) * dtype_bytes * 2 + L * L * 4 + n * p * 4
             if vmem <= spec.vmem_bytes:
                 flops = 2.0 * s * (L * n + L * p + n * p)   # per (b,h): L^2-ish terms

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .autotune import pom_attention_schedule, pom_matmul_schedule, pom_scan_schedule
-from .decode_attention import decode_attention as _decode_pallas
+from .decode_attention import decode_attention_rows as _decode_pallas
 from .flash_attention import flash_attention as _flash_pallas
 from .grouped_matmul import grouped_matmul as _gmm_pallas
 from .matmul_pom import matmul as _matmul_pallas
@@ -42,10 +42,11 @@ def matmul(x, y, *, schedule: str = "pom", impl: Impl = "ref",
     return _matmul_pallas(x, y, bm=bm, bn=bn, bk=bk, interpret=interpret)
 
 
-def attention(q, k, v, *, causal: bool = True, schedule: str = "pom",
-              impl: Impl = "ref", interpret: Optional[bool] = None):
+def attention(q, k, v, *, causal: bool = True, scale=None,
+              schedule: str = "pom", impl: Impl = "ref",
+              interpret: Optional[bool] = None):
     if impl == "ref":
-        return ref.attention(q, k, v, causal=causal)
+        return ref.attention(q, k, v, causal=causal, scale=scale)
     sq, skv, d = q.shape[2], k.shape[2], q.shape[3]
     if schedule == "pom":
         s = pom_attention_schedule(max(sq, 128), max(skv, 128), d,
@@ -53,22 +54,33 @@ def attention(q, k, v, *, causal: bool = True, schedule: str = "pom",
         bq, bkv = s.bq, s.bkv
     else:
         bq = bkv = 128
-    return _flash_pallas(q, k, v, causal=causal, bq=bq, bkv=bkv,
+    return _flash_pallas(q, k, v, causal=causal, scale=scale, bq=bq, bkv=bkv,
                          interpret=interpret)
 
 
-def decode_attention(q, k, v, *, length=None, schedule: str = "pom",
-                     impl: Impl = "ref", interpret: Optional[bool] = None):
+def decode_attention(q, k, v, *, length=None, layer=None, scale=None,
+                     schedule: str = "pom", impl: Impl = "ref",
+                     interpret: Optional[bool] = None):
+    """q: (B, Hq, D); k/v: the decode cache's rows (B, S, Hkv*D), or with
+    ``layer`` a stack of them (Lyr, B, S, Hkv*D) and the layer read."""
     if impl == "ref":
-        return ref.decode_attention(q, k, v, length=length)
-    skv, d = k.shape[2], q.shape[2]
+        if layer is not None:
+            k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+            v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+        b, s, w = k.shape
+        heads = lambda c: c.reshape(b, s, w // q.shape[-1], q.shape[-1]  # noqa: E731
+                                    ).transpose(0, 2, 1, 3)
+        return ref.decode_attention(q, heads(k), heads(v), length=length,
+                                    scale=scale)
+    skv, w = k.shape[-2:]              # a block holds whole rows of w values
     if schedule == "pom":
-        s = pom_attention_schedule(128, max(skv, 128), d,
+        s = pom_attention_schedule(128, max(skv, 128), w,
                                    jnp.dtype(q.dtype).itemsize, False)
         bkv = s.bkv
     else:
         bkv = 256
-    return _decode_pallas(q, k, v, length=length, bkv=bkv, interpret=interpret)
+    return _decode_pallas(q, k, v, length=length, layer=layer, scale=scale,
+                          bkv=bkv, interpret=interpret)
 
 
 def ssm_scan(x, a, b, c, *, schedule: str = "pom", impl: Impl = "ref",

@@ -36,12 +36,14 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                     length: jnp.ndarray | None = None) -> jnp.ndarray:
+                     length: jnp.ndarray | None = None,
+                     scale: float | None = None) -> jnp.ndarray:
     """Single-token decode. q: (B, Hq, D), k/v: (B, Hkv, S, D).
 
     ``length``: (B,) valid KV prefix per batch row (None = full)."""
     b, hq, d = q.shape
-    out = attention(q[:, :, None, :], k, v, causal=False)[:, :, 0, :]
+    out = attention(q[:, :, None, :], k, v, causal=False,
+                    scale=scale)[:, :, 0, :]
     if length is None:
         return out
     # masked variant
@@ -49,12 +51,19 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     group = hq // hkv
     kr = jnp.repeat(k, group, axis=1)
     vr = jnp.repeat(v, group, axis=1)
+    scale = scale if scale is not None else 1.0 / np.sqrt(d)
     s = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32),
-                   kr.astype(jnp.float32)) / np.sqrt(d)
+                   kr.astype(jnp.float32)) * scale
     mask = jnp.arange(k.shape[2])[None, None, :] < length[:, None, None]
     s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhk,bhkd->bhd", p, vr.astype(jnp.float32)).astype(q.dtype)
+
+
+def _per_head(m: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """(B, S, G, N) grouped b or c -> (B, S, H, N), each group repeated
+    over its heads."""
+    return jnp.repeat(m, heads // m.shape[2], axis=2)
 
 
 def ssm_scan(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
@@ -63,14 +72,15 @@ def ssm_scan(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
 
     x: (B, S, H, P)   inputs
     a: (B, S, H)      decay in (0, 1] (already exp(-softplus(...)dt))
-    b: (B, S, H, N)   input projection to state
-    c: (B, S, H, N)   state readout
+    b: (B, S, G, N)   input projection to state, G groups of H/G heads
+    c: (B, S, G, N)   state readout
     returns y: (B, S, H, P), h_last: (B, H, N, P)
 
     h_t = a_t * h_{t-1} + b_t ⊗ x_t ;  y_t = c_t · h_t
     """
     B, S, H, P = x.shape
     N = b.shape[-1]
+    b, c = _per_head(b, H), _per_head(c, H)
     if h0 is None:
         h0 = jnp.zeros((B, H, N, P), jnp.float32)
 
@@ -95,6 +105,7 @@ def ssm_scan_chunked(x, a, b, c, h0=None, chunk: int = 128,
     counts the full sequence (dry-run cost extraction)."""
     B, S, H, P = x.shape
     N = b.shape[-1]
+    b, c = _per_head(b, H), _per_head(c, H)
     L = min(chunk, S)
     assert S % L == 0
     nchunks = S // L

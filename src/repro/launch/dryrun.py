@@ -64,6 +64,9 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
 
 
 def _structural_period(cfg) -> int:
+    if cfg.layer_types:
+        from repro.models.model import pattern
+        return len(pattern(cfg)[0])
     if cfg.family == "moe":
         return cfg.moe_every
     if cfg.family == "hybrid":
@@ -111,9 +114,11 @@ def _extrapolate_costs(cfg, shape, pcfg, mc, long_ctx) -> Dict:
     P = _structural_period(cfg)
     vals = {}
     for mult in (1, 2):
+        depth = {"layer_types": cfg.layer_types[:P] * mult} \
+            if cfg.layer_types else {}
         cfg2 = dataclasses.replace(cfg, scan_layers=False,
                                    unroll_inner_scans=True,
-                                   num_layers=P * mult)
+                                   num_layers=P * mult, **depth)
         jitted, args = _build_args(cfg2, shape, pcfg, mc, long_ctx)
         compiled = jitted.lower(*args).compile()
         ca = compiled.cost_analysis()

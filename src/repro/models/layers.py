@@ -61,7 +61,8 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                       causal: bool = True, chunk: int = 512,
-                      unroll: bool = False) -> jnp.ndarray:
+                      unroll: bool = False,
+                      scale: Optional[float] = None) -> jnp.ndarray:
     """Online-softmax over q chunks.  q: (B,H,Sq,D), k/v: (B,Hkv,Skv,D).
 
     ``unroll=True`` python-loops the chunk scan (dry-run cost extraction:
@@ -71,7 +72,7 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     group = h // hkv
     kr = jnp.repeat(k, group, axis=1) if group > 1 else k
     vr = jnp.repeat(v, group, axis=1) if group > 1 else v
-    scale = 1.0 / np.sqrt(d)
+    scale = 1.0 / np.sqrt(d) if scale is None else scale
     chunk = min(chunk, sq)
     if sq % chunk:
         chunk = sq  # fallback for odd lengths (smoke tests)
@@ -133,6 +134,13 @@ def attention_init(key, cfg: ModelConfig) -> Params:
     return p
 
 
+def attention_scale(cfg: ModelConfig) -> float:
+    """The scores' scale: the configured multiplier, else 1/sqrt(head_dim)."""
+    if cfg.attention_multiplier is not None:
+        return cfg.attention_multiplier
+    return 1.0 / float(np.sqrt(cfg.resolved_head_dim))
+
+
 def _qkv(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions: jnp.ndarray):
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
@@ -146,74 +154,126 @@ def _qkv(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions: jnp.ndarray):
     q = q.reshape(b, s, cfg.num_heads, hd).transpose(0, 2, 1, 3)
     k = k.reshape(b, s, cfg.num_kv_heads, hd).transpose(0, 2, 1, 3)
     v = v.reshape(b, s, cfg.num_kv_heads, hd).transpose(0, 2, 1, 3)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    elif cfg.position_embedding != "nope":
+        raise ValueError(f"position_embedding {cfg.position_embedding!r}")
     return q, k, v
+
+
+def attention_prefill(p: Params, x: jnp.ndarray, cfg: ModelConfig,
+                      positions: jnp.ndarray):
+    """Full (train/prefill) causal attention: (out (B,S,d), k, v), with
+    k/v (B, S, KV*hd) the rows a decode cache holds."""
+    b, s, d = x.shape
+    q, k, v = _qkv(p, x, cfg, positions)
+    scale = attention_scale(cfg)
+    if cfg.use_pallas and s % 128 == 0:
+        o = _flash_kernel(q, k, v, scale)
+    else:
+        o = chunked_attention(q, k, v, causal=True, chunk=cfg.attn_chunk,
+                              unroll=cfg.unroll_inner_scans, scale=scale)
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.num_heads * cfg.resolved_head_dim)
+    return o @ p["wo"], _rows(k), _rows(v)
+
+
+def _rows(c: jnp.ndarray) -> jnp.ndarray:
+    """(B, KV, S, hd) -> the cache's rows (B, S, KV*hd)."""
+    b, kv, s, hd = c.shape
+    return c.transpose(0, 2, 1, 3).reshape(b, s, kv * hd)
 
 
 def attention_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                     positions: jnp.ndarray) -> jnp.ndarray:
     """Full (train/prefill) causal attention."""
-    b, s, d = x.shape
-    q, k, v = _qkv(p, x, cfg, positions)
-    if cfg.use_pallas and s % 128 == 0:
-        o = ops.attention(q, k, v, causal=True, impl="pallas")
-    else:
-        o = chunked_attention(q, k, v, causal=True, chunk=cfg.attn_chunk,
-                              unroll=cfg.unroll_inner_scans)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.num_heads * cfg.resolved_head_dim)
-    return o @ p["wo"]
+    return attention_prefill(p, x, cfg, positions)[0]
 
 
 def attention_decode(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                      cache_k: jnp.ndarray, cache_v: jnp.ndarray,
-                     pos: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One-token decode. x: (B, 1, d); cache: (B, KV, S, hd); pos: (B,)."""
+                     pos: jnp.ndarray, layer=None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One-token decode. x: (B, 1, d); cache: rows (B, S, KV*hd), each
+    position's K (or V) of every KV head side by side; pos: (B,).
+
+    With ``layer``, the caches are a stack (Lyr, B, S, KV*hd) and this is
+    layer ``layer`` of it: the new K/V row is written into the stack and
+    the kernel reads the layer in place, so no layer is sliced out."""
     b, _, d = x.shape
     hd = cfg.resolved_head_dim
     q, k, v = _qkv(p, x, cfg, pos[:, None])
-    # write new k/v at pos
-    idx = pos[:, None, None, None]  # (B,1,1,1)
-    onehot = (jnp.arange(cache_k.shape[2])[None, None, :, None] == idx)
-    cache_k = jnp.where(onehot, k.astype(cache_k.dtype), cache_k)
-    cache_v = jnp.where(onehot, v.astype(cache_v.dtype), cache_v)
+    if layer is None:
+        cache_k, cache_v, at = cache_k[None], cache_v[None], 0
+    else:
+        at = layer
+    # write each request's new k/v row at its position, in place
+    rows = jnp.arange(b)
+    cache_k = cache_k.at[at, rows, pos].set(_rows(k)[:, 0].astype(cache_k.dtype))
+    cache_v = cache_v.at[at, rows, pos].set(_rows(v)[:, 0].astype(cache_v.dtype))
     length = pos + 1
+    scale = attention_scale(cfg)
     if cfg.use_pallas:
-        o = _decode_kernel(q[:, :, 0, :], cache_k, cache_v, length)
+        o = _decode_kernel(q[:, :, 0, :], cache_k, cache_v, length, at, scale)
     else:
         o = ops.decode_attention(q[:, :, 0, :], cache_k, cache_v,
-                                 length=length, impl="ref")
+                                 length=length, layer=at, scale=scale,
+                                 impl="ref")
     o = o.reshape(b, 1, cfg.num_heads * hd)
+    if layer is None:
+        cache_k, cache_v = cache_k[0], cache_v[0]
     return o @ p["wo"], cache_k, cache_v
 
 
-def _decode_kernel(q, k, v, length):
-    """The Pallas decode kernel, partitioned by hand over the active mesh.
-
-    GSPMD cannot partition a Pallas kernel: compiled, JAX refuses it
-    ("Mosaic kernels cannot be automatically partitioned"); interpreted,
-    GSPMD gathers the whole cache to every device.  So under a mesh the
-    kernel is ``shard_map``'d: rows over the batch axes, KV heads (and
-    their query heads, a whole GQA group each) over the model axis, each
-    only where it divides."""
-    mc = current()
-    if mc is None:
-        return ops.decode_attention(q, k, v, length=length, impl="pallas")
+def _mesh_axes(mc, rows: int, heads: int):
+    """The mesh axes a kernel's requests and (KV) heads are split over:
+    the batch axes and the model axis, each only where it divides."""
     bat, hds = mc.spec(("batch", "kv_heads"))
 
     def size(ax):
         axes = () if ax is None else (ax,) if isinstance(ax, str) else ax
         return int(np.prod([mc.mesh.shape[a] for a in axes]))
 
-    bat = bat if q.shape[0] % size(bat) == 0 else None
-    hds = hds if k.shape[1] % size(hds) == 0 else None
-    kv = P(bat, hds, None, None)
+    return (bat if rows % size(bat) == 0 else None,
+            hds if heads % size(hds) == 0 else None)
+
+
+# GSPMD cannot partition a Pallas kernel: compiled, JAX refuses it ("Mosaic
+# kernels cannot be automatically partitioned"); interpreted, GSPMD gathers
+# the whole cache to every device.  So under a mesh each kernel is
+# ``shard_map``'d by hand: requests over the batch axes, KV heads (and
+# their query heads, a whole GQA group each) over the model axis.
+
+def _flash_kernel(q, k, v, scale):
+    """The Pallas flash kernel over q (B,H,S,hd), k/v (B,KV,S,hd)."""
+    def run(q, k, v):
+        return ops.attention(q, k, v, causal=True, scale=scale,
+                             impl="pallas")
+    mc = current()
+    if mc is None:
+        return run(q, k, v)
+    bat, hds = _mesh_axes(mc, q.shape[0], k.shape[1])
+    spec = P(bat, hds, None, None)
+    return jax.shard_map(run, mesh=mc.mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+def _decode_kernel(q, k, v, length, layer, scale):
+    """The Pallas decode kernel over q (B,H,hd), a stack of row caches k/v
+    (Lyr,B,S,KV*hd) and its layer ``layer``."""
+    def run(q, k, v, n, at):
+        return ops.decode_attention(q, k, v, length=n, layer=at, scale=scale,
+                                    impl="pallas")
+    layer = jnp.asarray(layer, jnp.int32)
+    mc = current()
+    if mc is None:
+        return run(q, k, v, length, layer)
+    bat, hds = _mesh_axes(mc, q.shape[0], k.shape[-1] // q.shape[-1])
+    kv = P(None, bat, None, hds)
     return jax.shard_map(
-        lambda q, k, v, n: ops.decode_attention(q, k, v, length=n,
-                                                impl="pallas"),
-        mesh=mc.mesh, in_specs=(P(bat, hds, None), kv, kv, P(bat)),
+        run, mesh=mc.mesh, in_specs=(P(bat, hds, None), kv, kv, P(bat), P()),
         out_specs=P(bat, hds, None),
-        check_vma=False)(q, k, v, length)  # pallas_call outputs carry no vma
+        check_vma=False)(q, k, v, length, layer)  # pallas_call outputs carry no vma
 
 
 # --------------------------------------------------------------------------

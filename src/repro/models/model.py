@@ -5,6 +5,13 @@ Layers are stacked with ``jax.vmap`` at init and iterated with
 (fast 512-device compiles).  Heterogeneous stacks (MoE interleave, zamba2
 shared attention, xLSTM sLSTM insertion) scan over *super-blocks* or use an
 index-conditioned branch with shared (non-scanned) weights.
+
+A config with ``layer_types`` is one stack driven by that per-layer
+pattern: each layer is a Mamba-2 or an attention mixer and then the MLP,
+and the stack scans over repeats of the pattern's period.  Its cache holds
+two kinds of state side by side: K/V for the attention layers, SSM state
+and conv window for the Mamba layers.  ``prefill`` fills a cache from whole
+prompts in one forward pass (this stack and the dense family).
 """
 from __future__ import annotations
 
@@ -15,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core import telemetry
 from repro.distributed.sharding import shard_hint
 from . import layers as L
 from . import mamba2 as M
@@ -22,6 +30,38 @@ from . import moe as MOE
 from . import xlstm as XL
 
 Params = Dict[str, Any]
+
+# the prefill pads prompts to a multiple of this many positions, which every
+# block size the Pallas flash and scan kernels choose divides
+PREFILL_BLOCK = 128
+
+
+def pattern(cfg: ModelConfig) -> Tuple[Tuple[str, ...], int]:
+    """(period, repeats) of ``cfg.layer_types``: the shortest prefix that
+    makes the whole pattern by repetition."""
+    lt = tuple(cfg.layer_types)
+    if len(lt) != cfg.num_layers or set(lt) - {"mamba", "attention"}:
+        raise ValueError(f"{cfg.name}: layer_types must name 'mamba' or "
+                         f"'attention' for each of {cfg.num_layers} layers")
+    for p in range(1, len(lt) + 1):
+        if len(lt) % p == 0 and lt == lt[:p] * (len(lt) // p):
+            return lt[:p], len(lt) // p
+
+
+_MIXER_KEY = {"mamba": "mamba", "attention": "attn"}
+
+
+def _scan(body, carry, xs, cfg: ModelConfig):
+    """``lax.scan`` over the leading axis of ``xs``, or a Python loop over
+    it when the config unrolls its layers."""
+    if cfg.scan_layers:
+        return jax.lax.scan(body, carry, xs)
+    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    ys = []
+    for i in range(n):
+        carry, y = body(carry, _layer_slice(xs, i))
+        ys.append(y)
+    return carry, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
 
 
 # ---------------------------------------------------------------------------
@@ -37,7 +77,24 @@ def init_params(key, cfg: ModelConfig) -> Params:
     p: Params = {"embed": L.embed_init(ke, cfg),
                  "final_norm": L.rmsnorm_init(cfg.d_model, L.dtype_of(cfg.param_dtype))}
 
-    if cfg.family in ("dense", "audio", "vlm"):
+    if cfg.layer_types:
+        period, repeats = pattern(cfg)
+
+        def period_init(k):
+            kk = jax.random.split(k, 2 * len(period))
+            out = {}
+            for i, kind in enumerate(period):
+                mixer = (M.mamba2_init if kind == "mamba"
+                         else L.attention_init)(kk[2 * i], cfg)
+                out[f"l{i}"] = {
+                    "ln1": L.rmsnorm_init(cfg.d_model, L.dtype_of(cfg.param_dtype)),
+                    _MIXER_KEY[kind]: mixer,
+                    "ln2": L.rmsnorm_init(cfg.d_model, L.dtype_of(cfg.param_dtype)),
+                    "mlp": L.mlp_init(kk[2 * i + 1], cfg)}
+            return out
+        p["blocks"] = _stacked(kl, repeats, period_init)
+
+    elif cfg.family in ("dense", "audio", "vlm"):
         def block_init(k):
             k1, k2, k3, k4 = jax.random.split(k, 4)
             return {"ln1": L.rmsnorm_init(cfg.d_model, L.dtype_of(cfg.param_dtype)),
@@ -111,11 +168,62 @@ def _remat(fn, cfg: ModelConfig):
     return jax.checkpoint(fn, static_argnums=(2,))
 
 
-def _dense_block(bp, x, cfg, positions):
-    x = x + L.attention_apply(bp["attn"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps),
-                              cfg, positions)
+def _dense_layer(bp, x, cfg, positions):
+    """One dense layer: (x, its K/V rows {"k", "v"})."""
+    o, k, v = L.attention_prefill(bp["attn"],
+                                  L.rmsnorm(bp["ln1"], x, cfg.norm_eps),
+                                  cfg, positions)
+    x = x + o
     x = x + L.mlp_apply(bp["mlp"], L.rmsnorm(bp["ln2"], x, cfg.norm_eps))
-    return shard_hint(x, ("batch", "seq", "embed"))
+    return shard_hint(x, ("batch", "seq", "embed")), {"k": k, "v": v}
+
+
+def _dense_block(bp, x, cfg, positions):
+    return _dense_layer(bp, x, cfg, positions)[0]
+
+
+def _pattern_period(bp, x, cfg, positions, length=None):
+    """One repeat of the layer pattern's period: each layer is
+    ``x += r * mixer(norm(x)); x += r * mlp(norm(x))``, r the residual
+    multiplier.  Returns (x, {l<i>: the layer's cache}): K/V rows of an
+    attention layer, the state after ``length`` positions of a Mamba one."""
+    period, _ = pattern(cfg)
+    r = cfg.residual_multiplier
+    caches = {}
+    for i, kind in enumerate(period):
+        blk = bp[f"l{i}"]
+        h = L.rmsnorm(blk["ln1"], x, cfg.norm_eps)
+        if kind == "attention":
+            o, k, v = L.attention_prefill(blk["attn"], h, cfg, positions)
+            caches[f"l{i}"] = {"k": k, "v": v}
+        else:
+            o, caches[f"l{i}"] = M.mamba2_apply(blk["mamba"], h, cfg, length)
+        x = x + r * o
+        x = x + r * L.mlp_apply(blk["mlp"], L.rmsnorm(blk["ln2"], x, cfg.norm_eps))
+        x = shard_hint(x, ("batch", "seq", "embed"))
+    return x, caches
+
+
+def _pattern_block(bp, x, cfg, positions):
+    return _pattern_period(bp, x, cfg, positions)[0]
+
+
+def _embed(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
+    x = L.embed_apply(params["embed"], tokens).astype(L.dtype_of(cfg.dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _unembed(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+    """Final norm, tied or own unembedding in ``logits_dtype``, and the
+    logits' scaling: (B, S, V) fp32."""
+    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    logits = L.unembed_apply(params["embed"], x, cfg.vocab_size,
+                             L.dtype_of(cfg.logits_dtype))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _moe_super_block(bp, x, cfg, positions):
@@ -138,7 +246,8 @@ def _moe_super_block(bp, x, cfg, positions):
 
 
 def _hybrid_block(bp, x, cfg, idx, shared, positions):
-    x = x + M.mamba2_apply(bp["mamba"], L.rmsnorm(bp["ln"], x, cfg.norm_eps), cfg)
+    x = x + M.mamba2_apply(bp["mamba"], L.rmsnorm(bp["ln"], x, cfg.norm_eps),
+                           cfg)[0]
     if cfg.attn_every and shared is not None:
         def with_attn(x):
             return _dense_block(shared, x, cfg, positions)
@@ -166,13 +275,18 @@ def forward(params: Params, cfg: ModelConfig,
         x = L.frontend_apply(cfg, embeds).astype(L.dtype_of(cfg.dtype))
         b, s = x.shape[:2]
     else:
-        x = L.embed_apply(params["embed"], tokens).astype(L.dtype_of(cfg.dtype))
+        x = _embed(params, cfg, tokens)
         b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     x = shard_hint(x, ("batch", "seq", "embed"))
     aux = jnp.zeros((), jnp.float32)
 
-    if cfg.family in ("dense", "audio", "vlm"):
+    if cfg.layer_types:
+        def body(carry, bp):
+            return _remat(_pattern_block, cfg)(bp, carry, cfg, positions), None
+        x, _ = _scan(body, x, params["blocks"], cfg)
+
+    elif cfg.family in ("dense", "audio", "vlm"):
         if cfg.scan_layers:
             def body(carry, bp):
                 return _remat(_dense_block, cfg)(bp, carry, cfg, positions), None
@@ -208,7 +322,8 @@ def forward(params: Params, cfg: ModelConfig,
             for i in range(cfg.num_layers):
                 bp = _layer_slice(params["blocks"], i)
                 x = x + M.mamba2_apply(bp["mamba"],
-                                       L.rmsnorm(bp["ln"], x, cfg.norm_eps), cfg)
+                                       L.rmsnorm(bp["ln"], x, cfg.norm_eps),
+                                       cfg)[0]
                 if cfg.attn_every and shared is not None \
                         and (i + 1) % cfg.attn_every == 0:
                     x = _dense_block(shared, x, cfg, positions)
@@ -231,10 +346,7 @@ def forward(params: Params, cfg: ModelConfig,
                         bp["slstm"], L.rmsnorm(bp["ln_s"], x, cfg.norm_eps), cfg)
                 x = shard_hint(x, ("batch", "seq", "embed"))
 
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed_apply(params["embed"], x, cfg.vocab_size,
-                             L.dtype_of(cfg.logits_dtype))
-    logits = shard_hint(logits, ("batch", "seq", "vocab"))
+    logits = shard_hint(_unembed(params, cfg, x), ("batch", "seq", "vocab"))
     return logits, aux
 
 
@@ -256,14 +368,40 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: Dict) -> Tuple[jnp.ndarray,
 # decode: cache init + single-token step
 # ---------------------------------------------------------------------------
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None) -> Params:
+    """The decode cache of ``batch`` requests up to ``max_seq`` positions.
+    Records a ``model.cache`` telemetry event with its K/V bytes and its
+    recurrent-state bytes."""
+    cache = _init_cache(cfg, batch, max_seq, dtype)
+    if telemetry.on():
+        kv = state = 0
+        for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+            n = leaf.size * jnp.dtype(leaf.dtype).itemsize
+            if getattr(path[-1], "key", None) in ("k", "v"):
+                kv += n
+            else:
+                state += n
+        telemetry.event("model.cache", "model", kv_bytes=int(kv),
+                        state_bytes=int(state), batch=batch, max_seq=max_seq)
+    return cache
+
+
+def _init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None) -> Params:
     dt = dtype or L.dtype_of(cfg.dtype)
     hd = cfg.resolved_head_dim
     kvh = cfg.num_kv_heads
 
-    def kv(n):
-        return {"k": jnp.zeros((n, batch, kvh, max_seq, hd), dt),
-                "v": jnp.zeros((n, batch, kvh, max_seq, hd), dt)}
+    def kv(n):            # rows: each position's K (V) of every KV head
+        return {"k": jnp.zeros((n, batch, max_seq, kvh * hd), dt),
+                "v": jnp.zeros((n, batch, max_seq, kvh * hd), dt)}
 
+    if cfg.layer_types:
+        # two kinds of state side by side, each stacked over the repeats:
+        # K/V at the attention positions, SSM state at the Mamba ones
+        period, repeats = pattern(cfg)
+        return {f"l{i}": kv(repeats) if kind == "attention" else
+                jax.vmap(lambda _: M.mamba2_init_state(cfg, batch))(
+                    jnp.arange(repeats))
+                for i, kind in enumerate(period)}
     if cfg.family in ("dense", "audio", "vlm"):
         return kv(cfg.num_layers)
     if cfg.family == "moe":
@@ -274,11 +412,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None) -> Params
             jnp.arange(cfg.num_layers))
         cache = {"ssm": st}
         if cfg.attn_every:
-            cache["shared_kv"] = {
-                "k": jnp.zeros((cfg.num_layers // cfg.attn_every, batch, kvh,
-                                max_seq, hd), dt),
-                "v": jnp.zeros((cfg.num_layers // cfg.attn_every, batch, kvh,
-                                max_seq, hd), dt)}
+            cache["shared_kv"] = kv(cfg.num_layers // cfg.attn_every)
         return cache
     if cfg.family == "ssm":
         m = jax.vmap(lambda _: XL.mlstm_init_state(cfg, batch))(
@@ -292,29 +426,52 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None) -> Params
 def decode_step(params: Params, cfg: ModelConfig, cache: Params,
                 token: jnp.ndarray, pos: jnp.ndarray) -> Tuple[jnp.ndarray, Params]:
     """token: (B,) int32; pos: (B,) current positions. Returns (logits(B,V), cache)."""
-    b = token.shape[0]
-    x = L.embed_apply(params["embed"], token[:, None]).astype(L.dtype_of(cfg.dtype))
+    x = _embed(params, cfg, token[:, None])
 
-    if cfg.family in ("dense", "audio", "vlm"):
-        def body(x, scanned):
-            bp, ck, cv = scanned
+    if cfg.layer_types:
+        # K/V stacks ride in the carry and each attention layer writes its
+        # row and reads its layer in place; SSM states go in and come out
+        # layer by layer
+        period, _ = pattern(cfg)
+        r = cfg.residual_multiplier
+        kv0 = {n: c for n, c in cache.items() if "k" in c}
+        st0 = {n: c for n, c in cache.items() if "k" not in c}
+
+        def body(carry, scanned):
+            x, rep, kv = carry
+            bp, st = scanned
+            kv, new = dict(kv), {}
+            for i, kind in enumerate(period):
+                name, blk = f"l{i}", bp[f"l{i}"]
+                h = L.rmsnorm(blk["ln1"], x, cfg.norm_eps)
+                if kind == "attention":
+                    o, ck, cv = L.attention_decode(
+                        blk["attn"], h, cfg, kv[name]["k"], kv[name]["v"],
+                        pos, layer=rep)
+                    kv[name] = {"k": ck, "v": cv}
+                else:
+                    o, new[name] = M.mamba2_decode(blk["mamba"], h, st[name],
+                                                   cfg)
+                x = x + r * o
+                x = x + r * L.mlp_apply(blk["mlp"],
+                                        L.rmsnorm(blk["ln2"], x, cfg.norm_eps))
+            return (x, rep + 1, kv), new
+        (x, _, kv), st = _scan(body, (x, jnp.int32(0), kv0),
+                               (params["blocks"], st0), cfg)
+        cache = {**kv, **st}
+
+    elif cfg.family in ("dense", "audio", "vlm"):
+        def body(carry, bp):
+            x, i, ks, vs = carry
             h = L.rmsnorm(bp["ln1"], x, cfg.norm_eps)
-            o, ck, cv = L.attention_decode(bp["attn"], h, cfg, ck, cv, pos)
+            o, ks, vs = L.attention_decode(bp["attn"], h, cfg, ks, vs, pos,
+                                           layer=i)
             x = x + o
             x = x + L.mlp_apply(bp["mlp"], L.rmsnorm(bp["ln2"], x, cfg.norm_eps))
-            return x, (ck, cv)
-        if cfg.scan_layers:
-            x, (ks, vs) = jax.lax.scan(body, x,
-                                       (params["blocks"], cache["k"], cache["v"]))
-            cache = {"k": ks, "v": vs}
-        else:
-            ks, vs = [], []
-            for i in range(cfg.num_layers):
-                x, (ck, cv) = body(x, (_layer_slice(params["blocks"], i),
-                                       cache["k"][i], cache["v"][i]))
-                ks.append(ck)
-                vs.append(cv)
-            cache = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+            return (x, i + 1, ks, vs), None
+        (x, _, ks, vs), _ = _scan(body, (x, jnp.int32(0), cache["k"],
+                                         cache["v"]), params["blocks"], cfg)
+        cache = {"k": ks, "v": vs}
 
     elif cfg.family == "moe":
         period = cfg.moe_every
@@ -361,19 +518,14 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
                 def attn_branch(args):
                     x, skv = args
                     site = (idx + 1) // cfg.attn_every - 1
-                    ck = jax.lax.dynamic_index_in_dim(skv["k"], site, 0, False)
-                    cv = jax.lax.dynamic_index_in_dim(skv["v"], site, 0, False)
                     h = L.rmsnorm(shared["ln1"], x, cfg.norm_eps)
                     o, ck, cv = L.attention_decode(shared["attn"], h, cfg,
-                                                   ck, cv, pos)
+                                                   skv["k"], skv["v"], pos,
+                                                   layer=site)
                     x = x + o
                     x = x + L.mlp_apply(
                         shared["mlp"], L.rmsnorm(shared["ln2"], x, cfg.norm_eps))
-                    skv = {
-                        "k": jax.lax.dynamic_update_index_in_dim(skv["k"], ck, site, 0),
-                        "v": jax.lax.dynamic_update_index_in_dim(skv["v"], cv, site, 0),
-                    }
-                    return x, skv
+                    return x, {"k": ck, "v": cv}
                 x, skv = jax.lax.cond((idx + 1) % cfg.attn_every == 0,
                                       attn_branch, lambda a: a, (x, skv))
             return (x, idx + 1, skv), st
@@ -394,15 +546,14 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
                 o, st_i = M.mamba2_decode(bp["mamba"], h, st_i, cfg)
                 x = x + o
                 if has_attn and (i + 1) % cfg.attn_every == 0:
-                    ck, cv = skv["k"][site], skv["v"][site]
                     h = L.rmsnorm(shared["ln1"], x, cfg.norm_eps)
                     o, ck, cv = L.attention_decode(shared["attn"], h, cfg,
-                                                   ck, cv, pos)
+                                                   skv["k"], skv["v"], pos,
+                                                   layer=site)
                     x = x + o
                     x = x + L.mlp_apply(shared["mlp"],
                                         L.rmsnorm(shared["ln2"], x, cfg.norm_eps))
-                    skv = {"k": skv["k"].at[site].set(ck),
-                           "v": skv["v"].at[site].set(cv)}
+                    skv = {"k": ck, "v": cv}
                     site += 1
                 sts.append(st_i)
             st = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *sts)
@@ -451,7 +602,48 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
     else:
         raise ValueError(cfg.family)
 
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed_apply(params["embed"], x, cfg.vocab_size,
-                             L.dtype_of(cfg.logits_dtype))[:, 0, :]
-    return logits, cache
+    return _unembed(params, cfg, x)[:, 0, :], cache
+
+
+# ---------------------------------------------------------------------------
+# prefill: a whole prompt in one forward pass
+# ---------------------------------------------------------------------------
+def prefills(cfg: ModelConfig) -> bool:
+    """Whether ``prefill`` covers this config's stack; the others fill a
+    cache by teacher-forced ``decode_step`` calls."""
+    return bool(cfg.layer_types) or cfg.family in ("dense", "audio", "vlm")
+
+
+def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+            cache: Params, start=0) -> Tuple[jnp.ndarray, Params]:
+    """One forward pass over prompts ``tokens`` (B, S), each from position
+    0, that fills rows ``start`` .. ``start + B - 1`` of ``cache``
+    (``init_cache``'s layout, ``max_seq >= S``): the attention layers'
+    K/V rows 0 .. S-1, each Mamba layer's state after S tokens (the scan's
+    final h and the last CONV_W-1 rows of xBC).  The prompt is padded to a
+    multiple of PREFILL_BLOCK positions, which attention's causal mask and
+    a zero dt at the pads keep out of every result.  Returns (logits
+    (B, S, V) fp32, cache)."""
+    if not prefills(cfg):
+        raise NotImplementedError(f"{cfg.name}: prefill covers layer patterns "
+                                  "and the dense family")
+    b, s = tokens.shape
+    sp = -(-s // PREFILL_BLOCK) * PREFILL_BLOCK
+    x = shard_hint(_embed(params, cfg, jnp.pad(tokens, ((0, 0), (0, sp - s)))),
+                   ("batch", "seq", "embed"))
+    positions = jnp.broadcast_to(jnp.arange(sp)[None, :], (b, sp))
+    if cfg.layer_types:
+        def body(x, bp):
+            return _pattern_period(bp, x, cfg, positions, s)
+    else:
+        def body(x, bp):
+            return _dense_layer(bp, x, cfg, positions)
+    x, layers = _scan(body, x, params["blocks"], cfg)
+
+    def put(path, c, new):                 # new: (layers, B, ...) of a leaf
+        if getattr(path[-1], "key", None) in ("k", "v"):
+            new = new[:, :, :s]
+        return jax.lax.dynamic_update_slice(
+            c, new.astype(c.dtype), (0, start) + (0,) * (c.ndim - 2))
+    cache = jax.tree_util.tree_map_with_path(put, cache, layers)
+    return _unembed(params, cfg, x[:, :s]), cache

@@ -11,7 +11,7 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import decode_attention_rows
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.matmul_pom import matmul
@@ -99,6 +99,13 @@ def test_flash_attention_prefill_suffix_alignment():
 # --------------------------------------------------------------------------
 # decode attention
 # --------------------------------------------------------------------------
+def _rows(c):
+    """A cache by head (B, Hkv, S, D) as the decode cache's rows
+    (B, S, Hkv*D)."""
+    b, hkv, s, d = c.shape
+    return c.transpose(0, 2, 1, 3).reshape(b, s, hkv * d)
+
+
 @pytest.mark.parametrize("hq,hkv,s", [(4, 4, 256), (8, 2, 512)])
 def test_decode_attention(hq, hkv, s):
     b, d = 2, 64
@@ -106,7 +113,8 @@ def test_decode_attention(hq, hkv, s):
     q = jnp.asarray(rng.normal(size=(b, hq, d)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, hkv, s, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, hkv, s, d)), jnp.float32)
-    got = decode_attention(q, k, v, bkv=128, interpret=True)
+    got = decode_attention_rows(q, _rows(k), _rows(v), bkv=128,
+                                interpret=True)
     want = ref.decode_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -119,7 +127,8 @@ def test_decode_attention_ragged_lengths():
     k = jnp.asarray(rng.normal(size=(b, hkv, s, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, hkv, s, d)), jnp.float32)
     length = jnp.array([17, 256, 130], jnp.int32)
-    got = decode_attention(q, k, v, length=length, bkv=64, interpret=True)
+    got = decode_attention_rows(q, _rows(k), _rows(v), length=length,
+                                bkv=64, interpret=True)
     want = ref.decode_attention(q, k, v, length=length)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)

@@ -76,7 +76,7 @@ def test_decode_step_shapes(arch):
 
 
 @pytest.mark.parametrize("arch", ["smollm_360m", "zamba2_1_2b", "xlstm_1_3b",
-                                  "granite_moe_1b"])
+                                  "granite_moe_1b", "granite_4_0_h_micro"])
 def test_prefill_decode_consistency(arch):
     """Greedy decode after teacher-forced prefill must match full forward."""
     cfg = reduced(get_config(arch))
@@ -108,6 +108,7 @@ def test_param_counts_in_band():
         "granite_moe_1b": (0.8e9, 1.8e9),
         "xlstm_1_3b": (0.8e9, 2.0e9),
         "phi3_vision_4_2b": (3.3e9, 5e9),
+        "granite_4_0_h_micro": (3.0e9, 3.4e9),
     }
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()

@@ -13,6 +13,7 @@ the fixture skips.  ``pallas_interpret()`` sees the CPU here, so each
 test asks for the compiled kernels itself.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,14 +101,14 @@ SMOLLM = dict(b=4, hq=15, hkv=5, d=64, s=256)
 
 
 def test_decode_attention_compiles_at_smollm_width(one_chip):
-    from repro.kernels.decode_attention import decode_attention
+    from repro.kernels.decode_attention import decode_attention_rows
     b, hq, hkv, d, s = (SMOLLM[k] for k in ("b", "hq", "hkv", "d", "s"))
     bf = jnp.bfloat16
-    f = jax.jit(lambda q, k, v, n: decode_attention(
+    f = jax.jit(lambda q, k, v, n: decode_attention_rows(
         q, k, v, length=n, bkv=128, interpret=False))
     c = f.lower(_sds((b, hq, d), bf, one_chip),
-                _sds((b, hkv, s, d), bf, one_chip),
-                _sds((b, hkv, s, d), bf, one_chip),
+                _sds((b, s, hkv * d), bf, one_chip),
+                _sds((b, s, hkv * d), bf, one_chip),
                 _sds((b,), jnp.int32, one_chip)).compile()
     assert c.as_text().count(KERNEL) == 1
 
@@ -189,3 +190,91 @@ def test_smollm_decode_step_on_a_4x1_mesh(data_mesh, monkeypatch):
     text = c.as_text()
     assert text.count(KERNEL) == 1
     assert "all-gather" not in text and "all-to-all" not in text
+
+
+def test_ssm_scan_compiles_at_granite_width(one_chip):
+    """The chunked scan at granite-4.0-h-micro's Mamba-2 widths (64 heads
+    of 64, state 128, one group) over a 4096-token prefill, with the
+    chunk POM's schedule picks."""
+    from repro.kernels import ops
+    import repro.kernels.ssm_scan as ss
+    b, s, h, p, g, n = 1, 4096, 64, 64, 1, 128
+    f32 = jnp.float32
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ss, "pallas_interpret", lambda: False)
+        f = jax.jit(lambda x, a, bb, c: ops.ssm_scan(x, a, bb, c,
+                                                     impl="pallas"))
+        c = f.lower(_sds((b, s, h, p), f32, one_chip),
+                    _sds((b, s, h), f32, one_chip),
+                    _sds((b, s, g, n), f32, one_chip),
+                    _sds((b, s, g, n), f32, one_chip)).compile()
+    assert c.as_text().count(KERNEL) == 1
+
+
+@pytest.fixture(scope="module")
+def granite_step(one_chip):
+    """The published-width granite-4.0-h-micro decode step of 32 requests
+    at context 4096, jitted as the benchmark's program jits it (K/V and
+    the buffer the new SSM state goes to donated, the prefill's state
+    read), compiled: (compiled, K/V and state shapes)."""
+    import repro.kernels.decode_attention as da
+    from repro.configs.base import get_config
+    from repro.models import decode_step, init_cache, init_params
+    cfg = dataclasses.replace(get_config("granite_4_0_h_micro"),
+                              use_pallas=True)
+    on = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: _sds(x.shape, x.dtype, one_chip), t)
+    params = on(jax.eval_shape(lambda k: init_params(k, cfg),
+                               jax.random.key(0)))
+    cache = on(jax.eval_shape(lambda: init_cache(cfg, 32, 4096)))
+    kv = {n: c for n, c in cache.items() if "k" in c}
+    state = {n: c for n, c in cache.items() if "k" not in c}
+
+    def step(params, rw, state, token, pos):
+        logits, new = decode_step(params, cfg, {**rw[0], **state}, token, pos)
+        return logits, ({n: new[n] for n in rw[0]},
+                        {n: new[n] for n in state})
+    tok = _sds((32,), jnp.int32, one_chip)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(da, "pallas_interpret", lambda: False)
+        c = jax.jit(step, donate_argnums=(1,), keep_unused=True).lower(
+            params, (kv, state), state, tok, tok).compile()
+    return c, kv, state
+
+
+def test_granite_decode_step_writes_its_cache_in_place(granite_step):
+    """The granite step: one kernel, every donated byte aliased to an
+    output, no copy of a cache (the K/V rows and the state are laid out
+    as the chip keeps them), and it fits the chip."""
+    c, kv, state = granite_step
+    text = c.as_text()
+    assert text.count(KERNEL) == 1
+    mem = c.memory_analysis()
+    donated = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves((kv, state)))
+    assert mem.alias_size_in_bytes == donated
+    # a layer's K/V or SSM state is 67 MB or more; no copy comes near it
+    item = {"f32": 4, "bf16": 2, "s32": 4}
+    for m in re.finditer(r"= (f32|bf16|s32)\[([\d,]*)\]\S* copy\(", text):
+        size = item[m.group(1)] * int(np.prod([int(d) for d in
+                                               m.group(2).split(",") if d]))
+        assert size < 2 ** 24, m.group(0)
+    assert mem.temp_size_in_bytes < 2 ** 28
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+
+
+def test_granite_state_updates_carry_the_mixer_scope(granite_step):
+    """Each Mamba layer's state update is one fusion whose root is the
+    layer scan's write; the mixer's ops fused into it carry
+    ``mamba2_decode`` in their op_name, so a reader of the trace can
+    find the state update by the computation each device op calls."""
+    from repro.models.mamba2 import DECODE_SCOPE
+    text = granite_step[0].as_text()
+    calls = re.findall(r"= f32\[4,32,64,64,128\]\S* fusion\(.*?calls=(%[\w.]+)",
+                       text)
+    # the nine Mamba positions of the period, each over the four repeats
+    assert len(calls) == 9
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"\n(%[\w.]+) [^\n]*\{\n(.*?)\n\}", text, re.S)}
+    for name in calls:
+        assert f"/{DECODE_SCOPE}/" in bodies[name], name
