@@ -18,8 +18,9 @@ schedules tiled to the (8, 128) layout reach Mosaic.
 
 ``PallasProgram.jitted()`` / ``batched(B)`` trace the whole loop AST into
 one XLA computation: a nest whose statement lowers as above runs its
-kernel, every other nest is vectorized (unit-stride accesses as slices,
-the rest as gathers and scatters, then reductions), or becomes a
+kernel, every other nest is vectorized (unit-stride and mixed-radix
+digit windows, such as a split loop's ``32*i_o + i_u``, as slices; the
+rest as gathers and scatters; then reductions), or becomes a
 ``fori_loop``.  Whether Pallas runs compiled or interpreted is
 ``repro.runtime.pallas_interpret()``: interpreted iff the backend is the CPU.
 """
@@ -583,43 +584,69 @@ def _vec_plan(node) -> Optional[Tuple]:
     return chain, sn, kept, red, rest_body
 
 
+def _digits(e: LinExpr, where: Dict) -> Optional[List[Tuple[int, int, int]]]:
+    """The chain vars of one index position as mixed-radix digits of one
+    contiguous range, ``[(chain axis, coefficient, extent), ...]`` most
+    significant first (``[]`` for a point), else None.
+
+    Sorted by |coefficient|, the smallest must be 1 and each next must be
+    the product of the extents below it (``32*i_o + i_u`` with ``i_u`` in
+    0..31), and every coefficient has the position's one sign."""
+    vs = sorted((v for v in e.vars() if v in where),
+                key=lambda v: abs(e.coeff(v)))
+    digits: List[Tuple[int, int, int]] = []
+    radix = 1
+    for v in vs:
+        c = e.coeff(v)
+        ax, lo, hi = where[v]
+        if abs(c) != radix or (digits and (c > 0) != (digits[0][1] > 0)):
+            return None
+        digits.append((ax, c, hi - lo + 1))
+        radix *= hi - lo + 1
+    return digits[::-1]
+
+
 def _slice_access(idx: Tuple[LinExpr, ...], chain, shape: Tuple[int, ...],
                   env: Dict) -> Optional[Tuple[List, List[int], List]]:
     """Classify one access of a vectorized nest for the slice path.
 
-    Each index position must be a *window* (exactly one ``chain`` var with
-    coefficient +1 or -1, plus a constant and outer ``env`` terms) or a
-    *point* (no chain var), and no chain var may index two positions.
-    Returns ``(starts, sizes, axes)`` per position: the first array index
-    the window covers, its extent, and ``(chain axis, sign)`` (None for a
-    point).  Returns None, so the access keeps its index grids, for any
-    other access or when a start known at trace time puts the window
-    outside the array."""
+    Each index position must be a *window* (chain vars that are the dense
+    mixed-radix digits of one contiguous range, ``_digits``: one var with
+    coefficient +1 or -1, or a split loop's ``32*i_o + i_u``; plus a
+    constant and outer ``env`` terms) or a *point* (no chain var), and no
+    chain var may index two positions.  Returns ``(starts, sizes, axes)``
+    per position: the first array index the window covers, its extent,
+    and its digits ``[(chain axis, coefficient, extent), ...]`` most
+    significant first (``[]`` for a point).  Returns None, so the access
+    keeps its index grids, for any other access or when a start known at
+    trace time puts the window outside the array."""
     where = {v: (ax, lo, hi) for ax, (v, lo, hi) in enumerate(chain)}
     starts: List = []
     sizes: List[int] = []
     axes: List = []
+    seen = set()
     for e, dim in zip(idx, shape):
-        vs = [v for v in e.vars() if v in where]
-        if len(vs) > 1:
+        digits = _digits(e, where)
+        if digits is None or any(d[0] in seen for d in digits):
             return None
-        if vs:
-            c = e.coeff(vs[0])
-            ax, lo, hi = where[vs[0]]
-            if abs(c) != 1 or any(a and a[0] == ax for a in axes):
-                return None
-            e = e.substitute(vs[0], LinExpr.cst(lo if c > 0 else hi))
-            axes.append((ax, c))
-            sizes.append(hi - lo + 1)
-        else:
-            axes.append(None)
-            sizes.append(1)
+        for ax, c, _ in digits:
+            seen.add(ax)
+            v, lo, hi = chain[ax]
+            e = e.substitute(v, LinExpr.cst(lo if c > 0 else hi))
+        axes.append(digits)
+        sizes.append(math.prod(n for _, _, n in digits))
         start = _lin_val(e, env)
         if sizes[-1] > dim or (isinstance(start, int)
                                and not 0 <= start <= dim - sizes[-1]):
             return None
         starts.append(start)
     return starts, sizes, axes
+
+
+def _split_window(acc) -> bool:
+    """Whether a ``_slice_access`` window has a position of two or more
+    digits."""
+    return any(len(a) > 1 for a in acc[2])
 
 
 def _window(buf, starts: List, sizes: List[int]):
@@ -632,14 +659,18 @@ def _window(buf, starts: List, sizes: List[int]):
 
 def _load_window(buf, acc, chain):
     """A slice access's values shaped like its index-grid gather: one axis
-    per chain var in chain order, of size 1 where the access omits it."""
+    per chain var in chain order, of size 1 where the access omits it.
+
+    A position whose digits count down is reversed whole; reversing the
+    range reverses each of its digits, since they share one sign."""
     starts, sizes, axes = acc
     w = _window(buf, starts, sizes)
-    rev = [p for p, a in enumerate(axes) if a and a[1] < 0]
+    rev = [p for p, a in enumerate(axes) if a and a[0][1] < 0]
     if rev:
         w = lax.rev(w, rev)
-    used = [a[0] for a in axes if a]
-    w = w.reshape([sizes[p] for p, a in enumerate(axes) if a])
+    digits = [d for a in axes for d in a]
+    used = [ax for ax, _, _ in digits]
+    w = w.reshape([n for _, _, n in digits])
     order = sorted(range(len(used)), key=used.__getitem__)
     if order != list(range(len(used))):
         w = w.transpose(order)
@@ -652,11 +683,12 @@ def _store_window(val, acc):
     position of the store) brought to the store window's array order:
     the inverse of ``_load_window``."""
     _, sizes, axes = acc
-    win = [a for a in axes if a]
-    perm = [ax for ax, _ in win]
+    perm = [ax for a in axes for ax, _, _ in a]
     if perm != sorted(perm):
         val = val.transpose(perm)
-    rev = [k for k, (_, c) in enumerate(win) if c < 0]
+    win = [a for a in axes if a]
+    val = val.reshape([math.prod(n for _, _, n in a) for a in win])
+    rev = [k for k, a in enumerate(win) if a[0][1] < 0]
     if rev:
         val = lax.rev(val, rev)
     return val.reshape(sizes)
@@ -668,13 +700,17 @@ def _run_vectorized(plan, bufs: Dict, env: Dict) -> Dict:
 
     An access whose every index position is a window or a point
     (``_slice_access``) is a rectangular window of its array: a load
-    takes it with ``lax.slice`` / ``lax.dynamic_slice``, and the store
-    writes it back with ``lax.dynamic_update_slice`` (an accumulator adds
-    into the window first, which equals a scatter-add because
-    ``_vec_plan`` proves the store injective).  Every other access indexes
-    its array with broadcast iota grids, a gather, and every other store
-    scatters.  Each access counts once per trace in ``vec.slice_access``
-    or ``vec.gather_access``."""
+    takes it with ``lax.slice`` / ``lax.dynamic_slice``, reshaped into the
+    digits of a split position (``32*i_o + i_u`` → ``(128, 32)``) and
+    transposed into chain order, and the store writes it back through the
+    inverse with ``lax.dynamic_update_slice`` (an accumulator adds into
+    the window first, which equals a scatter-add because ``_vec_plan``
+    proves the store injective).  Every other access (a conv's ``y + r``,
+    ``4*i + j`` with ``j`` in 0..1, a diagonal) indexes its array with
+    broadcast iota grids, a gather, and every other store scatters.  Each
+    access counts once per trace in ``vec.slice_access`` or
+    ``vec.gather_access``, and a window with a position of two or more
+    digits in ``vec.digit_window_access`` too."""
     chain, sn, kept, red, rest_body = plan
     arr, store_idx, by_id = _stmt_accesses(sn)
     shape = tuple(hi - lo + 1 for _, lo, hi in chain)
@@ -686,11 +722,13 @@ def _run_vectorized(plan, bufs: Dict, env: Dict) -> Dict:
 
     loads = loads_of(rest_body if red else sn.stmt.body)
     loaded = {}
+    n_digit = 0
     for ld in loads:
         a, idx = by_id[id(ld)]
         acc = _slice_access(idx, chain, bufs[a.name].shape, env)
         if acc is not None:
             loaded[id(ld)] = _load_window(bufs[a.name], acc, chain)
+            n_digit += _split_window(acc)
     n_slice = len(loaded)
     n_gather = len(loads) - n_slice
 
@@ -708,8 +746,10 @@ def _run_vectorized(plan, bufs: Dict, env: Dict) -> Dict:
         sidx = tuple(_lin_val(e, kenv) for e in store_idx)
     else:
         n_slice += 1
+        n_digit += _split_window(sacc)
     telemetry.counter("vec.slice_access").inc(n_slice)
     telemetry.counter("vec.gather_access").inc(n_gather)
+    telemetry.counter("vec.digit_window_access").inc(n_digit)
 
     bufs = dict(bufs)
     if red:
@@ -750,10 +790,10 @@ def _build_step(fn: Function, ast, interpret: bool):
     """Trace the loop AST into ``step(bufs) -> bufs`` (pure, jit-able).
 
     Statement nests are vectorized where legal (``_run_vectorized``:
-    unit-stride accesses as slices and ``dynamic_update_slice``, any other
-    access through index-grid gathers and scatters); compiled
-    (``interpret=False``), a nest whose statement lowers to a contraction
-    kernel runs that ``pallas_call`` instead.
+    unit-stride and mixed-radix digit windows as slices and
+    ``dynamic_update_slice``, any other access through index-grid gathers
+    and scatters); compiled (``interpret=False``), a nest whose statement
+    lowers to a contraction kernel runs that ``pallas_call`` instead.
     Raises ``TraceError`` (possibly only at trace time) when some
     construct has no JAX rendition.
     """

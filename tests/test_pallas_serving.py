@@ -302,6 +302,89 @@ def test_unit_stride_accesses_lower_to_slices(name):
             rtol=1e-4, atol=1e-4, err_msg=f"{name}:{k}")
 
 
+def _dse_gemm():
+    return workloads.gemm(64), {"dse": True}
+
+
+def _reversed_split_fn(n=12):
+    """A -1 coefficient through a hand split: ``A(n-1-i, j)`` and
+    ``B(j, n-1-i)`` with ``i = 4*i0 + i1``; the value of ``i`` itself
+    pins each element to its row."""
+    with pom.function("reversed_split") as f:
+        i, j = pom.var("i", 0, n), pom.var("j", 0, 5)
+        A = pom.placeholder("A", (n, 5))
+        B = pom.placeholder("B", (5, n))
+        s = pom.compute("rev", [i, j], 2.0 * A(n - 1 - i, j) + i,
+                        B(j, n - 1 - i))
+    s.split("i", 4, "i0", "i1")
+    return f, {}
+
+
+def _sparse_digits_fn(n=6):
+    """``A(4*i + j)`` with ``j`` in 0..1: two vars that do not tile one
+    range."""
+    with pom.function("sparse_digits") as f:
+        i, j = pom.var("i", 0, n), pom.var("j", 0, 2)
+        A = pom.placeholder("A", (4 * n,))
+        B = pom.placeholder("B", (n, 2))
+        pom.compute("pick", [i, j], A(4 * i + j) + 1.0, B(i, j))
+    return f, {}
+
+
+def _mixed_signs_fn(n=6):
+    """``A(4*i - j + 3)``: dense digits of opposite signs."""
+    with pom.function("mixed_signs") as f:
+        i, j = pom.var("i", 0, n), pom.var("j", 0, 4)
+        A = pom.placeholder("A", (4 * n,))
+        B = pom.placeholder("B", (n, 4))
+        pom.compute("pick", [i, j], A(4 * i - j + 3) + 1.0, B(i, j))
+    return f, {}
+
+
+# name -> (program and compile options,
+#          (slice, gather, digit window) accesses counted per trace)
+_DIGIT_CASES = {
+    "dse_gemm": (_dse_gemm, (3, 0, 2)),
+    "reversed_split": (_reversed_split_fn, (2, 0, 2)),
+    "sparse_digits": (_sparse_digits_fn, (1, 1, 0)),
+    "mixed_signs": (_mixed_signs_fn, (1, 1, 0)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_DIGIT_CASES))
+def test_split_positions_lower_to_digit_windows(name):
+    """An index position whose loop vars are the dense mixed-radix digits
+    of one range (a DSE split's ``32*i_o + i_u``, a hand split through a
+    -1 coefficient) is a window: one trace counts it in
+    ``vec.digit_window_access`` and the step has no gather or scatter.
+    Digits that leave holes (``4*i + j``, ``j`` in 0..1) or mix signs
+    keep their gather.  Each equals the oracle."""
+    import jax
+    from repro.core import telemetry
+    make, counts = _DIGIT_CASES[name]
+    f, opts = make()
+    prog = pcompile(f.fn, target="pallas", interpret=False, **opts)
+    assert prog.traceable()
+    spec = {p.name: jax.ShapeDtypeStruct(p.shape, np.float32)
+            for p in f.fn.placeholders.values()}
+    names = ("vec.slice_access", "vec.gather_access",
+             "vec.digit_window_access")
+    before = telemetry.REGISTRY.counter_values("vec.")
+    jaxpr = str(jax.make_jaxpr(bp._build_step(f.fn, prog.ast, False))(spec))
+    after = telemetry.REGISTRY.counter_values("vec.")
+    assert tuple(after.get(k, 0) - before.get(k, 0) for k in names) == counts
+    assert "scatter" not in jaxpr
+    assert ("gather" in jaxpr) == bool(counts[1])
+    arrs = _inputs(f.fn)
+    got = prog.jitted()(dict(arrs))
+    ref = compile_jax(f.fn, build_ast(f.fn))(
+        {k: np.asarray(v, dtype=np.float64) for k, v in arrs.items()})
+    for k in _outputs(f.fn):
+        np.testing.assert_allclose(
+            np.asarray(got[k], dtype=np.float64), ref[k],
+            rtol=1e-4, atol=1e-4, err_msg=f"{name}:{k}")
+
+
 def test_slice_access_counters_per_trace():
     """One trace of the gaussian step counts its 9 loads and 1 store as
     slice accesses and none as gather accesses."""
@@ -318,6 +401,8 @@ def test_slice_access_counters_per_trace():
     assert after["vec.slice_access"] - before.get("vec.slice_access", 0) == 10
     assert after.get("vec.gather_access", 0) \
         - before.get("vec.gather_access", 0) == 0
+    assert after.get("vec.digit_window_access", 0) \
+        - before.get("vec.digit_window_access", 0) == 0
 
 
 def test_service_pallas_runner_caches_executors(tmp_path):

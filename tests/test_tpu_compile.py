@@ -97,6 +97,20 @@ def test_gaussian_4096_step_has_no_gather_scatter_or_sort(one_chip):
         assert f" {op}(" not in text, op
 
 
+def test_dse_gemm_4096_step_has_no_gather_scatter_or_sort(one_chip):
+    """The DSE splits ``i`` into ``32*i_o + i_u``: ``A``'s load and the
+    accumulator store of ``C`` are digit windows, so the chip's compiler
+    gets slices, relayouts and a dynamic-update-slice, no gather, scatter
+    or sort, and the multiply-reduce keeps the nest's iteration shape."""
+    prog = pcompile(workloads.gemm(4096).fn, target="pallas",
+                    interpret=False, dse=True)
+    text = _step_text(prog, one_chip)
+    assert KERNEL not in text
+    assert "f32[4096,128,32]" in text
+    for op in ("gather", "scatter", "sort"):
+        assert f" {op}(" not in text, op
+
+
 SMOLLM = dict(b=4, hq=15, hkv=5, d=64, s=256)
 
 
