@@ -47,10 +47,8 @@ Dataflow columns: per workload, the DSE'd designs are re-aggregated under
 (streaming task graph), recording summed latency and BRAM18 per mode plus
 the number of applied regions — the latency/BRAM price of task overlap.
 
-Search-strategy columns (PR 3, widened for the parallel beam): each
-workload is additionally searched with ``greedy``, ``beam:2``,
-``beam:4``, the pooled ``beam:8:parallel``, and ``parallel:2``,
-recording wall-seconds *and* best design cost (summed report latency),
+Search-strategy columns: each workload is additionally searched with
+``greedy``, ``beam:2``, ``beam:4`` and ``beam:8``, recording wall-seconds *and* best design cost (summed report latency),
 so the snapshot tracks search **quality** alongside search speed.  Each
 strategy wall is the best (min) of ``STRATEGY_REPEATS`` cold-cache
 repeats, interleaved round-robin across strategies so machine drift
@@ -59,10 +57,8 @@ lands evenly — and the ``beam_scaling`` ratio (``beam8`` wall /
 instead of the naive ~8x it sits near 1x where sibling states collapse
 onto shared rungs (gemm) and ~2-3x where the eight states genuinely
 diverge (3mm evaluates ~6x the rungs of ``beam:1``; the
-transformed-node/whole-design caches absorb the rest).  The snapshot
-records the host ``cpus``: on a multi-core box the pooled wave
-dispatches states concurrently on top of that; on one core it degrades
-to the bit-identical serial wave.  The ``fusion_prepass`` section runs graph-level fusion
+transformed-node/whole-design caches absorb the rest).  The
+``fusion_prepass`` section runs graph-level fusion
 (``graph_passes=("fuse",)``) ahead of DSE on the multi-statement
 workloads and records the final cost against the default flow, where
 stage 1 distributes conflicting fusion groups and conservatively
@@ -140,17 +136,12 @@ def _run_workload(builders: List[Callable], max_parallel: int,
             "actions": actions, "latencies": latencies}
 
 
-# search strategies measured per workload: label -> auto_dse kwargs.
-# ``beam8`` runs the *pooled* wave beam (``beam:8:parallel``) — on a box
-# where the pool cannot win (single core, or fork unavailable) the
-# evaluator falls back to the serial wave, which is bit-identical by
-# construction, so the column is always the pooled spec's honest wall.
+# search strategies measured per workload: label -> auto_dse kwargs
 STRATEGY_SPECS: List[Tuple[str, Dict]] = [
     ("greedy", {}),
     ("beam2", {"strategy": "beam", "beam_width": 2}),
     ("beam4", {"strategy": "beam:4"}),
-    ("beam8", {"strategy": "beam:8:parallel"}),
-    ("parallel2", {"strategy": "parallel", "workers": 2}),
+    ("beam8", {"strategy": "beam:8"}),
 ]
 
 STRATEGY_REPEATS = 3
@@ -166,7 +157,8 @@ def _measure_strategies(builders: List[Callable],
 
     Each strategy row also carries a ``telemetry`` column summed from the
     per-run ``report.telemetry`` snapshots (see ``dse.auto_dse``): fresh
-    analysis evals, cross-state dedup credits, and pool retry count.
+    analysis evals, cross-state dedup credits, and the bound-and-confirm
+    pair.
     Counters are deterministic across cold-cache repeats, so the last
     repeat's sums stand for all of them."""
     out: Dict[str, Dict] = {}
@@ -182,8 +174,7 @@ def _measure_strategies(builders: List[Callable],
             cost = 0
             resources: Dict[str, float] = {}
             tel = {"analysis_evals": 0, "dedup_credits": 0,
-                   "pool_retries": 0, "confirmed_evals": 0,
-                   "pruned_candidates": 0}
+                   "confirmed_evals": 0, "pruned_candidates": 0}
             t0 = time.perf_counter()
             for build in builders:
                 res = auto_dse(build(), max_parallel=max_parallel, **kw)
@@ -194,8 +185,6 @@ def _measure_strategies(builders: List[Callable],
                 tel["analysis_evals"] += t.get("analysis_evals", 0)
                 tel["dedup_credits"] += (t.get("wave") or {}).get(
                     "cands_credited", 0)
-                tel["pool_retries"] += (t.get("pool") or {}).get(
-                    "retries", 0)
                 tel["confirmed_evals"] += (t.get("cost") or {}).get(
                     "confirmed_evals", 0)
                 tel["pruned_candidates"] += (t.get("cost") or {}).get(
@@ -211,8 +200,6 @@ def _measure_strategies(builders: List[Callable],
         out["beam2"]["best_cost"] <= out["greedy"]["best_cost"]
         and out["beam4"]["best_cost"] <= out["greedy"]["best_cost"]
         and out["beam8"]["best_cost"] <= out["greedy"]["best_cost"])
-    out["parallel_identical_to_greedy"] = (
-        out["parallel2"]["best_cost"] == out["greedy"]["best_cost"])
     # wall-clock price of widening the beam 8x over the greedy trajectory
     # (cross-state dedup + the transformed-node/whole-design caches are
     # what keep this near 1 instead of near 8)
@@ -381,47 +368,6 @@ def check_against_snapshot(path: str, tolerance: float = 0.10) -> int:
     return failures
 
 
-def beam_microbench(repeats: int = 3) -> Dict:
-    """Multi-core beam validation: wall-clock of the pooled wave beam
-    (``beam:4:parallel:2``) against the serial greedy ladder on the two
-    divergent-state workloads, on whatever host runs it.
-
-    Emits ``BENCH_beam_multicore.json`` (atomic) with the per-workload
-    ``beam_scaling`` ratio and the host ``cpus`` — the CI artifact the
-    ROADMAP's "multi-core validation" item asks for.  On a single-core
-    host the pooled wave degrades to the bit-identical serial wave, so
-    the ratio there measures algorithmic dedup only; with >= 2 cores the
-    wave dispatch should pull divergent-state workloads (3mm, conv_chain)
-    toward 1x."""
-    cases = [("gemm", [lambda: gemm(64).fn], 256),
-             ("3mm", [lambda: mm3(64).fn], 256),
-             ("conv_chain", [lambda: conv_chain(20, (3, 8, 8)).fn], 16)]
-    rows = []
-    for name, builders, mp in cases:
-        walls = {"greedy": [], "beam4_parallel2": []}
-        for _ in range(repeats):
-            for label, kw in (("greedy", {}),
-                              ("beam4_parallel2",
-                               {"strategy": "beam:4:parallel:2"})):
-                caching.clear_all()
-                caching.reset_counts()
-                t0 = time.perf_counter()
-                for build in builders:
-                    auto_dse(build(), max_parallel=mp, **kw)
-                walls[label].append(time.perf_counter() - t0)
-        g = min(walls["greedy"])
-        b = min(walls["beam4_parallel2"])
-        rows.append({"workload": name, "greedy_seconds": round(g, 3),
-                     "beam4_parallel2_seconds": round(b, 3),
-                     "beam_scaling": round(b / max(g, 1e-9), 2)})
-    snap = {"suite": "beam_multicore", "cpus": os.cpu_count(),
-            "results": rows}
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCH_beam_multicore.json")
-    atomic_write_json(path, snap)
-    return snap
-
-
 def main() -> None:
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
@@ -429,11 +375,6 @@ def main() -> None:
                     help="counter-only run, compared against the committed "
                          "BENCH_dse_speed.json; exits non-zero on a >10%% "
                          "analysis-eval or confirmed-eval regression")
-    ap.add_argument("--microbench", action="store_true",
-                    help="multi-core beam wall-clock microbench "
-                         "(beam:4:parallel:2 vs greedy); writes "
-                         "BENCH_beam_multicore.json with beam_scaling + "
-                         "host cpus")
     ap.add_argument("--snapshot", default=os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "BENCH_dse_speed.json"))
@@ -442,10 +383,6 @@ def main() -> None:
     if args.check:
         failures = check_against_snapshot(args.snapshot, args.tolerance)
         raise SystemExit(1 if failures else 0)
-    if args.microbench:
-        snap = beam_microbench()
-        print(json.dumps(snap, indent=2))
-        return
     for line in csv_rows():
         print(line)
 
@@ -458,12 +395,7 @@ def run_fusion_compare() -> List[Dict]:
 def csv_rows() -> List[str]:
     rows = run_all()
     fusion = run_fusion_compare()
-    # the host's core count contextualizes the beam_scaling columns: on a
-    # single-core box the pooled beam degrades to the (bit-identical)
-    # serial wave, so the ratio there measures pure algorithmic dedup,
-    # not parallel dispatch
-    snap = {"suite": "dse_speed", "cpus": os.cpu_count(),
-            "results": rows, "fusion_prepass": fusion}
+    snap = {"suite": "dse_speed", "results": rows, "fusion_prepass": fusion}
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "BENCH_dse_speed.json")
     # atomic: an interrupted run must not corrupt the committed snapshot
@@ -491,7 +423,6 @@ def csv_rows() -> List[str]:
             f"beam8_wall={strat['beam8']['seconds']};"
             f"beam_scaling={strat['beam_scaling']}x;"
             f"beam_le_greedy={strat['beam_cost_le_greedy']};"
-            f"parallel2_identical={strat['parallel_identical_to_greedy']};"
             f"dataflow_lat={df['latency_off']}->{df['latency_on']}"
             f"({df['latency_speedup']}x);"
             f"dataflow_bram18={df['bram18_off']}->{df['bram18_on']};"

@@ -13,35 +13,26 @@ replay traffic shaped like a real service workload:
   cold miss, every repeat a hit.  Reported: hit rate, cold-compile p50,
   hit p50/p99, and the hit speedup (cold p50 / hit p50 — the acceptance
   gate is ≥ 50×, measured runs are O(1000×)).
-* **crash-rate phase** — the same workloads cold-compiled under
-  ``POM_FAULT`` worker crashes at 10% per dispatch (``parallel:2``
-  strategy, seeded so the kill pattern is reproducible).  The supervised
-  pool kills/retries and the search completes with results identical to
-  greedy (asserted); reported: cold p50/p99 with and without the crash
-  rate — the latency price of supervision-and-retry under faults.
 
 ``--check`` is the CI smoke: a small replay trace, asserting the exact
 expected hit rate and the ≥ 50× hit speedup; exits non-zero on failure.
 The full run emits ``BENCH_service.json`` (atomic write) next to the
 repo root.  Latency columns are wall-clock and machine-dependent; the
 ``--check`` gate only tests the machine-independent facts (hit rate,
-hit/cold ratio, fault-run identity).
+hit/cold ratio, hit-report identity).
 """
 from __future__ import annotations
 
 import os
-import time
 from typing import Callable, Dict, List, Tuple
 
-from repro.core import caching, faultinject
+from repro.core import caching
 from repro.core.pipeline import CompileService
 from repro.core.designdb import DesignDB, atomic_write_json
 
 from .workloads import bicg, gemm, mm3
 
 REPLAY = 3          # requests per workload in the replay trace
-CRASH_P = 0.10      # injected worker-crash probability per dispatch
-CRASH_SEED = 7
 
 
 def _trace_workloads(small: bool) -> List[Tuple[str, Callable]]:
@@ -94,52 +85,6 @@ def run_replay(small: bool = False) -> Dict:
     }
 
 
-def _cold_latencies(small: bool, crash: bool) -> Tuple[List[float], bool]:
-    """Cold-compile every workload under parallel:2; optionally with the
-    10% injected worker-crash rate.  Returns latencies + result parity
-    (faulted parallel result == fault-free greedy result)."""
-    lat, identical = [], True
-    spec = (faultinject.install("worker.dispatch", "crash", p=CRASH_P,
-                                seed=CRASH_SEED) if crash else None)
-    try:
-        for _, build in _trace_workloads(small):
-            caching.clear_all()
-            caching.reset_counts()
-            svc = CompileService(db=DesignDB())
-            t0 = time.perf_counter()
-            res = svc.compile_one(build(), max_parallel=64,
-                                  strategy="parallel", workers=2)
-            lat.append(time.perf_counter() - t0)
-            caching.clear_all()
-            caching.reset_counts()
-            ref = CompileService(db=DesignDB()).compile_one(
-                build(), max_parallel=64, strategy="greedy")
-            identical = identical and res.report == ref.report \
-                and res.tile_sizes == ref.tile_sizes
-    finally:
-        faultinject.clear()
-    fired = spec.fires if spec else 0
-    return lat, identical and (not crash or fired >= 0)
-
-
-def run_crash_phase(small: bool = False) -> Dict:
-    import warnings
-    base, base_ok = _cold_latencies(small, crash=False)
-    with warnings.catch_warnings():
-        # worker_failed warnings are the supervision path working as
-        # designed under injected faults; keep the bench output clean
-        warnings.simplefilter("ignore")
-        faulted, fault_ok = _cold_latencies(small, crash=True)
-    return {
-        "crash_rate": CRASH_P,
-        "p50_s": round(_percentile(base, 0.5), 6),
-        "p99_s": round(_percentile(base, 0.99), 6),
-        "crash_p50_s": round(_percentile(faulted, 0.5), 6),
-        "crash_p99_s": round(_percentile(faulted, 0.99), 6),
-        "results_identical_to_greedy": base_ok and fault_ok,
-    }
-
-
 def check(small: bool = True) -> int:
     """CI smoke: machine-independent facts only."""
     failures = 0
@@ -154,14 +99,9 @@ def check(small: bool = True) -> int:
     if not rep["hit_reports_identical"]:
         print("FAIL db-hit report differs from cold compile")
         failures += 1
-    crash = run_crash_phase(small=small)
-    if not crash["results_identical_to_greedy"]:
-        print("FAIL crashed-pool result differs from greedy")
-        failures += 1
     status = "OK" if not failures else "FAIL"
     print(f"bench_service --check {status}: hit_rate={rep['hit_rate']} "
-          f"hit_speedup={rep['hit_speedup']}x "
-          f"crash_p50={crash['crash_p50_s']}s")
+          f"hit_speedup={rep['hit_speedup']}x")
     return failures
 
 
@@ -170,26 +110,20 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--check", action="store_true",
                     help="small-trace smoke: exact hit rate, >=50x hit "
-                         "speedup, fault-run identity; non-zero exit on "
+                         "speedup, hit-report identity; non-zero exit on "
                          "failure")
     args = ap.parse_args()
     if args.check:
         raise SystemExit(1 if check() else 0)
-    snap = {"suite": "service",
-            "replay": run_replay(),
-            "crash_phase": run_crash_phase()}
+    snap = {"suite": "service", "replay": run_replay()}
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "BENCH_service.json")
     atomic_write_json(path, snap)
-    rep, crash = snap["replay"], snap["crash_phase"]
+    rep = snap["replay"]
     print(f"service/replay,{rep['requests']} req,"
           f"hit_rate={rep['hit_rate']};cold_p50={rep['cold_p50_s']}s;"
           f"hit_p50={rep['hit_p50_s']}s;hit_p99={rep['hit_p99_s']}s;"
           f"hit_speedup={rep['hit_speedup']}x")
-    print(f"service/crash_rate_{CRASH_P},parallel:2,"
-          f"p50={crash['p50_s']}s->{crash['crash_p50_s']}s;"
-          f"p99={crash['p99_s']}s->{crash['crash_p99_s']}s;"
-          f"identical={crash['results_identical_to_greedy']}")
 
 
 if __name__ == "__main__":

@@ -39,7 +39,7 @@ ENABLED: bool = True
 ANALYTIC: bool = os.environ.get("POM_ANALYTIC_TRANSFER", "1") != "0"
 
 # Bound-and-confirm rung evaluation (branch-and-bound over a rung's
-# candidate set): when on, the evaluators order candidates by an admissible
+# candidate set): when on, the evaluator orders candidates by an admissible
 # closed-form latency lower bound and confirm with a full ``node_report``
 # only those whose bound could still beat the best confirmed bottleneck
 # latency.  The bound rides on the same ``ClosedFormII`` sweep the analytic
@@ -105,13 +105,12 @@ def clear_all() -> None:
     from .affine import _DEPVEC_CACHE, _INTERN
     from .ir import _TRIP_CANON_CACHE
     from .transforms import _LEGAL_CACHE
-    from .cost_model import _REC_II_CACHE, _REC_II_XFER
+    from .cost_model import _REC_II_CACHE
     _DEPVEC_CACHE.clear()
     _INTERN.clear()
     _TRIP_CANON_CACHE.clear()
     _LEGAL_CACHE.clear()
     _REC_II_CACHE.clear()
-    _REC_II_XFER.clear()
     from .graph_ir import (_EDGE_CACHE, _FUSION_CACHE, _SKELETON_CACHE,
                            _TASKGRAPH_CACHE)
     _TASKGRAPH_CACHE.clear()
@@ -128,103 +127,8 @@ def clear_all() -> None:
         pallas._LOWER_CACHE.clear()
 
 
-# --------------------------------------------------------------------------
-# counter / memo merge API (parallel candidate evaluation, PR 3)
-# --------------------------------------------------------------------------
-# The process-global name-canonical memo tables, by short name.  Worker
-# processes (forked by ``search.PoolEvaluator``) compute new entries that
-# the parent merges back deterministically; per-statement and per-model
-# caches are handled by ``search`` on top of this API.
-def global_memo_tables() -> Dict[str, dict]:
-    from .affine import _DEPVEC_CACHE
-    from .cost_model import _REC_II_CACHE
-    from .ir import _TRIP_CANON_CACHE
-    from .transforms import _LEGAL_CACHE
-    return {"trip_canon": _TRIP_CANON_CACHE, "legal": _LEGAL_CACHE,
-            "depvec": _DEPVEC_CACHE, "rec_ii": _REC_II_CACHE}
-
-
-def snapshot_memo_keys() -> Dict[str, set]:
-    """Key sets of every global memo table (delta baseline)."""
-    return {name: set(table) for name, table in global_memo_tables().items()}
-
-
-def memo_delta(before: Dict[str, set]) -> Dict[str, Dict]:
-    """Entries added to the global memo tables since ``before``."""
-    out: Dict[str, Dict] = {}
-    for name, table in global_memo_tables().items():
-        old = before.get(name, ())
-        new = {k: v for k, v in table.items() if k not in old}
-        if new:
-            out[name] = new
-    return out
-
-
-def global_xfer_sets() -> Dict[str, set]:
-    """Origin markers for analytic-transfer entries in the global memos.
-
-    ``rec_ii`` entries computed by the closed-form (analytic) II path are
-    tracked so the parallel-merge conversion decrements the right counter
-    (``analytic_node_evals`` vs ``full_node_evals``) on a key collision.
-    """
-    from .cost_model import _REC_II_XFER
-    return {"rec_ii": _REC_II_XFER}
-
-
-def merge_memo_delta(delta: Dict[str, Dict],
-                     xfer: Dict[str, set] = None) -> Dict[str, int]:
-    """Merge a worker's new global-memo entries into this process.
-
-    Returns, per table, the number of entries that were *already present*
-    (computed by an earlier-merged candidate): the caller converts those
-    from evaluations into cache hits so merged counters replay exactly
-    what a serial run would have counted.  Signature keys are structural,
-    so on a key collision both sides hold the identical value — insertion
-    order across workers cannot change any result.
-
-    ``xfer`` marks worker entries produced by the analytic-transfer path;
-    their collisions are reported under ``<table>_xfer`` so the caller
-    adjusts the analytic counter instead of the evaluation counter, and
-    fresh ones keep their origin mark in this process.
-    """
-    tables = global_memo_tables()
-    xfer = xfer or {}
-    origin = global_xfer_sets()
-    converted: Dict[str, int] = {}
-    for name, entries in delta.items():
-        table = tables[name]
-        marks = xfer.get(name, ())
-        dup = dup_x = 0
-        for k, v in entries.items():
-            if k in table:
-                if k in marks:
-                    dup_x += 1
-                else:
-                    dup += 1
-            else:
-                table[k] = v
-                if k in marks and name in origin:
-                    origin[name].add(k)
-        converted[name] = dup
-        converted[f"{name}_xfer"] = dup_x
-    # a merged delta must respect the depvec bound too (a tiny
-    # POM_DEPVEC_CACHE_MAX otherwise grows without limit through merges);
-    # results stay bit-identical — eviction only forgets memo entries
-    from . import affine
-    while len(affine._DEPVEC_CACHE) > affine._depvec_cache_limit() > 1:
-        affine._evict_half(affine._DEPVEC_CACHE)
-    return converted
-
-
 def counts_delta(before: Dict[str, int]) -> Dict[str, int]:
     return {k: COUNTS[k] - before.get(k, 0) for k in COUNTS}
-
-
-def merge_counts(delta: Dict[str, int]) -> None:
-    """Fold a worker's counter delta into this process's ``COUNTS``."""
-    for k, v in delta.items():
-        if k in COUNTS:
-            COUNTS[k] += v
 
 
 @contextmanager

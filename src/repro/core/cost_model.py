@@ -41,7 +41,6 @@ counters because they are stable across machines, unlike wall time.
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -164,10 +163,10 @@ class DesignReport:
     feasible: bool
     dataflow: Optional[DataflowReport] = None
     # Per-run telemetry snapshot attached by ``dse.auto_dse`` (analysis
-    # evals, cost-model counters, wave/pool deltas — see
+    # evals, cost-model counters, wave deltas — see
     # ``telemetry.metrics``).  Observational only: excluded from equality
-    # so every bit-identity invariant (serial vs pooled, cached vs
-    # uncached, traced vs untraced) compares reports unchanged, and not
+    # so every bit-identity invariant (cached vs uncached, traced vs
+    # untraced) compares reports unchanged, and not
     # serialized into the design database.
     telemetry: Optional[Dict] = field(default=None, compare=False,
                                       repr=False)
@@ -259,10 +258,6 @@ class CostStats:
 # shared across models: two structurally identical candidate schedules have
 # the same recurrence II regardless of which statement/layer produced them
 _REC_II_CACHE: Dict[Tuple, int] = {}
-# keys of _REC_II_CACHE entries produced by the closed-form (analytic)
-# path; the parallel replay-merge needs the origin to adjust the right
-# counter when a worker's entry collides with an earlier candidate's
-_REC_II_XFER: set = set()
 
 
 class HlsModel:
@@ -478,9 +473,7 @@ class HlsModel:
         self.stats.analytic_node_evals += 1
         if len(_REC_II_CACHE) >= 100_000:
             _REC_II_CACHE.clear()
-            _REC_II_XFER.clear()
         _REC_II_CACHE[key] = ii
-        _REC_II_XFER.add(key)
 
     def _recurrence_ii(self, stmt: Statement, p: int,
                        unrolls: Dict[str, int], st: ExprStats) -> int:
@@ -514,10 +507,7 @@ class HlsModel:
             ii = self._recurrence_ii_compute(stmt, p, unrolls, st)
             if len(_REC_II_CACHE) >= 100_000:
                 _REC_II_CACHE.clear()
-                _REC_II_XFER.clear()
             _REC_II_CACHE[key] = ii
-            if analytic:
-                _REC_II_XFER.add(key)
             return ii
         self.stats.full_node_evals += 1
         return self._recurrence_ii_compute(stmt, p, unrolls, st)
@@ -866,19 +856,6 @@ def recurrence_ii_arith(dims: Sequence[str], p: int, trips: Dict[str, int],
     return ii_rec
 
 
-def _ii_threads() -> int:
-    """``POM_II_THREADS``: thread count for sharding a rung's closed-form
-    II sweep (:meth:`ClosedFormII.prefetch`).  The sweep is pure integer
-    arithmetic on immutable facts — no pickling, no fork — so sharding it
-    across threads is safe by construction; on GIL-serialized builds the
-    speedup is modest, which is why the default is 1 (compute on demand,
-    single thread)."""
-    try:
-        return max(1, int(os.environ.get("POM_II_THREADS", "1") or 1))
-    except ValueError:
-        return 1
-
-
 _II_MISS = object()
 
 
@@ -896,12 +873,7 @@ class ClosedFormII:
     (factor exceeds a trip count) and falls back to None when a class
     resists exact transfer.
 
-    ``ii`` is memoized per rung (``_memo``); ``prefetch`` fills the memo
-    for a whole candidate set at once, sharded across ``POM_II_THREADS``
-    threads when that is > 1.  ``_compute_ii`` touches only the frozen
-    rung facts and thread-local state (``DependenceInfo.transform`` is
-    pure), so concurrent computes are data-race-free; the memo itself is
-    only written from the calling thread.
+    ``ii`` is memoized per rung (``_memo``) and computed on demand.
     """
     dims: List[str]
     bounds: Dict[str, Tuple[int, int]]
@@ -970,27 +942,6 @@ class ClosedFormII:
         for d in outer[:-1]:
             outer_trip *= trips.get(d, 1)
         return outer_trip, trips.get(outer[-1], 1)
-
-    def prefetch(self, factor_lists, threads: Optional[int] = None) -> None:
-        """Fill the memo for ``factor_lists`` (a rung's candidate set).
-
-        With ``threads`` (default ``POM_II_THREADS``) > 1 and at least
-        two uncomputed vectors, the computes run on a thread pool —
-        values and every counter are identical either way (the sweep
-        charges nothing; ``prime_recurrence_ii`` does the accounting when
-        a candidate consumes a value).  With one thread this is a no-op:
-        values are computed on demand by ``ii``, preserving the serial
-        engine's work order exactly."""
-        n = _ii_threads() if threads is None else max(1, int(threads))
-        todo = [f for f in dict.fromkeys(tuple(f) for f in factor_lists)
-                if f not in self._memo]
-        if n <= 1 or len(todo) < 2:
-            return
-        from concurrent.futures import ThreadPoolExecutor
-        with ThreadPoolExecutor(max_workers=min(n, len(todo))) as ex:
-            vals = list(ex.map(self._compute_ii, todo))
-        for f, v in zip(todo, vals):
-            self._memo[f] = v
 
     def _compute_ii(self, factors: Tuple[int, ...]) -> Optional[int]:
         from .affine import BasisMap

@@ -95,8 +95,7 @@ def function_key(fn, options: Optional[Dict[str, Any]] = None) -> str:
     specs (by statement index), the function's dataflow pin, and the
     search options.  Deliberately **not** included: ``Statement.uid`` or
     ``schedule_signature()`` (both process-local), statement/array
-    *names* (canonicalized away), and worker counts (the parallel
-    evaluator is bit-identical to greedy by invariant)."""
+    *names* (canonicalized away)."""
     from .graph_ir import op_structural_key
     from .ir import loads_of
     by_id = {id(s): i for i, s in enumerate(fn.statements)}

@@ -18,11 +18,10 @@ and raise its parallelism degree (tile + pipeline + unroll + array
 partition) step by step until resources run out, it stops being the
 bottleneck, or max parallelism is reached (the exit mechanism of SS VI-B).
 
-Stage 2 is pluggable (PR 3): the searcher lives in ``search.py`` behind a
+Stage 2 is pluggable: the searcher lives in ``search.py`` behind a
 strategy registry — ``greedy`` (the ladder above, bit-identical to the
-pre-subsystem engine), ``beam`` (top-k parallelization states per rung),
-and ``parallel`` (worker-pool candidate evaluation with deterministic
-cache/counter merge).  Select with ``auto_dse(strategy=...)`` or
+pre-subsystem engine) and ``beam`` (top-k parallelization states per
+rung), both on one serial candidate evaluator.  Select with ``auto_dse(strategy=...)`` or
 ``POM_DSE_STRATEGY``; every evaluated design lands in an optional
 ``search.ParetoArchive`` (``archive=...`` / ``POM_DUMP_PARETO``).
 
@@ -314,7 +313,7 @@ def stage2(fn: Function, model: Optional[HlsModel] = None,
 
     With the default (greedy) strategy this is bit-identical to the
     pre-subsystem single-trajectory ladder; see ``search.py`` for the
-    ``beam`` and ``parallel`` alternatives."""
+    ``beam`` alternative."""
     return run_stage2(fn, model, max_parallel, actions,
                       strategy=strategy, archive=archive, **strategy_kw)
 
@@ -339,7 +338,6 @@ def auto_dse(fn: Function, target: str = "fpga", max_parallel: int = 256,
              resources: Dict = XC7Z020,
              model: Optional[HlsModel] = None,
              strategy=None, beam_width: Optional[int] = None,
-             workers: Optional[int] = None,
              archive=None, graph_passes: Sequence[str] = (),
              outputs: Optional[Sequence[str]] = None,
              dataflow: Optional[bool] = None,
@@ -356,10 +354,10 @@ def auto_dse(fn: Function, target: str = "fpga", max_parallel: int = 256,
     (``HlsModel(cache=False)`` reproduces the pre-incremental engine) or to
     read back ``model.stats`` evaluation counters afterwards.
 
-    ``strategy`` selects the stage-2 searcher (``"greedy"`` / ``"beam"`` /
-    ``"parallel"``, a ``search.SearchStrategy``, or None → the
-    ``POM_DSE_STRATEGY`` environment variable, default greedy);
-    ``beam_width`` / ``workers`` parameterize it.  ``archive`` collects
+    ``strategy`` selects the stage-2 searcher (``"greedy"`` / ``"beam"``,
+    a ``search.SearchStrategy``, or None → the ``POM_DSE_STRATEGY``
+    environment variable, default greedy); ``beam_width`` parameterizes
+    it.  ``archive`` collects
     every evaluated design into a ``search.ParetoArchive`` (pass an
     instance or ``True``); ``POM_DUMP_PARETO=<path|->`` dumps the
     frontier after the run.  ``outputs`` names the externally observable
@@ -373,7 +371,7 @@ def auto_dse(fn: Function, target: str = "fpga", max_parallel: int = 256,
     this run — Chrome trace-event JSON to a path, a compact tree summary
     to stdout for ``"-"``.  The returned ``report.telemetry`` carries the
     per-run metrics snapshot (analysis evals, cost-model counters,
-    wave/pool deltas) whether or not tracing was on."""
+    wave deltas) whether or not tracing was on."""
     from . import caching, telemetry
     from .pipeline import (GRAPH_PASSES, BuildGraph, GraphCSE, GraphDCE,
                            LowerToPoly, PassManager, PipelineContext,
@@ -389,7 +387,6 @@ def auto_dse(fn: Function, target: str = "fpga", max_parallel: int = 256,
                                    "model": model,
                                    "strategy": strategy,
                                    "beam_width": beam_width,
-                                   "workers": workers,
                                    "archive": archive})
     # CSE classification only (warm=()): grouping feeds the dump/debug
     # surface while the name-canonical memos themselves are populated on
@@ -404,7 +401,6 @@ def auto_dse(fn: Function, target: str = "fpga", max_parallel: int = 256,
                Stage2DSE(), VerifyPoly()]
     counts0 = dict(caching.COUNTS)
     stats0 = copy.copy(model.stats)
-    pool0 = telemetry.REGISTRY.counter_values("pool.")
     with telemetry.maybe_trace(trace_path):
         with telemetry.span("auto_dse", _cat="dse", fn=fn.name,
                             target=target):
@@ -424,7 +420,6 @@ def auto_dse(fn: Function, target: str = "fpga", max_parallel: int = 256,
     # per-run metrics snapshot (the bench/CI telemetry schema): counter
     # *movement* over this run, never perturbing anything it reads
     counts = caching.counts_delta(counts0)
-    pool1 = telemetry.REGISTRY.counter_values("pool.")
     strat_obj = ctx.records["stage2"].get("strategy_obj")
     wave = dict(getattr(strat_obj, "wave_stats", None) or {})
     report.telemetry = {
@@ -434,8 +429,6 @@ def auto_dse(fn: Function, target: str = "fpga", max_parallel: int = 256,
         "cost": model.stats.delta(stats0),
         "bound_prune": caching.bound_prune_on(),
         "wave": wave or None,
-        "pool": {k[len("pool."):]: pool1.get(k, 0) - pool0.get(k, 0)
-                 for k in sorted(set(pool0) | set(pool1))},
         "dse_seconds": dt,
     }
     return DseResult(report, log, actions, dt, tiles, model.stats,

@@ -9,10 +9,9 @@ The engine distinguishes three failure surfaces:
 * :class:`PomInternalError` — an invariant of the engine itself broke.
 * :class:`PomWarning` — a structured, one-line, machine-parseable warning
   for *recovered* conditions: a Mosaic lowering that fell back to
-  interpret mode, a worker pool that degraded to the serial evaluator, a
-  quarantined design-database entry.  Emitted via :func:`warn_structured`
-  so every recovery path in the resilience layer logs the same
-  ``[pom:component] event key=value ...`` shape.
+  interpret mode, a quarantined design-database entry.  Emitted via
+  :func:`warn_structured` so every recovery path in the resilience layer
+  logs the same ``[pom:component] event key=value ...`` shape.
 """
 from __future__ import annotations
 
@@ -50,8 +49,7 @@ def warn_structured(component: str, event: str, **fields) -> str:
     returns the message (callers may also log it).
 
     The single emission path for recovered faults: the warning carries a
-    monotonic ``ts=`` field (seconds, comparable across one process and
-    its forked workers), and the same component/event/fields land in the
+    monotonic ``ts=`` field (seconds), and the same component/event/fields land in the
     telemetry layer — a named counter always, plus a timeline instant
     when a trace session is active — so injected failures are visible in
     the very trace they perturb."""

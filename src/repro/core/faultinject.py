@@ -1,18 +1,13 @@
 """Deterministic fault injection for the resilience layer.
 
-Every recovery path in the resilient compile service — worker
-supervision in ``search.PoolEvaluator``, checksum/quarantine handling in
-``designdb.DesignDB``, and the compiled-kernel failure path in
+Every recovery path in the resilient compile service — checksum/quarantine
+handling in ``designdb.DesignDB`` and the compiled-kernel failure path in
 ``backend_pallas`` (which raises) — is exercised through *named injection
 sites* rather than trusted:
 
 =================  ==========================================  ==============
 site               where it fires                              kinds
 =================  ==========================================  ==============
-``worker.dispatch``  parent-side, per candidate dispatched to  ``crash`` (worker
-                     a pool worker; the kind rides in the       SIGKILLs itself),
-                     task payload and the *worker* executes it  ``hang``, ``pickle``
-                                                                (malformed reply)
 ``designdb.read``    before a db entry is read                 ``truncate``,
                                                                 ``bitflip``,
                                                                 ``error``
@@ -27,10 +22,10 @@ Faults are configured either programmatically (:func:`install` /
 :func:`injected`) or through ``POM_FAULT=<site>:<kind>[:p]`` (comma-
 separated for several).  ``p`` is a fire probability drawn from a
 *seeded* ``random.Random`` stream, so a given spec fires on exactly the
-same dispatch sequence every run — tests and the crash-rate benchmark
-are deterministic.  ``max_fires`` bounds how often a spec fires (the
-usual test shape: fire exactly once, then verify the recovered result is
-bit-identical to the fault-free run).
+same check sequence every run — tests are deterministic.  ``max_fires``
+bounds how often a spec fires (the usual test shape: fire exactly once,
+then verify the recovered result is bit-identical to the fault-free
+run).
 
 All sites are no-ops (one dict lookup + one env check) when nothing is
 installed, which is what keeps the production path inert.
@@ -43,9 +38,8 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-SITES = ("worker.dispatch", "designdb.read", "designdb.write",
-         "backend.lower")
-KINDS = ("crash", "hang", "pickle", "truncate", "bitflip", "error")
+SITES = ("designdb.read", "designdb.write", "backend.lower")
+KINDS = ("truncate", "bitflip", "error")
 
 
 @dataclass

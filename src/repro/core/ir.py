@@ -258,12 +258,10 @@ class Statement:
         # the positional basis step applied (``affine.BasisMap`` step) and
         # the trip-bound transfer op; ``_xfer_keys`` marks cache entries
         # whose values came from the transfer algebra rather than FM (the
-        # parallel replay-merge and the II counter split both need the
-        # origin).  Both are metadata only — results are identical with
+        # II counter split needs the origin).  Both are metadata only — results are identical with
         # the trace cleared, just re-derived by FM.
         self._basis_trace: Dict[Tuple, Tuple] = {}
-        self._xfer_keys: Dict[str, set] = {
-            "selfdep": set(), "trip": set(), "legal": set()}
+        self._xfer_keys: Dict[str, set] = {"selfdep": set(), "trip": set()}
         # lazily rebuilt by ``subst_signature`` / ``schedule_signature``;
         # every site that mutates a signature component — ``iter_subst``
         # (the transform primitives, ``search._restore``), the domain,

@@ -305,7 +305,7 @@ class Stage2DSE(Pass):
     reaches into backend internals.
 
     The searcher itself is pluggable (``search.py``): ``strategy`` — a
-    registered name (``"greedy"``, ``"beam[:k]"``, ``"parallel[:n]"``) or a
+    registered name (``"greedy"``, ``"beam[:k]"``) or a
     ``search.SearchStrategy`` instance — picks it, falling back to
     ``ctx.options["strategy"]``, then the ``POM_DSE_STRATEGY`` environment
     variable, then greedy.  The subclasses below register the alternative
@@ -327,8 +327,7 @@ class Stage2DSE(Pass):
         strategy = resolve_strategy(
             self.strategy if self.strategy is not None
             else ctx.options.get("strategy"),
-            beam_width=ctx.options.get("beam_width"),
-            workers=ctx.options.get("workers"))
+            beam_width=ctx.options.get("beam_width"))
         actions: List[str] = []
         report = run_stage2(ctx.fn, model,
                             ctx.options.get("max_parallel", 256), actions,
@@ -349,19 +348,10 @@ class Stage2BeamDSE(Stage2DSE):
         super().__init__(f"beam:{width}")
 
 
-class Stage2ParallelDSE(Stage2DSE):
-    """Stage 2 with worker-pool candidate evaluation
-    (``search.ParallelSearch``)."""
-    name = "dse-stage2-parallel"
-
-    def __init__(self, workers: Optional[int] = None):
-        super().__init__(f"parallel:{workers}" if workers else "parallel")
-
-
 # alternative stage-2 searchers, registered as pipeline passes; the key is
 # the strategy name accepted by ``stage2_pass`` / ``POM_DSE_STRATEGY``
 STAGE2_PASSES: Dict[str, Callable[..., Stage2DSE]] = {
-    "greedy": Stage2DSE, "beam": Stage2BeamDSE, "parallel": Stage2ParallelDSE,
+    "greedy": Stage2DSE, "beam": Stage2BeamDSE,
 }
 
 
@@ -383,7 +373,7 @@ def stage2_pass(spec: Optional[str] = None) -> Stage2DSE:
     if cls is Stage2DSE:
         return Stage2DSE(spec)
     if arg and not arg.lstrip("-").isdigit():
-        # rich parameterization ("beam:scalar", "beam:8:parallel", ...):
+        # rich parameterization ("beam:scalar", "beam:8:scalar", ...):
         # the named subclasses only spell the single-int shorthand, so
         # carry the validated spec through the generic pass
         return Stage2DSE(spec)
@@ -737,12 +727,9 @@ class CompileService:
     full DSE and persists the outcome atomically; a corrupted entry is
     quarantined by the db layer and simply recomputed here.
 
-    The ``parallel`` strategy is keyed as ``greedy``: the supervised
-    pool is bit-identical to the serial ladder by invariant (asserted in
-    ``tests/test_search.py``), so worker counts must not split the
-    address space.  The db stores the *outcome* (report, action log,
-    tile sizes) — backend artifacts are still emitted by ``compile``;
-    what the service removes is the search, which is where the time is.
+    The db stores the *outcome* (report, action log, tile sizes) —
+    backend artifacts are still emitted by ``compile``; what the service
+    removes is the search, which is where the time is.
 
     Observability: every request runs under a ``service.request`` span
     and feeds live hit/miss latency histograms (p50/p99 via
@@ -779,16 +766,8 @@ class CompileService:
         merged = dict(self.defaults)
         merged.update(kw)
         strat = resolve_strategy(merged.get("strategy"),
-                                 beam_width=merged.get("beam_width"),
-                                 workers=merged.get("workers"))
+                                 beam_width=merged.get("beam_width"))
         desc = strat.describe()
-        if desc.split(":")[0] == "parallel":
-            desc = "greedy"
-        elif "parallel" in desc.split(":"):
-            # a pooled beam ("beam:8:parallel") produces bit-identical
-            # designs to the serial beam — the pool changes wall-clock
-            # only, so it must not change the content address
-            desc = ":".join(t for t in desc.split(":") if t != "parallel")
         resources = merged.get("resources", XC7Z020)
         opts = {"strategy": desc,
                 "max_parallel": merged.get("max_parallel", 256),
@@ -835,7 +814,7 @@ class CompileService:
         res = auto_dse(fn, **{k: v for k, v in merged.items()
                               if k in ("target", "max_parallel", "resources",
                                        "model", "strategy", "beam_width",
-                                       "workers", "archive", "graph_passes",
+                                       "archive", "graph_passes",
                                        "outputs", "dataflow")})
         payload = {"report": designdb.report_to_json(res.report),
                    "actions": list(res.actions),

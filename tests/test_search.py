@@ -6,15 +6,13 @@ Equivalence invariants:
     ``tests/test_incremental_dse.py`` and the count budgets in
     ``tests/test_perf_smoke.py``; here we additionally pin that the
     explicit strategy spellings agree with the default.
-  * ``beam_width=1`` and ``workers=1`` are bit-identical to greedy on
-    every workload (schedules, reports, action logs, tile sizes).
-  * The worker pool returns identical results for any worker count, and
-    the replay-merged eval counters / ``CostStats`` equal a serial run's.
+  * ``beam_width=1`` is bit-identical to greedy on every workload
+    (schedules, reports, action logs, tile sizes).
   * ``beam`` (k >= 2) never returns a design with cost worse than greedy.
+  * Cross-state dedup fires: sibling beam states proposing the same
+    (base design, statement, P) rung share one evaluation.
   * ``ParetoArchive`` keeps exactly the non-dominated feasible points.
 """
-import os
-
 import pytest
 
 from benchmarks import workloads
@@ -22,8 +20,7 @@ from repro.core import caching
 from repro.core.cost_model import HlsModel
 from repro.core.dse import auto_dse
 from repro.core.search import (BeamSearch, DesignPoint, GreedySearch,
-                               ParallelSearch, ParetoArchive, PoolEvaluator,
-                               STRATEGIES, resolve_strategy)
+                               ParetoArchive, STRATEGIES, resolve_strategy)
 
 # every workload family, sized to keep the suite quick (polyhedral work is
 # extent-independent)
@@ -75,86 +72,39 @@ def test_beam_width1_bit_identical_to_greedy(name):
     assert b.strategy == "beam:1"
 
 
+@pytest.mark.parametrize("width", (2, 3, 4))
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_workers1_bit_identical_to_greedy(name):
-    g, gc, gs = _run(CASES[name])
-    p, pc, ps = _run(CASES[name], strategy="parallel", workers=1)
-    assert _result_tuple(g) == _result_tuple(p)
-    # workers=1 *is* the serial code path: every counter identical
-    assert gc == pc
-    assert gs == ps
-
-
-@pytest.mark.parametrize("name", sorted(CASES))
-def test_beam_never_worse_than_greedy(name):
+def test_beam_never_worse_than_greedy(name, width):
     g, _, _ = _run(CASES[name])
-    for width in (2, 3):
-        b, _, _ = _run(CASES[name], strategy="beam", beam_width=width)
-        assert b.report.feasible
-        assert b.report.latency <= g.report.latency, (
-            f"beam:{width} regressed {name}: "
-            f"{b.report.latency} > greedy {g.report.latency}")
-        # alt branches must re-apply factors from the clean per-node base
-        # (never compound splits), so achieved unroll products stay within
-        # the ladder's max_parallel budget
-        for sname, tiles in b.tile_sizes.items():
-            prod = 1
-            for f in tiles:
-                prod *= f
-            assert prod <= 16, (
-                f"beam:{width} {name}/{sname}: unroll product {prod} "
-                f"exceeds max_parallel=16 (dirty base snapshot)")
+    b, _, _ = _run(CASES[name], strategy="beam", beam_width=width)
+    assert b.report.feasible
+    assert b.report.latency <= g.report.latency, (
+        f"beam:{width} regressed {name}: "
+        f"{b.report.latency} > greedy {g.report.latency}")
+    # alt branches must re-apply factors from the clean per-node base
+    # (never compound splits), so achieved unroll products stay within
+    # the ladder's max_parallel budget
+    for sname, tiles in b.tile_sizes.items():
+        prod = 1
+        for f in tiles:
+            prod *= f
+        assert prod <= 16, (
+            f"beam:{width} {name}/{sname}: unroll product {prod} "
+            f"exceeds max_parallel=16 (dirty base snapshot)")
 
 
 # --------------------------------------------------------------------------
-# parallel candidate evaluation
+# cross-state dedup (evaluate once, credit all states)
 # --------------------------------------------------------------------------
-PARALLEL_CASES = ["gemm", "bicg", "3mm", "blur"]
-
-
-@pytest.mark.parametrize("name", PARALLEL_CASES)
-def test_parallel_identical_results_any_worker_count(name):
-    g, _, _ = _run(CASES[name])
-    for workers in (2, 3):
-        p, _, _ = _run(CASES[name], strategy="parallel", workers=workers)
-        assert _result_tuple(g) == _result_tuple(p), (
-            f"parallel:{workers} diverged from serial on {name}")
-
-
-@pytest.mark.parametrize("name", PARALLEL_CASES)
-def test_parallel_merged_counters_equal_serial(name):
-    _, gc, gs = _run(CASES[name])
-    _, pc, ps = _run(CASES[name], strategy="parallel", workers=2)
-    # the replay-merge must book every expensive analysis exactly once:
-    # all *eval* counters and the full CostStats equal the serial run's
-    for k in ("selfdep_evals", "legal_evals", "trip_evals", "access_evals"):
-        assert pc[k] == gc[k], f"{k}: serial {gc[k]} != merged {pc[k]}"
-    assert ps == gs
-    # hit counters: workers may repeat canonical-key lookups a serial run
-    # short-circuits (dictionary lookups, not analyses) — never fewer,
-    # and within a few percent
-    for k in ("selfdep_hits", "legal_hits", "trip_hits", "access_hits"):
-        assert gc[k] <= pc[k] <= int(gc[k] * 1.10) + 5, (
-            f"{k}: serial {gc[k]} vs merged {pc[k]}")
-
-
-def test_parallel_archive_matches_serial():
-    # archive points must carry the candidate's own design signature even
-    # when the candidate was evaluated in a worker process: frontier and
-    # evaluated-design counts equal the serial run's
-    s, _, _ = _run(CASES["gemm"], archive=True)
-    p, _, _ = _run(CASES["gemm"], strategy="parallel", workers=2,
-                   archive=True)
-    assert p.archive.evaluated == s.archive.evaluated
-    assert (sorted(pt.objectives() for pt in p.archive.frontier())
-            == sorted(pt.objectives() for pt in s.archive.frontier()))
-
-
-def test_parallel_worker_count_does_not_change_counters():
-    _, c2, s2 = _run(CASES["3mm"], strategy="parallel", workers=2)
-    _, c3, s3 = _run(CASES["3mm"], strategy="parallel", workers=3)
-    assert c2 == c3
-    assert s2 == s3
+def test_wave_dedup_fires_and_beats_naive_fanout():
+    strat = resolve_strategy("beam:8")
+    assert isinstance(strat, BeamSearch)
+    _run(CASES["blur"], strategy=strat)
+    ws = strat.wave_stats
+    assert ws["cands_credited"] > 0, (
+        "sibling beam states never shared a rung evaluation")
+    naive = ws["cands_evaluated"] + ws["cands_credited"]
+    assert ws["cands_evaluated"] < naive
 
 
 # --------------------------------------------------------------------------
@@ -217,7 +167,15 @@ def test_pareto_dump_hook(tmp_path, monkeypatch):
 # registry / selection plumbing
 # --------------------------------------------------------------------------
 def test_registry_contents():
-    assert set(STRATEGIES) >= {"greedy", "beam", "parallel"}
+    assert set(STRATEGIES) == {"greedy", "beam"}
+    # an unregistered name or beam segment is rejected, naming the
+    # registered strategies / the grammar; there is no workers= kwarg
+    with pytest.raises(ValueError, match=r"registered: \['beam', 'greedy'\]"):
+        resolve_strategy("parallel")
+    with pytest.raises(ValueError, match=r"beam\[:k\]\[:latency\|scalar\]"):
+        resolve_strategy("beam:4:parallel")
+    with pytest.raises(TypeError, match="workers"):
+        auto_dse(CASES["gemm"]().fn, workers=2)
 
 
 def test_resolve_strategy_specs():
@@ -225,8 +183,8 @@ def test_resolve_strategy_specs():
     assert isinstance(resolve_strategy("greedy"), GreedySearch)
     b = resolve_strategy("beam:4")
     assert isinstance(b, BeamSearch) and b.width == 4
-    p = resolve_strategy("parallel:3")
-    assert isinstance(p, ParallelSearch) and p.workers == 3
+    with pytest.raises(ValueError, match="unknown DSE strategy 'parallel'"):
+        resolve_strategy("parallel:3")
     inst = BeamSearch(width=7)
     assert resolve_strategy(inst) is inst
     with pytest.raises(ValueError):
@@ -238,21 +196,19 @@ def test_resolve_strategy_specs():
 
 def test_resolve_strategy_kwarg_env_precedence(monkeypatch):
     # call-site kwargs are more explicit than the ambient environment:
-    # beam_width selects beam, workers selects parallel, symmetrically
-    monkeypatch.setenv("POM_DSE_STRATEGY", "parallel:8")
+    # beam_width selects beam
+    monkeypatch.setenv("POM_DSE_STRATEGY", "greedy")
     s = resolve_strategy(None, beam_width=2)
     assert isinstance(s, BeamSearch) and s.width == 2
-    monkeypatch.setenv("POM_DSE_STRATEGY", "beam:2")
-    s = resolve_strategy(None, workers=4)
-    assert isinstance(s, ParallelSearch) and s.workers == 4
     # explicit spec + matching kwarg: kwarg overrides the :k suffix
     s = resolve_strategy("beam:3", beam_width=5)
     assert isinstance(s, BeamSearch) and s.width == 5
-    # workers on a beam spec makes it pooled (kwargs spelling of
-    # beam:3:parallel:2)
-    s = resolve_strategy("beam:3", workers=2)
-    assert isinstance(s, BeamSearch) and s.width == 3
-    assert isinstance(s.evaluator, PoolEvaluator) and s.evaluator.workers == 2
+    # neither a workers= kwarg nor an unregistered env spec is accepted
+    with pytest.raises(TypeError, match="workers"):
+        resolve_strategy("beam:3", workers=2)
+    monkeypatch.setenv("POM_DSE_STRATEGY", "parallel:8")
+    with pytest.raises(ValueError, match=r"registered: \['beam', 'greedy'\]"):
+        resolve_strategy(None)
 
 
 def test_resolve_strategy_beam_grammar():
@@ -271,27 +227,9 @@ def test_resolve_strategy_beam_grammar():
     assert s.width == 6 and s.rank == "scalar"
 
 
-def test_resolve_strategy_beam_parallel_grammar():
-    s = resolve_strategy("beam:parallel")
-    assert isinstance(s, BeamSearch) and s.width == 2
-    assert isinstance(s.evaluator, PoolEvaluator)
-    assert s.evaluator.workers == (os.cpu_count() or 1)
-    s = resolve_strategy("beam:parallel:3")
-    assert isinstance(s.evaluator, PoolEvaluator) and s.evaluator.workers == 3
-    s = resolve_strategy("beam:8:parallel")
-    assert s.width == 8 and isinstance(s.evaluator, PoolEvaluator)
-    s = resolve_strategy("beam:8:scalar:parallel:2")
-    assert (s.width == 8 and s.rank == "scalar"
-            and isinstance(s.evaluator, PoolEvaluator)
-            and s.evaluator.workers == 2)
-    # a serial beam never carries a pool
-    s = resolve_strategy("beam:8")
-    assert not isinstance(s.evaluator, PoolEvaluator)
-
-
 def test_resolve_strategy_beam_grammar_errors():
     # duplicate / unknown segments are rejected and name the original spec
-    for bad in ("beam:4:2", "beam:scalar:latency", "beam:parallel:2:parallel",
+    for bad in ("beam:4:2", "beam:scalar:latency", "beam:parallel",
                 "beam:fast", "beam:4:bogus"):
         with pytest.raises(ValueError, match="beam"):
             resolve_strategy(bad)
@@ -306,12 +244,12 @@ def test_env_var_selects_strategy(monkeypatch):
 
 
 def test_stage2_pipeline_pass_registry():
-    from repro.core.pipeline import (STAGE2_PASSES, Stage2BeamDSE,
-                                     Stage2ParallelDSE, stage2_pass)
-    assert set(STAGE2_PASSES) == {"greedy", "beam", "parallel"}
+    from repro.core.pipeline import STAGE2_PASSES, Stage2BeamDSE, stage2_pass
+    assert set(STAGE2_PASSES) == {"greedy", "beam"}
     p = stage2_pass("beam:3")
     assert isinstance(p, Stage2BeamDSE) and p.strategy == "beam:3"
-    assert isinstance(stage2_pass("parallel"), Stage2ParallelDSE)
+    with pytest.raises(ValueError, match=r"registered: \['beam', 'greedy'\]"):
+        stage2_pass("parallel")
     with pytest.raises(ValueError):
         stage2_pass("bogus")
     with pytest.raises(ValueError, match="greedy:2"):

@@ -2,8 +2,8 @@
 
 Contract: a db hit serves the *same* outcome as the cold compile
 (report, actions, tile sizes) in O(lookup) without mutating the input
-function; the address is canonical (worker counts and statement names
-don't split it); and with ``POM_DESIGN_DB`` unset the layer is a
+function; the address is canonical (statement names don't split it,
+a different strategy does); and with ``POM_DESIGN_DB`` unset the layer is a
 per-process memo — fully inert for everyone not calling it.
 """
 import os
@@ -33,6 +33,10 @@ def test_miss_then_hit(tmp_path):
     assert r2.actions == r1.actions
     assert r2.tile_sizes == r1.tile_sizes
     assert (svc.stats.hits, svc.stats.misses) == (1, 1)
+    # a genuinely different strategy is a different address
+    r3 = svc.compile_one(workloads.gemm(24).fn, max_parallel=16,
+                         strategy="beam", beam_width=2)
+    assert not r3.from_db and r3.key != r1.key
 
 
 def test_hit_does_not_mutate_function(tmp_path):
@@ -53,18 +57,6 @@ def test_hit_survives_process_boundary(tmp_path):
         workloads.bicg(24).fn, max_parallel=16)
     assert not r1.from_db and r2.from_db
     assert r2.report == r1.report
-
-
-def test_parallel_keyed_as_greedy(tmp_path):
-    svc = serve(path=str(tmp_path / "db"))
-    r1 = svc.compile_one(workloads.gemm(24).fn, max_parallel=16)
-    r2 = svc.compile_one(workloads.gemm(24).fn, max_parallel=16,
-                         strategy="parallel", workers=3)
-    assert r2.from_db and r2.key == r1.key
-    # a genuinely different strategy is a different address
-    r3 = svc.compile_one(workloads.gemm(24).fn, max_parallel=16,
-                         strategy="beam", beam_width=2)
-    assert not r3.from_db and r3.key != r1.key
 
 
 def test_key_canonical_across_renamings(tmp_path):

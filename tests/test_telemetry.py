@@ -2,11 +2,13 @@
 
 Acceptance criteria of the observability PR:
 
-  * a traced ``beam:4:parallel`` run through the compile service produces
-    a valid Chrome trace-event JSON containing pipeline-pass, rung/wave,
-    worker-lane, and designdb spans;
-  * with tracing disabled every bit-identity invariant holds (traced vs
-    untraced designs compare equal) and the disabled path is pay-for-use
+  * a traced ``beam:4`` run through the compile service produces a valid
+    Chrome trace-event JSON containing pipeline-pass, rung/wave,
+    candidate, and designdb spans on one named process track;
+  * tracing never changes the search: traced and untraced runs pick the
+    same design with the same evaluation counters, on every workload
+    under ``greedy`` and ``beam:4`` (the traced rung and wave wrappers are
+    the search's only fork), and the disabled path is pay-for-use
     (null-span singleton, no per-call allocation);
   * ``warn_structured`` routes through the telemetry event API — one
     emission path feeding both ``PomWarning`` and the trace/registry;
@@ -25,7 +27,9 @@ from repro.core import dsl as pom
 from repro.core.dse import auto_dse
 from repro.core.errors import PomWarning, warn_structured
 from repro.core.pipeline import CompileService
+from repro.core.cost_model import HlsModel
 from repro.core.search import ParetoArchive
+from test_search import CASES
 
 
 @pytest.fixture(autouse=True)
@@ -44,17 +48,14 @@ def _fresh():
 
 
 # --------------------------------------------------------------------------
-# acceptance: traced pooled-beam service request → valid Chrome trace
+# acceptance: traced beam service request → valid Chrome trace
 # --------------------------------------------------------------------------
-def test_traced_beam_parallel_service_chrome_trace(tmp_path, monkeypatch):
-    # force the pool on even on a single-core runner: the acceptance
-    # criterion wants real worker lanes in the trace
-    monkeypatch.setenv("POM_POOL_MIN_CANDIDATES", "2")
+def test_traced_beam_parallel_service_chrome_trace(tmp_path):
     _fresh()
     tp = str(tmp_path / "trace.json")
     svc = CompileService(path=str(tmp_path / "db"), trace_path=tp)
     svc.compile_one(W.conv_chain(16, (3, 8, 8)).fn, target="hls",
-                    max_parallel=16, strategy="beam:4:parallel:2")
+                    max_parallel=16, strategy="beam:4")
     data = json.load(open(tp))          # json.load itself validates
     evs = data["traceEvents"]
     assert isinstance(evs, list) and evs
@@ -65,26 +66,35 @@ def test_traced_beam_parallel_service_chrome_trace(tmp_path, monkeypatch):
     names = {e["name"] for e in evs}
     assert any(n.startswith("pass.") for n in names)          # pipeline
     assert "stage2.rung" in names and "stage2.wave" in names  # DSE
-    assert "worker.candidate" in names                        # worker lane
+    assert "stage2.candidate" in names
     assert "designdb.get" in names and "designdb.put" in names
     assert "service.request" in names and "auto_dse" in names
-    # worker lanes ride on their own pid with a process_name track
-    worker_pids = {e["pid"] for e in evs if e["name"] == "worker.candidate"}
-    assert worker_pids and os.getpid() not in worker_pids
-    tracks = {e["args"]["name"] for e in evs if e["name"] == "process_name"}
-    assert "pom" in tracks
-    assert any(t.startswith("pom worker ") for t in tracks)
+    # one process, one named track
+    assert {e["pid"] for e in evs} == {os.getpid()}
+    tracks = [e["args"]["name"] for e in evs if e["name"] == "process_name"]
+    assert tracks == ["pom"]
 
 
-def test_traced_run_bit_identical_to_untraced(tmp_path):
+def _dse(name, strategy, **kw):
     _fresh()
-    off = auto_dse(W.mm2(12).fn, strategy="beam:2")
-    _fresh()
-    on = auto_dse(W.mm2(12).fn, strategy="beam:2",
-                  trace_path=str(tmp_path / "t.json"))
+    model = HlsModel()
+    res = auto_dse(CASES[name]().fn, max_parallel=16, model=model,
+                   strategy=strategy, **kw)
+    return res, dict(caching.COUNTS), model.stats
+
+
+@pytest.mark.parametrize("strategy", ["greedy", "beam:4"])
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_traced_run_bit_identical_to_untraced(tmp_path, name, strategy):
+    off, c_off, s_off = _dse(name, strategy)
+    on, c_on, s_on = _dse(name, strategy,
+                          trace_path=str(tmp_path / "t.json"))
     assert off.report == on.report      # telemetry field excluded (compare=False)
     assert off.actions == on.actions
     assert off.tile_sizes == on.tile_sizes
+    # tracing records observations only: no counter moves with it
+    assert c_off == c_on
+    assert s_off == s_on
 
 
 def test_report_telemetry_attached_even_untraced():
